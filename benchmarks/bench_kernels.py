@@ -398,9 +398,9 @@ def bench_fused_epilogue():
                "cpu_us_pallas_interpret": round(times["pallas"], 1)})
 
     # Data-calibrated readout (out_scale=None, the output_calibration=True
-    # serving path): the two-phase calibrated kernel folds the per-slot
-    # max|z| into the accumulator walk — one launch, ONE (M, N) HBM write —
-    # vs the legacy two-pass path (integrate kernel + unfused jnp epilogue).
+    # serving path): a per-tile max launch feeds the fused kernel its slot
+    # windows — ONE (M, N) HBM write — vs the legacy two-pass path
+    # (integrate kernel + unfused jnp epilogue).
     cal_counts, cal_outs = {}, {}
     for mode, fused in (("fused", True), ("unfused", False)):
         fn = jax.jit(functools.partial(

@@ -1,14 +1,4 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Shared Pallas-TPU compat helpers for the kernel modules."""
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.TPUCompilerParams`` was renamed ``CompilerParams`` in newer jax;
-    build whichever this install has."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+"""Pallas-TPU kernels: tdvmm (the paper's multiplier), crossing, ssd."""

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(t_ref, i_ref, o_ref, *, iters: int, k_charge: float,
             t_lo: float, t_hi: float):
@@ -76,7 +74,7 @@ def crossing_kernel(
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(t_on.astype(jnp.float32), currents.astype(jnp.float32))

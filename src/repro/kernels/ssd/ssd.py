@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, st_ref, *, nc: int):
     ci = pl.program_id(2)
@@ -101,7 +99,7 @@ def ssd_kernel(
         out_specs=pl.BlockSpec((1, q, 1, Pd), lambda bi, h, ci: (bi, ci, h, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, L, H, Pd), x.dtype),
         scratch_shapes=[pltpu.VMEM((Pd, S), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a_log, b, c)

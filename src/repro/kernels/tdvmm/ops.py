@@ -27,13 +27,14 @@ bit-for-bit identical to int8.  ``"f32"`` is the legacy float-code path
 Epilogue placement (Pallas backend): a *fixed* readout window (``out_scale``
 given) or no readout runs the whole epilogue inside the kernel's final K
 step (tdvmm_fused_kernel); a data-calibrated window (``out_scale=None`` with
-``out_bits``) runs the two-phase ``tdvmm_calibrated_kernel``, which folds
-the per-slot max|z| reduction into the accumulator walk and applies the
-windowed readout in the same launch.  Either way each output tile
-materializes in HBM exactly once, already in model units
-(``fused_calibration=False`` forces the legacy unfused jnp epilogue for the
-calibrated case).  All epilogues evaluate the same expression term for term,
-so every pairing is bit-for-bit identical.
+``out_bits``) first runs ``tdvmm_absmax_kernel`` for the per-tile max|z|,
+reduces it per readout slot, and then runs the fused kernel with those
+windows.  Either way each output tile materializes in HBM exactly once,
+already in model units (``fused_calibration=False`` forces the legacy
+unfused jnp epilogue for the calibrated case).  All epilogues evaluate the
+same expression term for term — the window's reciprocal comes from one XLA
+expression, ``tdvmm.readout_factors``, on every path — so every pairing is
+bit-for-bit identical.
 
 Batching: 3-D inputs (E, M, K) x (E, K, N) map the expert dim onto the
 kernel's batched grid axis (scales (E, M) / (E, N)); 2-D inputs run as E=1.
@@ -74,7 +75,7 @@ import numpy as np
 
 from repro.kernels.tdvmm.tdvmm import (
     acc_dtype_for, autotune_blocks, autotune_lookup, autotune_platform,
-    pad_to_blocks, tdvmm_calibrated_kernel, tdvmm_fused_kernel,
+    pad_to_blocks, readout_factors, tdvmm_absmax_kernel, tdvmm_fused_kernel,
     tdvmm_matmul_kernel)
 
 
@@ -266,16 +267,19 @@ def _epilogue(acc, x_scale, w_scale, gain, out_bits, out_scale,
                 s = jnp.asarray(s, jnp.float32).reshape(-1, 1, 1)
         else:
             s = jnp.float32(s)
-        s = jax.lax.optimization_barrier(s.astype(jnp.float32))
+        # The barriers in readout_factors pin mul(z, inv): XLA otherwise
+        # strength-reduces mul(z, div(1, s)) back into div(z, s) — 1 ulp
+        # off, and only in programs where s is a scalar broadcast, so a
+        # grouped (vector window) launch and its sequential counterpart
+        # would disagree.  The window is widened to one value per column
+        # first, as the fused kernels take it: on a TPU, XLA divides a
+        # scalar on the scalar unit, whose rounding differs from the vector
+        # unit's.
+        s = jnp.broadcast_to(jnp.asarray(s, jnp.float32),
+                             z.shape[:-2] + (1, z.shape[-1]))
+        inv, back = readout_factors(s, out_bits)
         levels = float((1 << out_bits) - 1)
-        # The barrier pins mul(z, inv): XLA otherwise strength-reduces
-        # mul(z, div(1, s)) back into div(z, s) — 1 ulp off, and only in
-        # programs where s is a scalar broadcast, so a grouped (vector
-        # window) launch and its sequential counterpart would disagree.
-        inv = jax.lax.optimization_barrier(jnp.float32(1.0) / s)
         z = jnp.round(jnp.clip(z * inv, -1.0, 1.0) * levels)
-        back = jax.lax.optimization_barrier(
-            s * (np.float32(1.0) / np.float32(levels)))
         ws_row = jax.lax.optimization_barrier(ws_row * back)
     # Pin (z * xs) before the ws_row multiply: with both factors broadcasts,
     # XLA reassociates the chain shape-dependently; the kernels' in-VMEM
@@ -284,22 +288,44 @@ def _epilogue(acc, x_scale, w_scale, gain, out_bits, out_scale,
     return zx * ws_row
 
 
-def _calib_slots(e: int, n: int, bn: int,
-                 group_widths) -> tuple[jax.Array, int]:
-    """(slots, nslots) for the calibrated kernel: the readout-slot id of
-    every N column block — the expert id for batched launches, the group
-    member owning the span for ragged launches (pad-tail blocks fold into
-    the last member; their zero accumulators can't move an abs-max)."""
-    bn = min(bn, n)
-    nn = n // bn
+def _slot_windows(tile_max: jax.Array, bn: int, n: int,
+                  group_widths) -> jax.Array:
+    """Per-tile maxima (E, M // bn_m, N // bn) -> (E|1, 1, N) per-column
+    data-calibrated windows: the max over each readout slot — the whole
+    expert tile for batched launches, the owning member's column span for
+    ragged ones (pad-tail blocks fold into the last member; their zero
+    accumulators can't move an abs-max) — floored as in ``_epilogue``."""
+    col = jnp.max(tile_max, axis=1)                    # (E, N // bn)
+    e, nn = col.shape
     if group_widths is None:
-        ids = jnp.broadcast_to(
-            jnp.arange(e, dtype=jnp.int32)[:, None], (e, nn))
-        return ids, e
-    bounds = np.cumsum(group_widths)
-    ids = np.searchsorted(bounds, np.arange(nn) * bn, side="right")
-    ids = np.minimum(ids, len(group_widths) - 1).astype(np.int32)
-    return jnp.asarray(ids)[None, :], len(group_widths)
+        s = jnp.broadcast_to(jnp.max(col, axis=1).reshape(e, 1, 1), (e, 1, n))
+    else:
+        ids = np.searchsorted(np.cumsum(group_widths), np.arange(nn) * bn,
+                              side="right")
+        ids = np.minimum(ids, len(group_widths) - 1)
+        member = jnp.stack([jnp.max(col[0, np.flatnonzero(ids == g)])
+                            for g in range(len(group_widths))])
+        s = _member_window_cols_arr(member, group_widths, n)
+    return jnp.maximum(s, 1e-9)
+
+
+def _whole_on_each_device(kernel, *operands):
+    """Run one kernel launch under the active serving/training mesh.
+
+    GSPMD cannot partition a Mosaic kernel (lowering refuses a launch in a
+    multi-device program outside shard_map), so under a mesh each device
+    runs the whole launch on replicated operands inside a shard_map — the
+    same arithmetic as one device, so sharded and meshless results stay
+    bit-identical.  Launches already inside a manual region (the MoE
+    shard_map body) are per-shard by construction and run as they are."""
+    from repro.launch import meshctx
+    mesh = meshctx.get_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel(*operands)
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*operands)
 
 
 def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
@@ -370,25 +396,29 @@ def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
                 and isinstance(out_scale, tuple)):
             window, scale_arg = _member_window_cols(
                 out_scale, group_widths, np_), None
-        y = tdvmm_fused_kernel(
-            xp, wp, xsp, wsp, window=window, gain=gain, out_bits=out_bits,
+        y = _whole_on_each_device(functools.partial(
+            tdvmm_fused_kernel, gain=gain, out_bits=out_bits,
             out_scale=scale_arg, bm=bm, bk=bk, bn=bn, interpret=interpret,
-            unpack4=unpack4)
+            unpack4=unpack4), xp, wp, xsp, wsp, window)
         return y if exact else y[:, :m, :n]
     if fused_calibration:
-        # Data-calibrated window, still one launch / one HBM output: the
-        # two-phase kernel folds the per-slot max into the accumulator walk.
+        # Data-calibrated window, still one (M, N) HBM output: per-tile
+        # maxima, reduced per readout slot, become the fused launch's window.
         xsp = jnp.pad(x_scale, ((0, 0), (0, mp - m)))[..., :, None]
         wsp = jnp.pad(w_scale, ((0, 0), (0, np_ - n)))[..., None, :]
-        slots, nslots = _calib_slots(e, np_, bn, group_widths)
-        y = tdvmm_calibrated_kernel(
-            xp, wp, xsp, wsp, slots, gain=gain, out_bits=out_bits,
-            nslots=nslots, bm=bm, bk=bk, bn=bn, interpret=interpret,
-            unpack4=unpack4)
+        tile_max = _whole_on_each_device(functools.partial(
+            tdvmm_absmax_kernel, gain=gain, bm=bm, bk=bk, bn=bn,
+            interpret=interpret, unpack4=unpack4), xp, wp)
+        window = _slot_windows(tile_max, min(bn, np_), np_, group_widths)
+        y = _whole_on_each_device(functools.partial(
+            tdvmm_fused_kernel, gain=gain, out_bits=out_bits, bm=bm, bk=bk,
+            bn=bn, interpret=interpret, unpack4=unpack4),
+            xp, wp, xsp, wsp, window)
         return y if exact else y[:, :m, :n]
     # Legacy two-pass: integrate in the kernel, epilogue unfused in jnp.
-    acc = tdvmm_matmul_kernel(
-        xp, wp, bm=bm, bk=bk, bn=bn, interpret=interpret, unpack4=unpack4)
+    acc = _whole_on_each_device(functools.partial(
+        tdvmm_matmul_kernel, bm=bm, bk=bk, bn=bn, interpret=interpret,
+        unpack4=unpack4), xp, wp)
     acc = acc if exact else acc[:, :m, :n]
     return _epilogue(acc, x_scale, w_scale, gain, out_bits, out_scale,
                      group_widths, out_window)
@@ -528,7 +558,7 @@ def tdvmm_matmul(
     """Quantized four-quadrant TD-VMM: codes matmul + readout + scale epilogue.
 
     ``out_scale=None`` calibrates the readout window from the data (§3.1) —
-    on the Pallas backend via the fused two-phase ``tdvmm_calibrated_kernel``
+    on the Pallas backend via a per-tile max launch plus the fused kernel
     (``fused_calibration=False`` forces the legacy unfused epilogue); pass
     the value captured by ``core.layers.calibrate_out_scale`` (or the
     model-wide calibration pass) to skip the per-call max entirely.  A tuple

@@ -27,19 +27,19 @@ latch gain, optional p-bit shared-counter readout (Eq. 3) over a calibrated
 window, and the per-row x per-channel digital rescale — so the output hits
 HBM exactly once, already in model units.  Readout windows enter as
 per-column (E, 1, N) operand vectors, so per-expert, per-group-member
-(ragged), and scalar windows all ride the same (1, 1, bn) blocks.
+(ragged), and scalar windows all ride the same (1, 1, bn) blocks.  The
+window's reciprocal and back-scale are computed outside the kernel by
+``readout_factors`` — the one place the epilogue divides — so the kernel
+only multiplies, rounds and clips, and matches the XLA epilogue
+(``ops._epilogue``) bit for bit whatever each compiler does for division.
 
-Data-calibrated readout (``tdvmm_calibrated_kernel``): when the window is
+Data-calibrated readout (``tdvmm_absmax_kernel``): when the window is
 max|z| over a whole slot (an expert tile, or one ragged group member's
-column span), no single (bm, bn) tile knows it.  A two-phase grid walks the
-same launch twice (grid axis 0, all dims sequential): phase 0 integrates,
-folds each tile's |z| max into a per-slot VMEM scratch vector, and parks the
-raw accumulator in the output buffer; phase 1 revisits each tile, reads the
-accumulator back, and applies the windowed readout + rescale with the now
-final slot windows.  int32 accumulators survive the f32 output buffer
-bit-exactly via bitcast.  Two passes over the output tile in VMEM, but still
-exactly one materialized (M, N) product in HBM — the epilogue chain never
-exists as full-size intermediates (the jnp fallback materializes ~10).
+column span), no single (bm, bn) tile knows it.  A first launch integrates
+and writes only each tile's max|z| (an (8, 128) block per tile, no (M, N)
+output); the caller reduces those per slot into windows and runs the fused
+kernel with them.  The codes are read twice, but exactly one (M, N) product
+reaches HBM.
 
 Batched expert grid: a leading E dimension maps (E, M, K) x (E, K, N) MoE
 expert stacks onto grid axis 0 — one analog tile per expert — with per-expert
@@ -68,7 +68,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.tdvmm.autotune_table import INTERPRET_TABLE, MOSAIC_TABLE
 
 # Default MXU-aligned block shape; pad_to_blocks() aligns arbitrary model
@@ -205,11 +204,13 @@ def _unpack_nibbles(packed: jax.Array, axis: int) -> jax.Array:
     applies to the x block's lane axis and the w block's sublane axis, and
     integer dot accumulation is order-independent — so the block dot is
     bit-for-bit the unpacked dot.  Arithmetic shifts sign-extend: the low
-    nibble recovers via (v << 4) >> 4, the high via v >> 4.
+    nibble recovers via (v << 28) >> 28, the high via (v << 24) >> 28.  The
+    shifts run on int32 lanes: Mosaic has no int8 shift.
     """
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
-    return jnp.concatenate([lo, hi], axis=axis)
+    v = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(v, 28), 28)
+    hi = jnp.right_shift(jnp.left_shift(v, 24), 28)
+    return jnp.concatenate([lo, hi], axis=axis).astype(jnp.int8)
 
 
 def _pin(v: jax.Array, interpret: bool) -> jax.Array:
@@ -228,15 +229,36 @@ def _pin(v: jax.Array, interpret: bool) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Kernel body (shared by the plain and fused entry points)
+# Kernel body (shared by every entry point)
 # ---------------------------------------------------------------------------
-def _kernel(*refs, nk: int, acc_dtype, fuse: bool, gain: float,
-            out_bits: int | None, has_window: bool = False,
-            unpack4: bool = False, interpret: bool = False):
-    os_ref = ob_ref = None
-    if fuse and has_window:
-        x_ref, w_ref, xs_ref, ws_ref, os_ref, ob_ref, o_ref, acc_ref = refs
-    elif fuse:
+def readout_factors(window: jax.Array, out_bits: int
+                    ) -> tuple[jax.Array, jax.Array]:
+    """(1 / window, window / levels) for a p-bit readout over ``window``.
+
+    The only division of the epilogue.  Both the fused kernels and the XLA
+    epilogue (``ops._epilogue``) take these factors from here, evaluated by
+    XLA outside any kernel, so the readout is the same arithmetic on every
+    backend.  Barriers keep XLA from strength-reducing the reciprocal back
+    into a divide or sinking constants through it."""
+    window = jax.lax.optimization_barrier(window.astype(jnp.float32))
+    levels = float((1 << out_bits) - 1)
+    inv = jax.lax.optimization_barrier(jnp.float32(1.0) / window)
+    back = jax.lax.optimization_barrier(
+        window * (np.float32(1.0) / np.float32(levels)))
+    return inv, back
+
+
+def _kernel(*refs, nk: int, acc_dtype, epilogue: str, gain: float,
+            out_bits: int | None, unpack4: bool = False,
+            interpret: bool = False):
+    """One (bm, bn) tile over the K walk; the final K step runs
+    ``epilogue``: "raw" (the accumulator), "fused" (gain, optional readout
+    and rescale, in model units) or "absmax" (the tile's max|acc * gain|,
+    broadcast over an (8, 128) block)."""
+    inv_ref = back_ref = None
+    if epilogue == "fused" and out_bits is not None:
+        x_ref, w_ref, xs_ref, ws_ref, inv_ref, back_ref, o_ref, acc_ref = refs
+    elif epilogue == "fused":
         x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref = refs
     else:
         x_ref, w_ref, o_ref, acc_ref = refs
@@ -254,39 +276,40 @@ def _kernel(*refs, nk: int, acc_dtype, fuse: bool, gain: float,
     @pl.when(pl.program_id(3) == nk - 1)
     def _done():
         acc = acc_ref[...]
-        if not fuse:
+        if epilogue == "raw":
             o_ref[0] = acc
+            return
+        z = _pin(acc.astype(jnp.float32) * gain, interpret)
+        if epilogue == "absmax":
+            # f32 max is exact and order-free, so the caller's per-slot
+            # max over tiles equals the unfused global max bit for bit;
+            # zero-padded rows/cols contribute |0|, which can't move it.
+            t = jnp.max(jnp.max(jnp.abs(z), axis=1, keepdims=True), axis=0,
+                        keepdims=True)
+            o_ref[0] = jnp.broadcast_to(t, o_ref.shape[1:])
             return
         # Fused epilogue — the (bm, bn) tile is finished in VMEM and written
         # to HBM exactly once.  The expression mirrors ops._epilogue term for
         # term so the fused and unfused paths stay bit-for-bit identical.
-        z = _pin(acc.astype(jnp.float32) * gain, interpret)
         ws_row = ws_ref[0]
         if out_bits is not None:
-            # The readout window (and its precomputed back-scale s/levels)
-            # ride along as per-column (1, 1, bn) blocks of (E, 1, N)
-            # operand vectors — grid axis 0 is the expert axis, so each tile
-            # reads its own analog tile's calibrated window (scalar and
-            # per-expert windows are broadcast per-column by the caller;
-            # ragged grouped launches carry genuinely per-member columns).
-            # Runtime values + the constant-free post-round chain
-            # ``(q * xs) * (ws * back)`` mirror ops._epilogue term for term,
-            # so fused, unfused, and per-call data-calibrated windows stay
-            # bit-for-bit identical (baked literals would invite XLA
-            # strength reduction / constant reassociation on one side only).
+            # The window's reciprocal and back-scale ride along as per-column
+            # (1, 1, bn) blocks of (E, 1, N) operands — grid axis 0 is the
+            # expert axis, so each tile reads its own analog tile's window
+            # (ragged grouped launches carry per-member columns).  Runtime
+            # values + the constant-free post-round chain
+            # ``(q * xs) * (ws * back)`` mirror ops._epilogue term for term.
             levels = float((1 << out_bits) - 1)
-            inv = _pin(jnp.float32(1.0) / os_ref[0], interpret)
-            z = jnp.round(jnp.clip(z * inv, -1.0, 1.0) * levels)
-            ws_row = _pin(ws_row * ob_ref[0], interpret)
+            z = jnp.round(jnp.clip(z * inv_ref[0], -1.0, 1.0) * levels)
+            ws_row = _pin(ws_row * back_ref[0], interpret)
         o_ref[0] = _pin(z * xs_ref[0], interpret) * ws_row
 
 
-def _grid_call(e, m, k, n, bm, bk, bn, *, acc_dtype, out_dtype, fuse,
-               gain, out_bits, interpret, shared_x=False, unpack4=False):
+def _grid_call(e, m, k, n, bm, bk, bn, *, acc_dtype, epilogue, gain,
+               out_bits, interpret, shared_x=False, unpack4=False):
     bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
     assert m % bm == 0 and k % bk == 0 and n % bn == 0, (m, k, n, bm, bk, bn)
     nk = k // bk
-    has_window = fuse and out_bits is not None
     # Shared-input grouped launch: x (and x_scale) carry a single batch entry
     # that every grid-axis-0 tile reads — one code copy in HBM for G tiles.
     xb = (lambda b: 0) if shared_x else (lambda b: b)
@@ -294,27 +317,33 @@ def _grid_call(e, m, k, n, bm, bk, bn, *, acc_dtype, out_dtype, fuse,
         pl.BlockSpec((1, bm, bk), lambda b, i, j, s: (xb(b), i, s)),
         pl.BlockSpec((1, bk, bn), lambda b, i, j, s: (b, s, j)),
     ]
-    if fuse:
+    if epilogue == "fused":
         in_specs += [
             pl.BlockSpec((1, bm, 1), lambda b, i, j, s: (xb(b), i, 0)),
             pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, j)),
         ]
-    if has_window:
-        # (E, 1, N) per-column window + back-scale vectors, one (1, 1, bn)
-        # block per tile.
-        in_specs += [pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, j)),
-                     pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, j))]
+        if out_bits is not None:
+            # (E, 1, N) per-column reciprocal-window + back-scale vectors,
+            # one (1, 1, bn) block per tile.
+            in_specs += [pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, j)),
+                         pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, j))]
+    if epilogue == "absmax":
+        out_block, out_dtype = (1, 8, LANE), jnp.float32
+        out_shape = (e, (m // bm) * 8, (n // bn) * LANE)
+    else:
+        out_block, out_shape = (1, bm, bn), (e, m, n)
+        out_dtype = acc_dtype if epilogue == "raw" else jnp.float32
     return pl.pallas_call(
         functools.partial(
-            _kernel, nk=nk, acc_dtype=acc_dtype, fuse=fuse, gain=gain,
-            out_bits=out_bits, has_window=has_window, unpack4=unpack4,
+            _kernel, nk=nk, acc_dtype=acc_dtype, epilogue=epilogue,
+            gain=gain, out_bits=out_bits, unpack4=unpack4,
             interpret=interpret),
         grid=(e, m // bm, n // bn, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, s: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
+        out_specs=pl.BlockSpec(out_block, lambda b, i, j, s: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -358,10 +387,9 @@ def tdvmm_matmul_kernel(
     ex, m, k = x_codes.shape
     e, k2, n = w_codes.shape
     assert (ex == e or ex == 1) and k == k2, (x_codes.shape, w_codes.shape)
-    acc_dtype = acc_dtype_for(x_codes.dtype)
     out = _grid_call(
-        e, m, k, n, bm, bk, bn, acc_dtype=acc_dtype, out_dtype=acc_dtype,
-        fuse=False, gain=1.0, out_bits=None,
+        e, m, k, n, bm, bk, bn, acc_dtype=acc_dtype_for(x_codes.dtype),
+        epilogue="raw", gain=1.0, out_bits=None,
         interpret=interpret, shared_x=ex == 1 and e > 1, unpack4=unpack4,
     )(x_codes, w_codes)
     return out[0] if squeeze else out
@@ -396,8 +424,8 @@ def tdvmm_fused_kernel(
     per-expert windows, one per tile on grid axis 0 — or a full per-column
     ``window`` vector), and the per-row x per-channel rescale all run on the
     finished accumulator tile in VMEM; each output tile is written to HBM
-    exactly once.  Data-calibrated windows need a cross-tile max and use
-    ``tdvmm_calibrated_kernel`` instead.
+    exactly once.  Data-calibrated windows need a cross-tile max: take it
+    from ``tdvmm_absmax_kernel`` and pass it here as ``window``.
     """
     assert x_codes.ndim == 3, "fused kernel is batched; add an E=1 axis"
     if out_bits is not None and out_scale is None and window is None:
@@ -411,165 +439,52 @@ def tdvmm_fused_kernel(
                          f"for E={e} tiles")
     operands = [x_codes, w_codes, x_scale, w_scale]
     if out_bits is not None:
-        # The window (and its back-scale window/levels) enter the kernel as
-        # runtime (E, 1, N) operands, never baked literals: constant scales
-        # invite XLA strength-reduction / constant reassociation that would
-        # break bitwise parity with the unfused and per-call paths (see
+        # The window enters as runtime (E, 1, N) reciprocal + back-scale
+        # operands, never baked literals: constant scales invite XLA
+        # strength-reduction / constant reassociation that would break
+        # bitwise parity with the unfused and per-call paths (see
         # ops._epilogue).
         if window is None:
             if isinstance(out_scale, tuple):
                 window = jnp.asarray(out_scale, jnp.float32).reshape(e, 1, 1)
             else:
                 window = jnp.full((e, 1, 1), out_scale, jnp.float32)
-        window = jax.lax.optimization_barrier(
-            jnp.broadcast_to(window.astype(jnp.float32), (e, 1, n)))
-        levels = float((1 << out_bits) - 1)
-        # back-scale pinned as (window * 1/levels), matching ops._epilogue.
-        operands += [window, jax.lax.optimization_barrier(
-            window * (np.float32(1.0) / np.float32(levels)))]
+        operands += readout_factors(
+            jnp.broadcast_to(window.astype(jnp.float32), (e, 1, n)), out_bits)
     return _grid_call(
         e, m, k, n, bm, bk, bn, acc_dtype=acc_dtype_for(x_codes.dtype),
-        out_dtype=jnp.float32, fuse=True, gain=gain, out_bits=out_bits,
+        epilogue="fused", gain=gain, out_bits=out_bits,
         interpret=interpret, shared_x=ex == 1 and e > 1, unpack4=unpack4,
     )(*operands)
 
 
-# ---------------------------------------------------------------------------
-# Fused data-calibrated readout (two-phase grid)
-# ---------------------------------------------------------------------------
-def _calib_kernel(*refs, nk: int, acc_dtype, gain: float, out_bits: int,
-                  unpack4: bool, interpret: bool = False):
-    x_ref, w_ref, xs_ref, ws_ref, slot_ref, o_ref, maxs_ref, acc_ref = refs
-    p, s = pl.program_id(0), pl.program_id(4)
-    int_acc = jnp.issubdtype(acc_dtype, jnp.integer)
-    first = ((p == 0) & (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
-             & (pl.program_id(3) == 0) & (s == 0))
-
-    @pl.when(first)
-    def _init_maxs():
-        maxs_ref[...] = jnp.zeros_like(maxs_ref)
-
-    @pl.when(p == 0)
-    def _integrate():
-        @pl.when(s == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        xb, wb = x_ref[0], w_ref[0]
-        if unpack4:
-            xb = _unpack_nibbles(xb, axis=-1)
-            wb = _unpack_nibbles(wb, axis=-2)
-        acc_ref[...] += jnp.dot(xb, wb, preferred_element_type=acc_dtype)
-
-        @pl.when(s == nk - 1)
-        def _fold():
-            # Fold this tile's |z| max into its slot's running window (f32
-            # max is exact and order-free, so the slotwise fold equals the
-            # unfused global max bit for bit; zero-padded rows/cols
-            # contribute |0|, which can't move an abs-max) and park the raw
-            # accumulator in the output buffer — int32 survives the f32
-            # buffer via bitcast.
-            acc = acc_ref[...]
-            # int32 starts on both axes: under jax_enable_x64 a Python-int
-            # start would promote to int64 and dynamic_slice rejects the mix.
-            slot = slot_ref[0, 0].astype(jnp.int32)
-            tile_max = jnp.max(
-                jnp.abs(acc.astype(jnp.float32) * gain)).reshape(1, 1)
-            idx = (pl.dslice(slot, 1), pl.dslice(jnp.int32(0), 1))
-            pl.store(maxs_ref, idx,
-                     jnp.maximum(pl.load(maxs_ref, idx), tile_max))
-            o_ref[0] = (jax.lax.bitcast_convert_type(acc, jnp.float32)
-                        if int_acc else acc)
-
-    @pl.when((p == 1) & (s == 0))
-    def _readout():
-        # Revisit the tile with every slot window final: read the parked
-        # accumulator back and run the same epilogue expression as
-        # ops._epilogue / the fixed-window fused kernel, term for term.
-        parked = o_ref[0]
-        acc = (jax.lax.bitcast_convert_type(parked, jnp.int32)
-               if int_acc else parked)
-        z = _pin(acc.astype(jnp.float32) * gain, interpret)
-        slot = slot_ref[0, 0].astype(jnp.int32)
-        win = jnp.maximum(
-            pl.load(maxs_ref,
-                    (pl.dslice(slot, 1), pl.dslice(jnp.int32(0), 1)))[0, 0],
-            1e-9)
-        levels = float((1 << out_bits) - 1)
-        inv = _pin(jnp.float32(1.0) / win, interpret)
-        z = jnp.round(jnp.clip(z * inv, -1.0, 1.0) * levels)
-        back = _pin(win * (np.float32(1.0) / np.float32(levels)), interpret)
-        o_ref[0] = (_pin(z * xs_ref[0], interpret)
-                    * _pin(ws_ref[0] * back, interpret))
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "gain", "out_bits", "nslots", "bm", "bk", "bn", "interpret", "unpack4"))
-def tdvmm_calibrated_kernel(
+    "gain", "bm", "bk", "bn", "interpret", "unpack4"))
+def tdvmm_absmax_kernel(
     x_codes: jax.Array,      # (E|1, M, K) signed time codes (int8 or f32)
     w_codes: jax.Array,      # (E, K, N) signed weight codes
-    x_scale: jax.Array,      # (E|1, M, 1) f32 per-row input scales
-    w_scale: jax.Array,      # (E, 1, N) f32 per-channel weight scales
-    slots: jax.Array,        # (E, N // bn) int32 readout-slot id per column
-    #                          block: expert id for batched launches, group
-    #                          member id for ragged grouped launches
     gain: float = 1.0,
-    out_bits: int = 6,
-    nslots: int = 1,
     bm: int = BM,
     bk: int = BK,
     bn: int = BN,
     interpret: bool = False,
     unpack4: bool = False,
 ) -> jax.Array:
-    """Integrate + *data-calibrated* readout in one launch, one HBM output.
+    """Per-tile max|acc * gain|: (E, M // bm, N // bn) f32.
 
-    The readout window is max|z| over each slot (per expert tile, or per
-    ragged group member's column span) — a cross-tile reduction no single
-    grid step sees.  Grid axis 0 runs the launch twice, fully sequential:
-    pass 0 integrates and reduces per-slot maxes into VMEM scratch, pass 1
-    applies the windowed readout + rescale in place.  Bit-for-bit identical
-    to the unfused jnp epilogue (ops._epilogue with out_scale=None).
+    The first half of a data-calibrated readout: the window is a max over a
+    whole slot (an expert tile, or a ragged group member's column span),
+    which no single grid step sees.  Each tile writes only its own maximum,
+    so no (M, N) array reaches HBM here; the caller reduces the tiles of
+    each slot and runs ``tdvmm_fused_kernel`` with the resulting windows.
     """
-    assert x_codes.ndim == 3, "calibrated kernel is batched; add an E=1 axis"
+    assert x_codes.ndim == 3, "absmax kernel is batched; add an E=1 axis"
     ex, m, k = x_codes.shape
     e, _, n = w_codes.shape
     assert ex == e or ex == 1, (x_codes.shape, w_codes.shape)
-    bm_, bk_, bn_ = min(bm, m), min(bk, k), min(bn, n)
-    assert m % bm_ == 0 and k % bk_ == 0 and n % bn_ == 0
-    assert slots.shape == (e, n // bn_), (slots.shape, (e, n // bn_))
-    nk = k // bk_
-    acc_dtype = acc_dtype_for(x_codes.dtype)
-    shared_x = ex == 1 and e > 1
-    xb = (lambda b: 0) if shared_x else (lambda b: b)
-    # Phase 1 pins the x/w fetches to block 0 (idx * (1 - p)): the operands
-    # are not re-read, so the revisit costs no HBM code traffic.
-    in_specs = [
-        pl.BlockSpec((1, bm_, bk_),
-                     lambda p, b, i, j, s: (xb(b) * (1 - p), i * (1 - p),
-                                            s * (1 - p))),
-        pl.BlockSpec((1, bk_, bn_),
-                     lambda p, b, i, j, s: (b * (1 - p), s * (1 - p),
-                                            j * (1 - p))),
-        pl.BlockSpec((1, bm_, 1), lambda p, b, i, j, s: (xb(b), i, 0)),
-        pl.BlockSpec((1, 1, bn_), lambda p, b, i, j, s: (b, 0, j)),
-        pl.BlockSpec((1, 1), lambda p, b, i, j, s: (b, j),
-                     memory_space=pltpu.SMEM),
-    ]
-    return pl.pallas_call(
-        functools.partial(
-            _calib_kernel, nk=nk, acc_dtype=acc_dtype, gain=gain,
-            out_bits=out_bits, unpack4=unpack4, interpret=interpret),
-        grid=(2, e, m // bm_, n // bn_, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bm_, bn_),
-                               lambda p, b, i, j, s: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((nslots, 1), jnp.float32),
-                        pltpu.VMEM((bm_, bn_), acc_dtype)],
-        # Every dim sequential: the per-slot max scratch carries across
-        # tiles, and phase 1 must run strictly after phase 0.
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",) * 5),
-        interpret=interpret,
-    )(x_codes, w_codes, x_scale, w_scale, slots)
+    out = _grid_call(
+        e, m, k, n, bm, bk, bn, acc_dtype=acc_dtype_for(x_codes.dtype),
+        epilogue="absmax", gain=gain, out_bits=None,
+        interpret=interpret, shared_x=ex == 1 and e > 1, unpack4=unpack4,
+    )(x_codes, w_codes)
+    return out[:, ::8, ::LANE]

@@ -7,18 +7,27 @@ dry-run forces 512 host devices via XLA_FLAGS before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    arrays with sharding constraints and shard_map, and leaves the rest to
+    GSPMD.  ``make_mesh``'s own default (``Explicit``) types every array by
+    its sharding and refuses the embedding gather."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds a leading 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
-    """Small mesh for unit tests (run under forced host device count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """(data, model) mesh over the first data * model visible devices."""
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh(spec: str):

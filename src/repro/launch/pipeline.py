@@ -7,10 +7,9 @@ contiguous half of the layer stack; microbatches stream through, and the
 stage boundary is one collective_permute hop per microbatch — the only
 cross-pod traffic (cheap on data-center interconnect vs FSDP gathers).
 
-Implementation: `launch.compat.shard_map` with `axis_names={'pod'}` — the pod
-axis is manual (explicit permutes), while `data`/`model` stay AUTO on new jax,
-so the FSDP+TP sharding of each stage's layers is still GSPMD's job inside the
-stage.  (jax 0.4.x runs the stage body fully manual instead — see compat.py.)
+Implementation: `jax.shard_map` with `axis_names={'pod'}` — the pod axis is
+manual (explicit permutes), while `data`/`model` stay AUTO, so the FSDP+TP
+sharding of each stage's layers is still GSPMD's job inside the stage.
 
 Layer stacks are (n_layers, ...) pytrees; we reshape to (n_stages,
 layers_per_stage, ...) and shard dim 0 over `pod`.  Every pod executes the
@@ -24,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.launch import compat
 from repro.models import common, transformer
 
 
@@ -95,7 +93,7 @@ def pp_forward(params, batch_tokens, cfg: ModelConfig, mesh, n_micro: int = 8):
     x_mb = x.reshape(n_micro, b // n_micro, s, d)
 
     staged_specs = jax.tree.map(lambda _: P("pod"), staged)
-    outs = compat.shard_map(
+    outs = jax.shard_map(
         pipelined, mesh=mesh,
         in_specs=(staged_specs, P(), P("pod")),
         out_specs=P(),

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, smoke as smoke_cfg
 from repro.launch import meshctx, sharding
 from repro.launch.mesh import axis_info
+from repro.launch.xla_setup import honor_bf16_rounding, use_persistent_cache
 from repro.models import model
 
 
@@ -449,8 +450,8 @@ def main():
                     help="enable analog TD-VMM at the plan sites matching "
                          "PATTERN (e.g. 'ffn.*'); stock arch configs ship "
                          "all-digital, so clip_rate series and per-site "
-                         "attribution need this (jnp backend: bit-exact "
-                         "with pallas, no interpret-mode slowdown on CPU)")
+                         "attribution need this (backend auto: the Pallas "
+                         "kernel on a TPU, jnp elsewhere)")
     ap.add_argument("--trace-out", default=None,
                     help="engine path: write a Chrome-trace/Perfetto JSON "
                          "of the whole request lifecycle here (spans ride "
@@ -459,13 +460,15 @@ def main():
     ap.add_argument("--report-json", default=None,
                     help="engine path: write the full EngineReport here")
     args = ap.parse_args()
+    honor_bf16_rounding()
+    use_persistent_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_cfg(cfg)
     if args.tdvmm:
         from repro.configs import TDVMMPlan, tdvmm_rule
         cfg = cfg.replace(tdvmm_plan=TDVMMPlan(rules=(
-            tdvmm_rule(args.tdvmm, enabled=True, backend="jnp"),)))
+            tdvmm_rule(args.tdvmm, enabled=True),)))
     if args.kv_int8:
         from repro.models import attention
         attention.set_kv_cache_int8(True)

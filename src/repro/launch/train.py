@@ -24,6 +24,7 @@ from repro.configs import SHAPES, OptimizerConfig, RunConfig, get_config, smoke
 from repro.data.pipeline import DataConfig, make_pipeline
 from repro.launch import meshctx, sharding, steps
 from repro.launch.mesh import axis_info
+from repro.launch.xla_setup import honor_bf16_rounding, use_persistent_cache
 from repro.models import model
 from repro.optim.optimizer import make_optimizer
 from repro.runtime import fault
@@ -136,6 +137,8 @@ def main():
                     help="run all linears through the TD-VMM layer (QAT)")
     ap.add_argument("--tdvmm-bits", type=int, default=6)
     args = ap.parse_args()
+    honor_bf16_rounding()
+    use_persistent_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.layers import TDVMMLayerConfig, td_grouped_matmul, td_matmul
-from repro.launch import compat
 
 
 def resolve_dtype(name: str):
@@ -171,7 +170,7 @@ def dense_tp_reduce(params, x: jax.Array, td: TDVMMLayerConfig, key=None) -> jax
         return y
 
     batch_spec = P(dp, *([None] * (x.ndim - 2)), tp)
-    y = compat.shard_map(
+    y = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(batch_spec, P(tp, dp)),
         out_specs=P(dp, *([None] * (x.ndim - 1))),

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import calibration
-from repro.launch import compat, meshctx
+from repro.launch import meshctx
 from repro.models import common
 
 
@@ -283,7 +283,7 @@ def apply(params, x: jax.Array, cfg: ModelConfig, key=None) -> tuple[jax.Array, 
     if k_routed is not None:
         in_specs += (P(),)          # noise key: replicated across the mesh
         args += (k_routed,)
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=in_specs,

@@ -277,9 +277,10 @@ def test_tdvmm_ragged_group_widths_matches_sequential():
 
 
 def test_tdvmm_fused_calibration_matches_unfused():
-    """The two-phase calibrated kernel (max|z| folded into the accumulator
-    walk, one launch, one HBM write) is bit-for-bit with the legacy two-pass
-    path and with the jnp oracle — batched experts included."""
+    """The fused data-calibrated readout (per-tile max|z| launch, then the
+    fused kernel with the per-expert windows: one (M, N) HBM write) is
+    bit-for-bit with the legacy two-pass path and with the jnp oracle —
+    batched experts included."""
     from repro.kernels.tdvmm import ops
     e, m, k, n = 2, 33, 96, 40
     kx, kw = jax.random.split(jax.random.PRNGKey(8))

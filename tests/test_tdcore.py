@@ -14,7 +14,17 @@ from repro.core import encoding as enc
 from repro.core import tdcore
 from repro.core.constants import TDVMMSpec
 
-jax.config.update("jax_enable_x64", True)
+
+@pytest.fixture(autouse=True, scope="module")
+def _x64():
+    # The closed-form identities are checked to 1e-9..1e-12, which needs
+    # f64.  Scoped to this module: set at import, the flag would leak into
+    # every other module an xdist worker imports.
+    old = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)
+    yield
+    jax.config.update("jax_enable_x64", old)
+
 
 SPEC = TDVMMSpec(bits=8)
 
