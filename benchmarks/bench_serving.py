@@ -36,10 +36,7 @@ Invariants (asserted by ``check_invariants`` in CI and ``benchmarks/run.py``):
     spans, monotonic timestamps per thread) with span boundaries matching
     the report's finish steps, and the per-site attribution table sums
     **bit-exactly** to the aggregate analog-ops / energy / fJ/Op counters
-    (the chained plan's saved inter-site I/O is explicit per site);
-  * tracing overhead (``serving_trace_overhead``): median tick latency
-    with tracing on <= 1.05x tracing off — span bookkeeping is host-side
-    and never touches the two compiled step programs.
+    (the chained plan's saved inter-site I/O is explicit per site).
 
 Wall timings route through ``benchmarks.common`` (warmup + median of
 repeats, spread recorded per row) so serving numbers carry the same
@@ -428,52 +425,6 @@ def run(n_requests: int = 10):
              "compiled_steps": r6.compiled_steps,
          })
 
-    # --- trace overhead: the span bookkeeping is pure host-side work, so
-    # the traced engine's median tick must stay within 5% of untraced.
-    # The engine is deterministic, so tick i of every replay does identical
-    # work; each replay records its per-tick latencies through the engine's
-    # own MetricsSink series (both engines carry a sink, so the comparison
-    # isolates the tracer).  Runs alternate ABBA to cancel machine drift,
-    # and the per-tick-index MIN across replays filters scheduler/GC spikes
-    # before the medians are compared — a sequential A-then-B wall-clock
-    # timing would book both noise sources as tracing cost.
-    eng_off = Engine(cfg_u, params, ecfg, calib=calib_u, sink=MetricsSink())
-    eng_on = Engine(cfg_u, params, ecfg, calib=calib_u, sink=MetricsSink(),
-                    tracer=Tracer())
-    eng_off.run(trace)
-    eng_on.run(trace)                  # warm both jit caches
-
-    def _tick_latencies(eng) -> np.ndarray:
-        eng.sink = MetricsSink()       # fresh series per replay
-        eng.run(trace)
-        return np.asarray(list(eng.sink.series["step_latency_s"].values))
-
-    pairs = 5
-    offs, ons = [], []
-    for i in range(pairs):             # ABBA: off/on order flips each pair
-        order = (eng_off, eng_on) if i % 2 == 0 else (eng_on, eng_off)
-        for eng in order:
-            (offs if eng is eng_off else ons).append(_tick_latencies(eng))
-    n_ticks = min(min(map(len, offs)), min(map(len, ons)))
-    off_best = np.min([t[:n_ticks] for t in offs], axis=0)
-    on_best = np.min([t[:n_ticks] for t in ons], axis=0)
-    tick_off = float(np.median(off_best)) * 1e6
-    tick_on = float(np.median(on_best)) * 1e6
-    overhead_ratio = tick_on / max(tick_off, 1e-9)
-    spread_on = float(np.ptp(on_best)) * 1e6
-    emit("serving_trace_overhead",
-         Timing(tick_on, pairs, spread_on),
-         f"tick {tick_on:.1f}us traced vs {tick_off:.1f}us untraced "
-         f"(x{overhead_ratio:.3f} over {n_ticks} paired ticks)",
-         data={
-             "tick_us_tracing_off": tick_off,
-             "tick_us_tracing_on": tick_on,
-             "pairs": pairs,
-             "paired_ticks": n_ticks,
-             "overhead_ratio": overhead_ratio,
-             "overhead_bound": 1.05,
-         })
-
     # --- mesh scaling: DP slot-pool linearity + per-request bit-identity.
     # Runs in a subprocess with 4 forced host devices so this process keeps
     # its single-device jax runtime (same pattern as the multidev tests).
@@ -625,10 +576,6 @@ def check_invariants(doc: dict) -> None:
     assert tr["site_sums_bit_exact"], tr             # table sums == aggregate
     assert tr["chained_io_saved_j"] > 0.0, tr        # chain savings explicit
     assert tr["compiled_steps"] == 2, tr
-    ov = rows["serving_trace_overhead"]
-    assert ov["overhead_ratio"] <= ov["overhead_bound"], ov
-    assert ov.get("pairs", 0) >= 5, ov               # ABBA replay pairs
-    assert ov.get("paired_ticks", 0) >= 20, ov       # per-tick sample depth
     assert doc.get("autotune", {}).get("platform"), doc.get("autotune")
     ms = rows["serving_mesh_scaling"]
     assert set(ms["meshes"]) == {"1x1", "2x1", "4x1"}, ms

@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.configs.base import TDVMMLayerConfig  # re-export (historic home)
 from repro.core import quant
+from repro.runtime.trace import scope
 
 __all__ = ["TDVMMLayerConfig", "td_matmul", "td_expert_matmul",
            "td_grouped_matmul", "calibrate_out_scale", "TDVMMLinear",
@@ -233,41 +234,42 @@ def td_matmul(
             return jnp.dot(x, w, preferred_element_type=pet)
         return x @ w
 
-    noisy = cfg.noise and key is not None
+    with scope("tdvmm"):
+        noisy = cfg.noise and key is not None
 
-    # ---- plan: shapes + code storage + backend/blocks ----
-    plan = plan_matmul(x.shape, w.shape, cfg, noisy=noisy)
+        # ---- plan: shapes + code storage + backend/blocks ----
+        plan = plan_matmul(x.shape, w.shape, cfg, noisy=noisy)
 
-    # ---- encode inputs / program weights (core/quant.py stages) ----
-    qx = quant.encode_input(x, cfg.bits)
-    qw = quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
-    if noisy:
-        qw = quant.program_noise(qw, cfg.spec, key)
+        # ---- encode inputs / program weights (core/quant.py stages) ----
+        qx = quant.encode_input(x, cfg.bits)
+        qw = quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
+        if noisy:
+            qw = quant.program_noise(qw, cfg.spec, key)
 
-    # ---- integrate + readout + rescale (kernel epilogue) ----
-    from repro.kernels.tdvmm import ops
-    gain = _latch_gain(qx.levels, qw.levels, plan.k)
-    # Digital rescale: per-row input range and per-channel 2*N_in*w_max.
-    w_scale = jnp.broadcast_to(
-        qw.scale.reshape(-1) * (2.0 * plan.k), (plan.n,))
-    out_bits, out_scale = _readout_args(cfg)
-    out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
-    _record_window(cfg, qx.view().reshape(plan.m, plan.k), qw.view(),
-                   plan.backend, plan.code_dtype, gain, per_tile=False)
-    y = ops.tdvmm_matmul(
-        qx.view().reshape(plan.m, plan.k),
-        qw.view(),
-        qx.scale.reshape(plan.m),
-        w_scale,
-        gain=gain,
-        out_bits=out_bits,
-        out_scale=out_scale,
-        backend=plan.backend,
-        code_dtype=plan.code_dtype,
-        block_sizes=plan.blocks,
-        out_window=out_window,
-    )
-    return y.reshape(plan.batch_shape + (plan.n,)).astype(x.dtype)
+        # ---- integrate + readout + rescale (kernel epilogue) ----
+        from repro.kernels.tdvmm import ops
+        gain = _latch_gain(qx.levels, qw.levels, plan.k)
+        # Digital rescale: per-row input range and per-channel 2*N_in*w_max.
+        w_scale = jnp.broadcast_to(
+            qw.scale.reshape(-1) * (2.0 * plan.k), (plan.n,))
+        out_bits, out_scale = _readout_args(cfg)
+        out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
+        _record_window(cfg, qx.view().reshape(plan.m, plan.k), qw.view(),
+                       plan.backend, plan.code_dtype, gain, per_tile=False)
+        y = ops.tdvmm_matmul(
+            qx.view().reshape(plan.m, plan.k),
+            qw.view(),
+            qx.scale.reshape(plan.m),
+            w_scale,
+            gain=gain,
+            out_bits=out_bits,
+            out_scale=out_scale,
+            backend=plan.backend,
+            code_dtype=plan.code_dtype,
+            block_sizes=plan.blocks,
+            out_window=out_window,
+        )
+        return y.reshape(plan.batch_shape + (plan.n,)).astype(x.dtype)
 
 
 def td_expert_matmul(
@@ -290,44 +292,45 @@ def td_expert_matmul(
         kw = {"preferred_element_type": pet} if pet is not None else {}
         return jnp.einsum("eck,ekn->ecn", x, w, **kw)
 
-    e, c, k = x.shape
-    e2, k2, n = w.shape
-    assert e == e2 and k == k2, (x.shape, w.shape)
-    noisy = cfg.noise and key is not None
-    code_dtype = _plan_code_dtype(cfg, k, noisy)
-    from repro.kernels.tdvmm import ops
-    kp = ops.plan_kernel(cfg.backend, c, k, n, code_dtype)
+    with scope("tdvmm"):
+        e, c, k = x.shape
+        e2, k2, n = w.shape
+        assert e == e2 and k == k2, (x.shape, w.shape)
+        noisy = cfg.noise and key is not None
+        code_dtype = _plan_code_dtype(cfg, k, noisy)
+        from repro.kernels.tdvmm import ops
+        kp = ops.plan_kernel(cfg.backend, c, k, n, code_dtype)
 
-    qx = quant.encode_input(x, cfg.bits)                       # scale (E, C, 1)
-    qw = quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
-    if noisy:
-        qw = quant.program_noise(qw, cfg.spec, key)
+        qx = quant.encode_input(x, cfg.bits)                       # scale (E, C, 1)
+        qw = quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
+        if noisy:
+            qw = quant.program_noise(qw, cfg.spec, key)
 
-    gain = _latch_gain(qx.levels, qw.levels, k)
-    # qw.scale is (E, 1, N) per-channel or (E, 1, 1) per-tensor; the explicit
-    # last dim (not -1) keeps E=0 expert stacks reshapeable.
-    w_scale = jnp.broadcast_to(
-        qw.scale.reshape(e, qw.scale.shape[-1]) * (2.0 * k), (e, n))
-    out_bits, out_scale = _readout_args(cfg, n_experts=e)
-    out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
-    # Per-expert windows: each expert is its own analog tile, so the
-    # recorded vector is the (E,) per-tile max the epilogue calibrates.
-    _record_window(cfg, qx.view(), qw.view(), kp.backend, code_dtype, gain,
-                   per_tile=True)
-    y = ops.tdvmm_matmul(
-        qx.view(),
-        qw.view(),
-        qx.scale.reshape(e, c),
-        w_scale,
-        gain=gain,
-        out_bits=out_bits,
-        out_scale=out_scale,
-        backend=kp.backend,
-        code_dtype=code_dtype,
-        block_sizes=kp.blocks,
-        out_window=out_window,
-    )
-    return y.astype(x.dtype)
+        gain = _latch_gain(qx.levels, qw.levels, k)
+        # qw.scale is (E, 1, N) per-channel or (E, 1, 1) per-tensor; the explicit
+        # last dim (not -1) keeps E=0 expert stacks reshapeable.
+        w_scale = jnp.broadcast_to(
+            qw.scale.reshape(e, qw.scale.shape[-1]) * (2.0 * k), (e, n))
+        out_bits, out_scale = _readout_args(cfg, n_experts=e)
+        out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
+        # Per-expert windows: each expert is its own analog tile, so the
+        # recorded vector is the (E,) per-tile max the epilogue calibrates.
+        _record_window(cfg, qx.view(), qw.view(), kp.backend, code_dtype, gain,
+                       per_tile=True)
+        y = ops.tdvmm_matmul(
+            qx.view(),
+            qw.view(),
+            qx.scale.reshape(e, c),
+            w_scale,
+            gain=gain,
+            out_bits=out_bits,
+            out_scale=out_scale,
+            backend=kp.backend,
+            code_dtype=code_dtype,
+            block_sizes=kp.blocks,
+            out_window=out_window,
+        )
+        return y.astype(x.dtype)
 
 
 def td_grouped_matmul(
@@ -365,62 +368,63 @@ def td_grouped_matmul(
         kw = {"preferred_element_type": pet} if pet is not None else {}
         return tuple(jnp.dot(x, w, **kw) for w in ws)
 
-    k = x.shape[-1]
-    ns = tuple(w.shape[-1] for w in ws)
-    for w in ws:
-        assert w.ndim == 2 and w.shape[0] == k, (x.shape, w.shape)
-    batch_shape = tuple(x.shape[:-1])
-    m = 1
-    for d in batch_shape:
-        m *= d
-    noisy = cfg.noise and key is not None
-    code_dtype = _plan_code_dtype(cfg, k, noisy)
-    from repro.kernels.tdvmm import ops, tdvmm
-    # Per-member column spans: each member rounds to the 128 lane only.
-    widths = tuple(
-        tdvmm.padded_size(n, tdvmm.LANE, tdvmm.LANE) for n in ns)
-    n_total = sum(widths)
-    kp = ops.plan_kernel(cfg.backend, m, k, n_total, code_dtype)
-    # No N block may span two members' readout windows: shrink block_n to
-    # the gcd of the plan's choice and every member span (all multiples of
-    # the 128 lane, so the gcd stays lane-aligned).
-    bn_g = math.gcd(kp.bn, *widths)
+    with scope("tdvmm"):
+        k = x.shape[-1]
+        ns = tuple(w.shape[-1] for w in ws)
+        for w in ws:
+            assert w.ndim == 2 and w.shape[0] == k, (x.shape, w.shape)
+        batch_shape = tuple(x.shape[:-1])
+        m = 1
+        for d in batch_shape:
+            m *= d
+        noisy = cfg.noise and key is not None
+        code_dtype = _plan_code_dtype(cfg, k, noisy)
+        from repro.kernels.tdvmm import ops, tdvmm
+        # Per-member column spans: each member rounds to the 128 lane only.
+        widths = tuple(
+            tdvmm.padded_size(n, tdvmm.LANE, tdvmm.LANE) for n in ns)
+        n_total = sum(widths)
+        kp = ops.plan_kernel(cfg.backend, m, k, n_total, code_dtype)
+        # No N block may span two members' readout windows: shrink block_n to
+        # the gcd of the plan's choice and every member span (all multiples of
+        # the 128 lane, so the gcd stays lane-aligned).
+        bn_g = math.gcd(kp.bn, *widths)
 
-    qx = quant.encode_input(x, cfg.bits)                       # encode ONCE
-    qw = quant.concat_group(
-        [quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
-         for w in ws], widths)
-    if noisy:
-        qw = quant.program_noise(qw, cfg.spec, key)
+        qx = quant.encode_input(x, cfg.bits)                       # encode ONCE
+        qw = quant.concat_group(
+            [quant.program_weights(w, cfg.weight_bits, cfg.per_channel)
+             for w in ws], widths)
+        if noisy:
+            qw = quant.program_noise(qw, cfg.spec, key)
 
-    gain = _latch_gain(qx.levels, qw.levels, k)
-    w_scale = qw.scale.reshape(n_total) * (2.0 * k)
-    out_bits, out_scale = _readout_args(cfg, n_experts=len(ws))
-    out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
-    # Per-member windows: each member's column span is its own analog tile,
-    # so calibration records one (G,) vector for the site.
-    _record_window(cfg, qx.view().reshape(m, k), qw.view(), kp.backend,
-                   code_dtype, gain, per_tile=True, group_widths=widths)
-    y = ops.tdvmm_matmul(
-        qx.view().reshape(m, k),
-        qw.view(),
-        qx.scale.reshape(m),
-        w_scale,
-        gain=gain,
-        out_bits=out_bits,
-        out_scale=out_scale,
-        backend=kp.backend,
-        code_dtype=code_dtype,
-        block_sizes=(kp.bm, kp.bk, bn_g),
-        group_widths=widths,
-        out_window=out_window,
-    )                                                          # (M, n_total)
-    outs, off = [], 0
-    for n, wd in zip(ns, widths):
-        outs.append(
-            y[:, off:off + n].reshape(batch_shape + (n,)).astype(x.dtype))
-        off += wd
-    return tuple(outs)
+        gain = _latch_gain(qx.levels, qw.levels, k)
+        w_scale = qw.scale.reshape(n_total) * (2.0 * k)
+        out_bits, out_scale = _readout_args(cfg, n_experts=len(ws))
+        out_scale, out_window = _runtime_override(cfg, out_bits, out_scale)
+        # Per-member windows: each member's column span is its own analog tile,
+        # so calibration records one (G,) vector for the site.
+        _record_window(cfg, qx.view().reshape(m, k), qw.view(), kp.backend,
+                       code_dtype, gain, per_tile=True, group_widths=widths)
+        y = ops.tdvmm_matmul(
+            qx.view().reshape(m, k),
+            qw.view(),
+            qx.scale.reshape(m),
+            w_scale,
+            gain=gain,
+            out_bits=out_bits,
+            out_scale=out_scale,
+            backend=kp.backend,
+            code_dtype=code_dtype,
+            block_sizes=(kp.bm, kp.bk, bn_g),
+            group_widths=widths,
+            out_window=out_window,
+        )                                                          # (M, n_total)
+        outs, off = [], 0
+        for n, wd in zip(ns, widths):
+            outs.append(
+                y[:, off:off + n].reshape(batch_shape + (n,)).astype(x.dtype))
+            off += wd
+        return tuple(outs)
 
 
 def calibrate_out_scale(

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import encoding as enc
+from repro.runtime.trace import scope
 
 # Signed-magnitude codes span [-(2^p - 1), 2^p - 1]: int8 holds p <= 7.
 INT8_MAX_BITS = 7
@@ -205,6 +206,7 @@ def encode_input(x: jax.Array, bits: int, axis: int = -1) -> QuantizedTensor:
     return QuantizedTensor(codes=codes, scale=s, bits=bits, ste=lin)
 
 
+@scope("weight_program")
 def program_weights(
     w: jax.Array, bits: int, per_channel: bool = True
 ) -> QuantizedTensor:
@@ -227,6 +229,7 @@ def program_weights(
     return QuantizedTensor(codes=codes, scale=w_max, bits=bits, ste=lin)
 
 
+@scope("weight_program")
 def stack_group(qws: "list[QuantizedTensor] | tuple[QuantizedTensor, ...]",
                 n_to: int) -> QuantizedTensor:
     """Stack G programmed (K, N_g) weight members into one (G, K, n_to) bank.
@@ -271,6 +274,7 @@ def stack_group(qws: "list[QuantizedTensor] | tuple[QuantizedTensor, ...]",
     return QuantizedTensor(codes=codes, scale=scale, bits=bits, ste=stes)
 
 
+@scope("weight_program")
 def concat_group(qws: "list[QuantizedTensor] | tuple[QuantizedTensor, ...]",
                  widths: "tuple[int, ...]") -> QuantizedTensor:
     """Concatenate G programmed (K, N_g) members along N into one ragged bank.
@@ -318,6 +322,7 @@ def concat_group(qws: "list[QuantizedTensor] | tuple[QuantizedTensor, ...]",
     return QuantizedTensor(codes=codes, scale=scale, bits=bits, ste=stes)
 
 
+@scope("weight_program")
 def program_noise(qw: QuantizedTensor, spec, key: jax.Array) -> QuantizedTensor:
     """Stochastic DIBL + FG tuning noise on programmed current codes.
 

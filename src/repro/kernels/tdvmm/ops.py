@@ -77,6 +77,7 @@ from repro.kernels.tdvmm.tdvmm import (
     acc_dtype_for, autotune_blocks, autotune_lookup, autotune_platform,
     pad_to_blocks, readout_factors, tdvmm_absmax_kernel, tdvmm_fused_kernel,
     tdvmm_matmul_kernel)
+from repro.runtime.trace import scope
 
 
 def _on_tpu() -> bool:
@@ -339,15 +340,13 @@ def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
         # Empty expert batch / filtered serving batch / zero-width contraction:
         # zero charge everywhere, and readout(0) * scales == 0 on every path.
         return jnp.zeros((e, m, n), jnp.float32)
-    if code_dtype in ("int8", "int4"):
-        # Codes are integer-valued within the storage range by the caller's
-        # contract (p <= 7 / p <= 3); the cast is exact and XLA fuses it
-        # into the producer, so the kernel streams 1-byte codes from HBM.
-        xi = x_codes.astype(jnp.int8)
-        wi = w_codes.astype(jnp.int8)
-    else:
-        xi = x_codes.astype(jnp.float32)
-        wi = w_codes.astype(jnp.float32)
+    code = jnp.int8 if code_dtype in ("int8", "int4") else jnp.float32
+    # Integer codes lie within the storage range by the caller's contract
+    # (p <= 7 / p <= 3); the cast is exact and XLA fuses it into the
+    # producer, so the kernel streams 1-byte codes from HBM.
+    xi = x_codes.astype(code)
+    with scope("weight_program"):
+        wi = w_codes.astype(code)
     if blocks is None:
         blocks = autotune_blocks(
             m, k, n, "int4" if code_dtype == "int4" else xi.dtype)
@@ -371,7 +370,8 @@ def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
         # switches to packed units — the kernel unpacks per block.
         from repro.core.quant import pack_int4
         xi = pack_int4(xi, axis=-1)
-        wi = pack_int4(wi, axis=-2)
+        with scope("weight_program"):
+            wi = pack_int4(wi, axis=-2)
         bk = max(bk // 2, 1)
     xp, wp = pad_to_blocks(xi, wi, bm, bk, bn)
     mp, np_ = xp.shape[-2], wp.shape[-1]

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
+from repro.runtime.trace import scope
 
 
 class KVCache(NamedTuple):
@@ -270,6 +271,7 @@ def _flash(q, k, v, cfg: ModelConfig) -> jax.Array:
     return _attend_flash(q, k, v, cfg)
 
 
+@scope("attention")
 def _attend(q, k, v, mask, cfg: ModelConfig) -> jax.Array:
     """q: (B,Sq,H,D); k,v: (B,Skv,Kv,D); mask: (B,1,Sq,Skv) or broadcastable."""
     hd = q.shape[-1]
@@ -401,6 +403,23 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
+@scope("kv.write")
+def _paged_write(cache: PagedKVCache, k, v, pid, off):
+    """Write one K/V row per (page ``pid``, offset ``off``) pair: k, v
+    (R, n_kv, head_dim), quantized under KV_CACHE_INT8.  Returns the new
+    (k, v, k_scale, v_scale) pools (scales None when not quantized)."""
+    def write(buf, val):
+        return buf.at[pid, off].set(val.astype(buf.dtype))
+
+    if cache.k_scale is None:
+        return write(cache.k, k), write(cache.v, v), None, None
+    k_q, k_s = _kv_quantize(k)
+    v_q, v_s = _kv_quantize(v)
+    return (write(cache.k, k_q), write(cache.v, v_q),
+            write(cache.k_scale, k_s), write(cache.v_scale, v_s))
+
+
+@scope("kv.read")
 def _paged_read(cache: PagedKVCache, k_buf, v_buf, k_sc, v_sc, tables, dtype):
     """Gather a slot's pages into position order.  tables: (..., P) page ids
     -> k/v (..., P*page_size, n_kv, head_dim) in the compute dtype."""
@@ -451,21 +470,7 @@ def apply_prefill_paged(params, x: jax.Array, cfg: ModelConfig,
     pid = jnp.where(in_chunk, pid, trash)                    # (C,)
     off = gpos % ps
 
-    def write(buf, val):                                     # val: (C, ...)
-        return buf.at[pid, off].set(val.astype(buf.dtype))
-
-    k_sc = v_sc = None
-    if cache.k_scale is not None:
-        k_q, k_s1 = _kv_quantize(k)
-        v_q, v_s1 = _kv_quantize(v)
-        new_k = write(cache.k, k_q[0])
-        new_v = write(cache.v, v_q[0])
-        k_sc = write(cache.k_scale, k_s1[0])
-        v_sc = write(cache.v_scale, v_s1[0])
-    else:
-        new_k = write(cache.k, k[0])
-        new_v = write(cache.v, v[0])
-
+    new_k, new_v, k_sc, v_sc = _paged_write(cache, k[0], v[0], pid, off)
     k_read, v_read = _paged_read(cache, new_k, new_v, k_sc, v_sc,
                                  ctx.block_row[None], q.dtype)
     kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
@@ -504,21 +509,8 @@ def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
     pid = jnp.where(ctx.active, pid, trash)                  # (B,)
     off = pos % ps
 
-    def write(buf, val):                                     # val: (B, ...)
-        return buf.at[pid, off].set(val.astype(buf.dtype))
-
-    k_sc = v_sc = None
-    if cache.k_scale is not None:
-        k_q, k_s1 = _kv_quantize(k)
-        v_q, v_s1 = _kv_quantize(v)
-        new_k = write(cache.k, k_q[:, 0])
-        new_v = write(cache.v, v_q[:, 0])
-        k_sc = write(cache.k_scale, k_s1[:, 0])
-        v_sc = write(cache.v_scale, v_s1[:, 0])
-    else:
-        new_k = write(cache.k, k[:, 0])
-        new_v = write(cache.v, v[:, 0])
-
+    new_k, new_v, k_sc, v_sc = _paged_write(cache, k[:, 0], v[:, 0], pid,
+                                            off)
     k_read, v_read = _paged_read(cache, new_k, new_v, k_sc, v_sc,
                                  ctx.block_tables, q.dtype)
     kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)

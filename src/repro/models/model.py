@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention, common, ssm, transformer
+from repro.runtime.trace import scope
 
 
 def init_params(key, cfg: ModelConfig) -> dict:
@@ -193,9 +194,10 @@ def prefill_chunk(params, batch: dict, caches: dict, cfg: ModelConfig,
                                              embed0=x, page_ctx=ctx)
         # logits only at the chunk's last real token (== prefill_step's
         # x[:, -1:] on the final chunk); padded rows never reach the head.
-        x = jax.lax.dynamic_slice_in_dim(x, ctx.valid - 1, 1, axis=1)
-        x = common.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        return _head(params, x, cfg), new_caches
+        with scope("head"):
+            x = jax.lax.dynamic_slice_in_dim(x, ctx.valid - 1, 1, axis=1)
+            x = common.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+            return _head(params, x, cfg), new_caches
 
 
 def decode_slots(params, batch: dict, caches: dict, cfg: ModelConfig,
@@ -216,8 +218,9 @@ def decode_slots(params, batch: dict, caches: dict, cfg: ModelConfig,
         x, new_caches, _ = transformer.apply(params["blocks"], x, cfg,
                                              "decode_paged", caches, None,
                                              embed0=x, page_ctx=ctx)
-        x = common.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        return _head(params, x, cfg), new_caches
+        with scope("head"):
+            x = common.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+            return _head(params, x, cfg), new_caches
 
 
 def calibrate(params, batch: dict, cfg: ModelConfig, max_len: int = 0):

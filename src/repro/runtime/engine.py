@@ -118,6 +118,7 @@ from repro.launch.mesh import axis_info
 from repro.models import model
 from repro.runtime import fault
 from repro.runtime import sla as sla_policy
+from repro.runtime import trace
 from repro.runtime.paged_cache import PagePool, pages_for
 from repro.runtime.scheduler import (Request, RequestRecord, Slot,
                                      SlotScheduler, static_baseline)
@@ -278,6 +279,8 @@ class RunState:
     last_clip_obs: int = 0
     wall_s: float = 0.0
     util_samples: list = dataclasses.field(default_factory=list)
+    kv_pages_read: int = 0        # pages the steps' KV gathers touched
+    kv_pages_live: int = 0        # of those, pages holding a written position
     drift_events: list = dataclasses.field(default_factory=list)
     preempted: bool = False
     snapshot_path: Optional[str] = None
@@ -375,12 +378,16 @@ class Engine:
             # keeps compiled_steps == 2: a drifting output layout would make
             # the next call's donated input a new signature.
             jit_kw["out_shardings"] = (None, self._cache_sh)
-        self._prefill = jax.jit(
-            lambda p, b, c, w: model.prefill_chunk(p, b, c, cfg, windows=w),
-            donate_argnums=(2,), **jit_kw)
-        self._decode = jax.jit(
-            lambda p, b, c, w: model.decode_slots(p, b, c, cfg, windows=w),
-            donate_argnums=(2,), **jit_kw)
+        # Named so that a profile's program line reads jit_engine_prefill /
+        # jit_engine_decode.
+        def engine_prefill(p, b, c, w):
+            return model.prefill_chunk(p, b, c, cfg, windows=w)
+
+        def engine_decode(p, b, c, w):
+            return model.decode_slots(p, b, c, cfg, windows=w)
+
+        self._prefill = jax.jit(engine_prefill, donate_argnums=(2,), **jit_kw)
+        self._decode = jax.jit(engine_decode, donate_argnums=(2,), **jit_kw)
 
         self._st: Optional[RunState] = None
         self._fault: Optional[FaultConfig] = None
@@ -592,7 +599,8 @@ class Engine:
             for req in st.sched.pending:     # open `queued` spans (idempotent)
                 if req.arrival_step <= st.steps:
                     self.tracer.note_arrival(req.rid, st.steps)
-        self._admit()
+        with trace.span("engine.admit"):
+            self._admit()
         occupied = st.sched.occupied()
         prefilling = [s for s in occupied if s.prefilling]
         decoding = [s for s in occupied if not s.prefilling]
@@ -755,20 +763,23 @@ class Engine:
         prompt = slot.record.request.prompt
         start = slot.prefill_done
         n = min(ecfg.chunk, len(prompt) - start)
-        tokens = np.zeros((1, ecfg.chunk), np.int32)
-        tokens[0, :n] = prompt[start:start + n]
-        row = np.full((ecfg.resolved_max_pages,), st.pool.trash_page,
-                      np.int32)
-        row[:len(slot.pages)] = slot.pages
-        batch = {"inputs": jnp.asarray(tokens),
-                 "block_row": jnp.asarray(row),
-                 "offset": jnp.int32(start), "valid": jnp.int32(n)}
-        if self.mesh is not None:
-            batch = jax.device_put(batch, self._batch_sh["prefill"])
+        last = start + n == len(prompt)
+        with trace.span("engine.assemble"):
+            tokens = np.zeros((1, ecfg.chunk), np.int32)
+            tokens[0, :n] = prompt[start:start + n]
+            row = np.full((ecfg.resolved_max_pages,), st.pool.trash_page,
+                          np.int32)
+            row[:len(slot.pages)] = slot.pages
+            batch = {"inputs": jnp.asarray(tokens),
+                     "block_row": jnp.asarray(row),
+                     "offset": jnp.int32(start), "valid": jnp.int32(n)}
+            if self.mesh is not None:
+                batch = jax.device_put(batch, self._batch_sh["prefill"])
         try:
-            logits, caches = self._run_compiled(
-                "prefill", self._prefill, self.params, batch, st.caches,
-                self._windows)
+            with trace.span("engine.dispatch"):
+                logits, caches = self._run_compiled(
+                    "prefill", self._prefill, self.params, batch, st.caches,
+                    self._windows)
         except RuntimeError as e:
             # Persistent step failure: this slot IS the step's work — finish
             # it as failed (graceful degradation) and re-plan next tick.
@@ -776,22 +787,28 @@ class Engine:
             self._finish(slot, "failed")
             return
         st.caches = caches
-        st.prefill_steps += 1
-        slot.prefill_done += n
-        slot.pos += n
-        st.prompt_tokens += n
-        self._account(slot.record, n)
-        if self.tracer is not None:
-            self.tracer.mark_chunk(
-                slot.record.request.rid, start // ecfg.chunk, n,
-                done=not slot.prefilling, step=st.steps)
-        if not slot.prefilling:
-            row_logits = logits[0, 0]
-            tok = int(jnp.argmax(row_logits[:vocab]))
-            st.nan_steps += int(bool(jnp.isnan(row_logits).any()))
-            st.generated_tokens += 1
-            self._account(slot.record, 1)
-            self._emit(slot, tok)
+        if last:
+            with trace.span("engine.readback"):
+                row_logits = logits[0, 0]
+                tok = int(jnp.argmax(row_logits[:vocab]))
+                nan = int(bool(jnp.isnan(row_logits).any()))
+        with trace.span("engine.emit"):
+            st.prefill_steps += 1
+            slot.prefill_done += n
+            slot.pos += n
+            st.prompt_tokens += n
+            st.kv_pages_read += ecfg.resolved_max_pages
+            st.kv_pages_live += pages_for(slot.pos, ecfg.page_size)
+            self._account(slot.record, n)
+            if self.tracer is not None:
+                self.tracer.mark_chunk(
+                    slot.record.request.rid, start // ecfg.chunk, n,
+                    done=last, step=st.steps)
+            if last:
+                st.nan_steps += nan
+                st.generated_tokens += 1
+                self._account(slot.record, 1)
+                self._emit(slot, tok)
         st.steps += 1
 
     def _decode_tick(self, decoding: list[Slot]) -> None:
@@ -800,39 +817,41 @@ class Engine:
         ecfg = self.ecfg
         ps, cap_pages = ecfg.page_size, ecfg.resolved_max_pages
         vocab = self.cfg.vocab_size
-        # --- evict-before-poison: secure every slot's write page ----------
-        runnable = []
-        for slot in decoding:
-            if slot.pos >= len(slot.pages) * ps:
-                if len(slot.pages) >= cap_pages or \
-                        (new := st.pool.alloc(
-                            1, rank=slot.sid // ecfg.slots)) is None:
-                    self._finish(slot, "evicted")
-                    continue
-                slot.pages.extend(new)
-            runnable.append(slot)
-        if not runnable:
-            return                # state changed (evictions); re-plan
-        b = self.total_slots
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        tables = np.full((b, cap_pages), st.pool.trash_page, np.int32)
-        active = np.zeros((b,), bool)
-        for slot in runnable:
-            tokens[slot.sid, 0] = slot.cur_token
-            pos[slot.sid] = slot.pos
-            tables[slot.sid, :len(slot.pages)] = slot.pages
-            active[slot.sid] = True
-        batch = {"inputs": jnp.asarray(tokens),
-                 "block_tables": jnp.asarray(tables),
-                 "pos": jnp.asarray(pos),
-                 "active": jnp.asarray(active)}
-        if self.mesh is not None:
-            batch = jax.device_put(batch, self._batch_sh["decode"])
+        with trace.span("engine.assemble"):
+            # --- evict-before-poison: secure every slot's write page ------
+            runnable = []
+            for slot in decoding:
+                if slot.pos >= len(slot.pages) * ps:
+                    if len(slot.pages) >= cap_pages or \
+                            (new := st.pool.alloc(
+                                1, rank=slot.sid // ecfg.slots)) is None:
+                        self._finish(slot, "evicted")
+                        continue
+                    slot.pages.extend(new)
+                runnable.append(slot)
+            if not runnable:
+                return            # state changed (evictions); re-plan
+            b = self.total_slots
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            tables = np.full((b, cap_pages), st.pool.trash_page, np.int32)
+            active = np.zeros((b,), bool)
+            for slot in runnable:
+                tokens[slot.sid, 0] = slot.cur_token
+                pos[slot.sid] = slot.pos
+                tables[slot.sid, :len(slot.pages)] = slot.pages
+                active[slot.sid] = True
+            batch = {"inputs": jnp.asarray(tokens),
+                     "block_tables": jnp.asarray(tables),
+                     "pos": jnp.asarray(pos),
+                     "active": jnp.asarray(active)}
+            if self.mesh is not None:
+                batch = jax.device_put(batch, self._batch_sh["decode"])
         try:
-            logits, caches = self._run_compiled(
-                "decode", self._decode, self.params, batch, st.caches,
-                self._windows)
+            with trace.span("engine.dispatch"):
+                logits, caches = self._run_compiled(
+                    "decode", self._decode, self.params, batch, st.caches,
+                    self._windows)
         except RuntimeError as e:
             # Persistent step failure: blame the attributed request (or the
             # oldest runnable slot), finish it failed, re-plan next tick.
@@ -846,19 +865,23 @@ class Engine:
             self._finish(culprit, "failed")
             return
         st.caches = caches
-        st.decode_steps += 1
-        if self.tracer is not None:
-            self.tracer.mark_decode(
-                [s.record.request.rid for s in runnable], st.steps)
-        st.util_samples.append(len(runnable) / b)
-        toks = np.asarray(jnp.argmax(logits[:, 0, :vocab], axis=-1))
-        nans = np.asarray(jnp.isnan(logits[:, 0]).any(axis=-1))
-        for slot in runnable:              # admission order
-            st.nan_steps += int(nans[slot.sid])
-            slot.pos += 1
-            st.generated_tokens += 1
-            self._account(slot.record, 1)
-            self._emit(slot, int(toks[slot.sid]))
+        with trace.span("engine.readback"):
+            toks = np.asarray(jnp.argmax(logits[:, 0, :vocab], axis=-1))
+            nans = np.asarray(jnp.isnan(logits[:, 0]).any(axis=-1))
+        with trace.span("engine.emit"):
+            st.decode_steps += 1
+            if self.tracer is not None:
+                self.tracer.mark_decode(
+                    [s.record.request.rid for s in runnable], st.steps)
+            st.util_samples.append(len(runnable) / b)
+            st.kv_pages_read += b * cap_pages
+            for slot in runnable:              # admission order
+                st.nan_steps += int(nans[slot.sid])
+                slot.pos += 1
+                st.kv_pages_live += pages_for(slot.pos, ps)
+                st.generated_tokens += 1
+                self._account(slot.record, 1)
+                self._emit(slot, int(toks[slot.sid]))
         st.steps += 1
 
     # ------------------------------------------------------------------
@@ -989,6 +1012,8 @@ class Engine:
                 "last_clip_obs": st.last_clip_obs,
                 "wall_s": st.wall_s,
                 "util_samples": [float(u) for u in st.util_samples],
+                "kv_pages_read": st.kv_pages_read,
+                "kv_pages_live": st.kv_pages_live,
                 "drift_events": st.drift_events,
             },
         }
@@ -1167,6 +1192,8 @@ class Engine:
             last_drift_check=c["last_drift_check"],
             last_clip_obs=c.get("last_clip_obs", 0), wall_s=c["wall_s"],
             util_samples=list(c["util_samples"]),
+            kv_pages_read=c.get("kv_pages_read", 0),
+            kv_pages_live=c.get("kv_pages_live", 0),
             drift_events=list(c["drift_events"]),
         )
 

@@ -412,3 +412,130 @@ def test_trace_report_script_renders_markdown(traced, tmp_path):
     for r in rep.requests:
         assert f"| {r['rid']} | {r['finish_reason']} " \
                f"| {r['finished_step']} |" in md
+
+
+# ==========================================================================
+# Profiler attribution: engine spans, step-program scopes, KV-page counters
+# ==========================================================================
+def _ticks_profiled(eng, n, logdir):
+    """Run ``n`` engine ticks under the JAX profiler; returns the host
+    events of the thread that ran them, as (name, start_ns, end_ns)."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    for _ in range(n):
+        eng.tick()
+    jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(logdir.rglob("*.xplane.pb"))))
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.end_ns) for e in line.events]
+            if any(name == "engine.admit" for name, _, _ in evs):
+                return evs
+    raise AssertionError("no engine spans in the profile")
+
+
+def test_engine_spans_in_tick_order_nested_under_nothing(served, tmp_path):
+    from repro.runtime.trace import ENGINE_SPANS
+    cfg, params, calib, _ = served
+    # One-chunk prompts: every tick (prefill or decode) reads back a token.
+    ecfg = EngineConfig(slots=2, page_size=4, num_pages=16, chunk=8)
+    reqs = [Request(rid=i, prompt=tuple(range(1, 6 + i)), max_new_tokens=4)
+            for i in range(2)]
+    eng = Engine(cfg, params, ecfg, calib=calib)
+    eng.start(reqs)
+    eng.tick()                                   # compile the prefill step
+    evs = _ticks_profiled(eng, 4, tmp_path)      # prefill, then decodes
+    spans = sorted((e for e in evs if e[0] in ENGINE_SPANS), key=lambda e: e[1])
+    assert [n for n, _, _ in spans] == list(ENGINE_SPANS) * 4
+    for name, s, e in spans:
+        outer = [o for o in evs if o[0] != name and o[1] <= s and o[2] >= e
+                 and (o[1], o[2]) != (s, e)]
+        assert not outer, (name, outer)
+    while eng.tick():
+        pass
+    _same_streams(Engine(cfg, params, ecfg, calib=calib).run(reqs), eng.report())
+    assert eng.compiled_steps() == 2
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_step_programs_named_and_scoped(served, kind):
+    import re
+    from repro.runtime.trace import STEP_SCOPES
+    cfg, params, calib, _ = served
+    ecfg = EngineConfig(slots=2, page_size=4, num_pages=16, chunk=4)
+    eng = Engine(cfg, params, ecfg, calib=calib)
+    eng.start([])
+    sd = jax.ShapeDtypeStruct
+    p = ecfg.resolved_max_pages
+    batch = ({"inputs": sd((1, ecfg.chunk), np.int32),
+              "block_row": sd((p,), np.int32),
+              "offset": sd((), np.int32), "valid": sd((), np.int32)}
+             if kind == "prefill" else
+             {"inputs": sd((2, 1), np.int32),
+              "block_tables": sd((2, p), np.int32),
+              "pos": sd((2,), np.int32), "active": sd((2,), np.bool_)})
+    fn = eng._prefill if kind == "prefill" else eng._decode
+    text = fn.lower(eng.params, batch, eng._st.caches,
+                    eng._windows).compile().as_text()
+    assert f"HloModule jit_engine_{kind}" in text
+    parts = {part for path in re.findall(r'op_name="([^"]*)"', text)
+             for part in path.split("/")}
+    assert set(STEP_SCOPES) <= parts, set(STEP_SCOPES) - parts
+
+
+def test_span_and_scope_names_are_checked():
+    from repro.runtime import trace
+    with pytest.raises(ValueError, match="not one of"):
+        trace.scope("kv.reads")
+    with trace.span("engine.admit"):     # no profiler running: a no-op
+        pass
+
+
+def _kv_run(served, stop=None):
+    """Two requests (prompts of 6 and 7 tokens, 3 tokens out) through
+    4-token pages, 4 pages a slot and 4-token chunks: prefill chunks at
+    positions 4, 6 / 4, 7, then two decode steps over both slots."""
+    cfg, params, calib, _ = served
+    ecfg = EngineConfig(slots=2, page_size=4, num_pages=16,
+                        max_pages_per_slot=4, chunk=4)
+    reqs = [Request(rid=i, prompt=tuple(range(1, 7 + i)), max_new_tokens=3)
+            for i in range(2)]
+    eng = Engine(cfg, params, ecfg, calib=calib)
+    fc = None if stop is None else FaultConfig(
+        injector=fi.FaultInjector([fi.PreemptAt(stop)]))
+    return eng, eng.run(reqs, fc)
+
+
+def test_kv_page_counters_match_a_hand_count(served):
+    eng, rep = _kv_run(served)
+    assert (rep.prefill_steps, rep.decode_steps) == (4, 2)
+    st = eng._st
+    # read: 4 chunks x 4 pages + 2 decode steps x 2 slots x 4 pages
+    assert st.kv_pages_read == 4 * 4 + 2 * 2 * 4
+    # live after each step: chunks 1, 2, 1, 2 pages; decodes (2 + 2), (2 + 3)
+    assert st.kv_pages_live == (1 + 2 + 1 + 2) + (2 + 2) + (2 + 3)
+
+
+@pytest.mark.parametrize("legacy", [False, True], ids=["v4", "no-kv-keys"])
+def test_kv_page_counters_ride_snapshot_restore(served, legacy):
+    cfg, params, calib, _ = served
+    whole, _ = _kv_run(served)
+    eng, rep = _kv_run(served, stop=3)
+    assert rep.preempted
+    snap = eng.snapshot()
+    if legacy:       # a snapshot from before the counters reads them as 0
+        meta = json.loads(snap["meta"].tobytes().decode("utf-8"))
+        for key in ("kv_pages_read", "kv_pages_live"):
+            del meta["counters"][key]
+        snap["meta"] = np.frombuffer(json.dumps(meta).encode("utf-8"), np.uint8)
+    restored = Engine(cfg, params, eng.ecfg, calib=calib)
+    restored.restore(snap)
+    base = (0, 0) if legacy else (eng._st.kv_pages_read, eng._st.kv_pages_live)
+    assert (restored._st.kv_pages_read, restored._st.kv_pages_live) == base
+    restored.resume()
+    later = (whole._st.kv_pages_read - eng._st.kv_pages_read,
+             whole._st.kv_pages_live - eng._st.kv_pages_live)
+    assert (restored._st.kv_pages_read - base[0],
+            restored._st.kv_pages_live - base[1]) == later
