@@ -200,12 +200,13 @@ def cache_specs(cache_shape: Any, cfg: ModelConfig, mesh: Mesh):
 def paged_specs(cache_shape: Any, cfg: ModelConfig, mesh: Mesh):
     """Paged KV pools: head dims over TP, the page pool itself replicated.
 
-    Paged leaves are (L, pages, page_size, KV, HD) — the leading ``pages``
+    Paged leaves are (L, pages, page_size, KV*HD) — the leading ``pages``
     dim is a global pool indexed through host-built block tables, so it must
     NOT be sharded (every device gathers arbitrary page ids; the DP slot-pool
-    dimension lives in the *block tables*, not the pool).  kv-heads go over
-    TP when divisible, else head_dim — same fallback as ``cache_specs``.
-    Per-position int8 KV scales (L, pages, page_size, KV) follow their pool.
+    dimension lives in the *block tables*, not the pool).  The heads dim goes
+    over TP when KV divides evenly, so that each device holds whole heads;
+    else the pool is replicated.  Per-position int8 KV scales (L, pages,
+    page_size, KV) follow their pool.
     """
     info = axis_info(mesh)
     tp = info["tp_axis"]
@@ -214,11 +215,9 @@ def paged_specs(cache_shape: Any, cfg: ModelConfig, mesh: Mesh):
     def spec_for(path, leaf):
         s = _path_str(path)
         nd = len(leaf.shape)
-        if re.search(r"/(k|v)$", s):          # (L, pages, ps, KV, HD)
-            L, PG, PS, KV, HD = leaf.shape
-            kv_ax = tp if KV % tpn == 0 else None
-            hd_ax = tp if (kv_ax is None and HD % tpn == 0) else None
-            return P(None, None, None, kv_ax, hd_ax)
+        if re.search(r"/(k|v)$", s):          # (L, pages, ps, KV*HD)
+            return P(None, None, None,
+                     tp if cfg.n_kv_heads % tpn == 0 else None)
         if re.search(r"/(k_scale|v_scale)$", s):   # (L, pages, ps, KV)
             L, PG, PS, KV = leaf.shape
             return P(None, None, None, tp if KV % tpn == 0 else None)

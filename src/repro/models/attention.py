@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
+from repro.runtime.paged_cache import PrefillChunkCtx
 from repro.runtime.trace import scope
 
 
@@ -375,7 +376,14 @@ def apply_prefill(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
 # the trash-page convention; the engine (runtime/engine.py) owns allocation.
 # --------------------------------------------------------------------------
 class PagedKVCache(NamedTuple):
-    k: jax.Array          # (num_pages+1, page_size, n_kv, head_dim); last
+    """One layer's page pool; stacked (L, ...) in the step programs.  The
+    same tuple carries a step's new rows: (R, n_kv * head_dim) leaves.
+
+    A position's heads sit side by side in one minor dim: with a head_dim
+    under 128 (qwen1.5-0.5b's 64) a (..., n_kv, head_dim) pool is laid out
+    page-minor by a TPU (to save padding), and its page gathers and row
+    writes then relay the whole pool out and back every step."""
+    k: jax.Array          # (num_pages+1, page_size, n_kv * head_dim); last
     #                       page is the write sink for padded/inactive rows
     v: jax.Array
     k_scale: jax.Array | None = None   # (num_pages+1, page_size, n_kv) int8 mode
@@ -392,10 +400,10 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         raise NotImplementedError(
             "paged KV cache does not support sliding-window archs yet "
             "(the ring buffer already bounds their dense cache)")
-    shape = (ranks * (num_pages + 1), page_size, cfg.n_kv_heads,
-             cfg.resolved_head_dim)
+    shape = (ranks * (num_pages + 1), page_size,
+             cfg.n_kv_heads * cfg.resolved_head_dim)
     if KV_CACHE_INT8:
-        sshape = shape[:-1]
+        sshape = shape[:-1] + (cfg.n_kv_heads,)
         return PagedKVCache(jnp.zeros(shape, jnp.int8),
                             jnp.zeros(shape, jnp.int8),
                             jnp.zeros(sshape, jnp.float32),
@@ -403,61 +411,118 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-@scope("kv.write")
-def _paged_write(cache: PagedKVCache, k, v, pid, off):
-    """Write one K/V row per (page ``pid``, offset ``off``) pair: k, v
-    (R, n_kv, head_dim), quantized under KV_CACHE_INT8.  Returns the new
-    (k, v, k_scale, v_scale) pools (scales None when not quantized)."""
-    def write(buf, val):
-        return buf.at[pid, off].set(val.astype(buf.dtype))
+def _paged_targets(ctx, pools: PagedKVCache, r: int
+                   ) -> tuple[jax.Array, jax.Array]:
+    """Where this step's R rows go: (page id, offset) per row, (R,) each.
 
-    if cache.k_scale is None:
-        return write(cache.k, k), write(cache.v, v), None, None
+    Every layer shares them (pages are allocated per slot, not per layer).
+    ``ctx`` is a ``PrefillChunkCtx`` (R = C chunk rows; rows past ``valid``
+    go to the trash page) or a ``DecodeCtx`` (R = B slots; inactive slots
+    go to the trash page)."""
+    ps = pools.k.shape[2]
+    trash = pools.k.shape[1] - 1
+    if isinstance(ctx, PrefillChunkCtx):
+        gpos = ctx.offset + jnp.arange(r, dtype=jnp.int32)
+        n_rows = ctx.block_row.shape[0]
+        pid = ctx.block_row[jnp.minimum(gpos // ps, n_rows - 1)]
+        return jnp.where(jnp.arange(r) < ctx.valid, pid, trash), gpos % ps
+    n_rows = ctx.block_tables.shape[1]
+    page_idx = jnp.minimum(ctx.pos // ps, n_rows - 1)
+    pid = jnp.take_along_axis(ctx.block_tables, page_idx[:, None], 1)[:, 0]
+    return jnp.where(ctx.active, pid, trash), ctx.pos % ps
+
+
+@scope("kv.write")
+def paged_write(pools: PagedKVCache, rows: PagedKVCache, ctx) -> PagedKVCache:
+    """Write every layer's new rows into the stacked pools, once, after the
+    layer scan: ``rows`` holds (L, R, ...) leaves (``_fresh_rows`` of each
+    layer), ``pools`` the donated (L, pages, page_size, ...) pools, and row
+    r of layer l goes to ``(l, *_paged_targets(ctx, pools, R)[r])``.
+    One scatter per pool, in place on the donated pool (why not inside the
+    scan: see ``transformer._scan_segment``)."""
+    pid, off = _paged_targets(ctx, pools, rows.k.shape[1])
+    layer = jnp.arange(pools.k.shape[0], dtype=jnp.int32)[:, None]
+
+    def write(buf, val):
+        if buf is None:
+            return None
+        return buf.at[layer, pid[None], off[None]].set(val.astype(buf.dtype))
+
+    return PagedKVCache(*(write(b, r) for b, r in zip(pools, rows)))
+
+
+@scope("kv.write")
+def _fresh_rows(pools: PagedKVCache, k, v, dtype):
+    """This layer's new rows as the pools store them (int8 codes and
+    scales under KV_CACHE_INT8), and as a read of the pools would return
+    them in ``dtype``: k, v (R, n_kv, head_dim)."""
+    def flat(x):
+        return x.reshape(x.shape[0], -1)
+
+    if pools.k_scale is None:
+        k, v = k.astype(pools.k.dtype), v.astype(pools.v.dtype)
+        return PagedKVCache(flat(k), flat(v)), k.astype(dtype), v.astype(dtype)
     k_q, k_s = _kv_quantize(k)
     v_q, v_s = _kv_quantize(v)
-    return (write(cache.k, k_q), write(cache.v, v_q),
-            write(cache.k_scale, k_s), write(cache.v_scale, v_s))
+    return (PagedKVCache(flat(k_q), flat(v_q), k_s, v_s),
+            _kv_dequantize(k_q, k_s, dtype), _kv_dequantize(v_q, v_s, dtype))
 
 
 @scope("kv.read")
-def _paged_read(cache: PagedKVCache, k_buf, v_buf, k_sc, v_sc, tables, dtype):
-    """Gather a slot's pages into position order.  tables: (..., P) page ids
-    -> k/v (..., P*page_size, n_kv, head_dim) in the compute dtype."""
-    k_read = k_buf[tables]                       # (..., P, ps, kv, hd)
-    v_read = v_buf[tables]
-    flat = k_read.shape[:-4] + (-1,) + k_read.shape[-2:]
-    k_read = k_read.reshape(flat)
-    v_read = v_read.reshape(flat)
-    if cache.k_scale is not None:
-        ks = k_sc[tables].reshape(flat[:-2] + k_sc.shape[-1:])
-        vs = v_sc[tables].reshape(flat[:-2] + v_sc.shape[-1:])
-        return (_kv_dequantize(k_read, ks, dtype),
-                _kv_dequantize(v_read, vs, dtype))
-    return k_read.astype(dtype), v_read.astype(dtype)
+def _paged_read(pools: PagedKVCache, layer, tables, k_new, v_new, src, sel,
+                dtype):
+    """Gather layer ``layer``'s pages of a slot into position order, with
+    this step's new rows selected in where ``sel`` holds.
+
+    pools: the stacked (L, pages, ...) pools, read in place (no layer's
+    pool is sliced out); tables: (N, P) page ids; k_new / v_new: the R new
+    rows as a read returns them; src, sel: (N, P*page_size) or
+    broadcastable, the new row at each key position and whether to take
+    it.  The new rows are not in the pools yet (``paged_write`` puts them
+    there after the layer scan), so they are merged by position with an
+    elementwise ``where``: every key keeps its position and exactly the
+    value a read after the write would give, so attention reduces in the
+    same order as the dense path, bit for bit."""
+    heads = tables.shape[:1] + (-1,) + k_new.shape[-2:]   # (N, P*ps, kv, hd)
+    k_read = pools.k[layer, tables].reshape(heads)
+    v_read = pools.v[layer, tables].reshape(heads)
+    if pools.k_scale is not None:
+        ks = pools.k_scale[layer, tables].reshape(heads[:-1])
+        vs = pools.v_scale[layer, tables].reshape(heads[:-1])
+        k_read = _kv_dequantize(k_read, ks, dtype)
+        v_read = _kv_dequantize(v_read, vs, dtype)
+    sel = sel[..., None, None]
+    return (jnp.where(sel, k_new[src], k_read.astype(dtype)),
+            jnp.where(sel, v_new[src], v_read.astype(dtype)))
 
 
 def apply_prefill_paged(params, x: jax.Array, cfg: ModelConfig,
-                        cache: PagedKVCache, ctx, key=None
+                        pools: PagedKVCache, layer, ctx, key=None
                         ) -> tuple[jax.Array, PagedKVCache]:
-    """One fixed-size prefill chunk for ONE slot (the engine's compiled
-    prefill step body).  x: (1, C, d); ctx: runtime.paged_cache.PrefillChunkCtx.
+    """One fixed-size prefill chunk for ONE slot, one layer of the
+    engine's compiled prefill step.  x: (1, C, d); pools: the stacked page
+    pools, read only; layer: this layer's index into them; ctx:
+    runtime.paged_cache.PrefillChunkCtx.  Returns the attention output and
+    this layer's C new rows, which ``paged_write`` stores after the layer
+    scan.
 
     Tokens [offset, offset + valid) of the slot's prompt are projected,
-    rope'd at their global positions, written into the slot's pages via the
-    block-table row, and attended against every page the slot owns (earlier
-    chunks included) under the global causal mask.  Padded rows (>= valid)
-    write to the trash page and their outputs are garbage the engine drops.
-    Bit-for-bit identical to ``apply_prefill`` on the whole prompt when the
-    chunk covers it AND the cache is not int8-quantized (per-row
-    encode/attend; masked tail keys contribute exact zeros).  Under
-    KV_CACHE_INT8 this path attends over the quantize->dequantize KV it
-    just wrote (earlier chunks can only be read back dequantized), whereas
-    dense ``apply_prefill`` attends over the full-precision k/v before
-    storing — the engine's isolation contract is therefore engine-vs-solo-
-    engine in int8 mode, not engine-vs-dense."""
+    rope'd at their global positions, and attended against every page the
+    slot owns (earlier chunks included) with the chunk's own rows merged in
+    at their positions, under the global causal mask.  Rows past ``valid``
+    are padding: their outputs are garbage the engine drops and their rows
+    go to the trash page.  The last chunk can reach past the block row
+    (offset + C > P * page_size): the merge indexes the chunk by clipped
+    position, so those rows are never read.  Bit-for-bit identical to
+    ``apply_prefill`` on the whole prompt when the chunk covers it AND the
+    cache is not int8-quantized (per-row encode/attend; masked tail keys
+    contribute exact zeros).  Under KV_CACHE_INT8 this path attends over
+    the quantize->dequantize KV (earlier chunks can only be read back
+    dequantized), whereas dense ``apply_prefill`` attends over the
+    full-precision k/v before storing — the engine's isolation contract is
+    therefore engine-vs-solo-engine in int8 mode, not engine-vs-dense."""
     _, c, _ = x.shape
-    ps = cache.k.shape[1]
-    trash = cache.k.shape[0] - 1
+    ps = pools.k.shape[2]
     n_rows = ctx.block_row.shape[0]
     gpos = ctx.offset + jnp.arange(c, dtype=jnp.int32)       # (C,) global
     positions = gpos[None]
@@ -465,38 +530,36 @@ def apply_prefill_paged(params, x: jax.Array, cfg: ModelConfig,
     q = common.apply_rope(q, positions, cfg.rope_theta)
     k = common.apply_rope(k, positions, cfg.rope_theta)
 
-    in_chunk = jnp.arange(c, dtype=jnp.int32) < ctx.valid
-    pid = ctx.block_row[jnp.minimum(gpos // ps, n_rows - 1)]
-    pid = jnp.where(in_chunk, pid, trash)                    # (C,)
-    off = gpos % ps
-
-    new_k, new_v, k_sc, v_sc = _paged_write(cache, k[0], v[0], pid, off)
-    k_read, v_read = _paged_read(cache, new_k, new_v, k_sc, v_sc,
-                                 ctx.block_row[None], q.dtype)
+    rows, k_new, v_new = _fresh_rows(pools, k[0], v[0], q.dtype)
     kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
-    mask = (kpos[None, :] <= gpos[:, None]) \
-        & (kpos[None, :] < ctx.offset + ctx.valid)
+    end = ctx.offset + ctx.valid
+    sel = (kpos >= ctx.offset) & (kpos < end)
+    src = jnp.clip(kpos - ctx.offset, 0, c - 1)
+    k_read, v_read = _paged_read(pools, layer, ctx.block_row[None], k_new,
+                                 v_new, src[None], sel[None], q.dtype)
+    mask = (kpos[None, :] <= gpos[:, None]) & (kpos[None, :] < end)
     out = _attend(q, k_read, v_read, mask[None, None], cfg)
     y = common.dense(params["wo"], _merge_heads(out),
                      cfg.site_tdvmm("attn.out"), key)
-    return y, PagedKVCache(new_k, new_v, k_sc, v_sc)
+    return y, rows
 
 
 def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
-                       cache: PagedKVCache, ctx, key=None
+                       pools: PagedKVCache, layer, ctx, key=None
                        ) -> tuple[jax.Array, PagedKVCache]:
-    """Batched one-token decode over all B slots (the engine's compiled
-    decode step body).  x: (B, 1, d); ctx: runtime.paged_cache.DecodeCtx.
+    """Batched one-token decode over all B slots, one layer of the engine's
+    compiled decode step.  x: (B, 1, d); pools, layer as in
+    ``apply_prefill_paged``; ctx: runtime.paged_cache.DecodeCtx.  Returns
+    the attention output and this layer's B new rows, which
+    ``paged_write`` stores after the layer scan.
 
-    Each active slot writes its new KV at position ``pos`` through its
-    block-table row and attends over its own gathered pages; inactive slots
-    write to the trash page, never advance, and produce ignored outputs.
-    There is NO decode-past-capacity poisoning path here: the engine evicts
-    a request *before* its next write would overflow its page budget, so an
+    Each slot attends over its own gathered pages with its new row merged
+    in at position ``pos``; inactive slots' rows go to the trash page,
+    never advance, and produce ignored outputs.  There is NO
+    decode-past-capacity poisoning path here: the engine evicts a request
+    *before* its next write would overflow its page budget, so an
     overflowing write can never corrupt (or NaN) a neighbor slot."""
-    b = x.shape[0]
-    ps = cache.k.shape[1]
-    trash = cache.k.shape[0] - 1
+    ps = pools.k.shape[2]
     n_rows = ctx.block_tables.shape[1]
     pos = ctx.pos
     positions = pos[:, None]
@@ -504,21 +567,17 @@ def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
     q = common.apply_rope(q, positions, cfg.rope_theta)
     k = common.apply_rope(k, positions, cfg.rope_theta)
 
-    page_idx = jnp.minimum(pos // ps, n_rows - 1)
-    pid = jnp.take_along_axis(ctx.block_tables, page_idx[:, None], 1)[:, 0]
-    pid = jnp.where(ctx.active, pid, trash)                  # (B,)
-    off = pos % ps
-
-    new_k, new_v, k_sc, v_sc = _paged_write(cache, k[:, 0], v[:, 0], pid,
-                                            off)
-    k_read, v_read = _paged_read(cache, new_k, new_v, k_sc, v_sc,
-                                 ctx.block_tables, q.dtype)
+    rows, k_new, v_new = _fresh_rows(pools, k[:, 0], v[:, 0], q.dtype)
     kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
+    sel = kpos[None, :] == pos[:, None]                       # (B, cap)
+    src = jnp.arange(pos.shape[0])[:, None]                   # (B, 1)
+    k_read, v_read = _paged_read(pools, layer, ctx.block_tables, k_new,
+                                 v_new, src, sel, q.dtype)
     mask = (kpos[None, :] <= pos[:, None])[:, None, None, :]  # (B,1,1,cap)
     out = _attend(q, k_read, v_read, mask, cfg)
     y = common.dense(params["wo"], _merge_heads(out),
                      cfg.site_tdvmm("attn.out"), key)
-    return y, PagedKVCache(new_k, new_v, k_sc, v_sc)
+    return y, rows
 
 
 def apply_decode(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,

@@ -157,14 +157,17 @@ def init_paged_caches(cfg: ModelConfig, num_pages: int, page_size: int,
         return attention.init_paged_cache(cfg, num_pages, page_size, dtype,
                                           ranks=ranks)
 
-    def stack(mk, n):
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *[mk() for _ in range(n)])
+    def stacked(n):
+        # One zero allocation per stacked pool: stacking n per-layer pools
+        # would hold twice the pools' bytes while it copies.
+        return jax.tree.map(lambda a: jnp.zeros((n,) + a.shape, a.dtype),
+                            jax.eval_shape(one_attn))
 
     caches: dict[str, Any] = {}
     for i, (kind, n) in enumerate(transformer.segments(cfg)):
         if kind not in ("attn_ffn", "attn_moe"):
             raise NotImplementedError(f"paged serving: segment kind {kind!r}")
-        caches[f"seg{i}"] = stack(one_attn, n)
+        caches[f"seg{i}"] = stacked(n)
     return caches
 
 

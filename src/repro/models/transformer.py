@@ -44,12 +44,12 @@ def attn_ffn_block(params, x, cfg: ModelConfig, mode: str, cache, positions, key
         new_cache = cache
     elif mode == "prefill":
         a, new_cache = attention.apply_prefill(params["attn"], h, cfg, cache, key)
-    elif mode == "prefill_paged":
+    elif mode == "prefill_paged":   # cache: (stacked pools, layer index)
         a, new_cache = attention.apply_prefill_paged(
-            params["attn"], h, cfg, cache, page_ctx, key)
+            params["attn"], h, cfg, *cache, page_ctx, key)
     elif mode == "decode_paged":
         a, new_cache = attention.apply_decode_paged(
-            params["attn"], h, cfg, cache, page_ctx, key)
+            params["attn"], h, cfg, *cache, page_ctx, key)
     else:
         a, new_cache = attention.apply_decode(params["attn"], h, cfg, cache, key)
     x = x + a
@@ -150,7 +150,14 @@ def init(key, cfg: ModelConfig, dtype) -> dict:
 # Apply: scan over stacked segment params
 # --------------------------------------------------------------------------
 def _scan_segment(body, stacked_params, x, caches, cfg: ModelConfig):
-    """caches: stacked pytree with leading layer dim (or None for train)."""
+    """caches: stacked pytree with leading layer dim (or None for train).
+
+    The paged modes pass the layer indices here instead of their page
+    pools: a pool in ``xs`` / ``ys`` would be sliced out of the stack,
+    rewritten and stacked again by every layer, whole, for the few rows a
+    step adds.  Their body reads the stacked pools it closes over and
+    returns its new rows, which ``attention.paged_write`` stores once after
+    the scan."""
     def step(carry, layer_in):
         p, c = layer_in
         new_x, new_c, aux = body(p, carry, c)
@@ -174,21 +181,27 @@ def apply(params, x: jax.Array, cfg: ModelConfig, mode: str,
     new_caches: dict[str, Any] = {}
     aux_total = {"lb_loss": jnp.zeros((), jnp.float32),
                  "z_loss": jnp.zeros((), jnp.float32)}
+    paged = mode in ("prefill_paged", "decode_paged")
 
     for i, (kind, n) in enumerate(segments(cfg)):
         seg_params = params[f"seg{i}"]
         seg_cache = None if caches is None else caches.get(f"seg{i}")
 
         if kind in ("attn_ffn", "attn_moe"):
-            def body(p, h, c, _kind=kind):
+            def body(p, h, c, _pools=seg_cache):
+                c = (_pools, c) if paged else c
                 h2, nc, aux = attn_ffn_block(p, h, cfg, mode, c, positions, key,
                                              page_ctx=page_ctx)
                 aux = {k2: aux.get(k2, jnp.zeros((), jnp.float32))
                        for k2 in ("lb_loss", "z_loss")}
                 return h2, nc, aux
-            x, nc, auxs = _scan_segment(body, seg_params, x, seg_cache, cfg)
+            x, nc, auxs = _scan_segment(
+                body, seg_params, x,
+                jnp.arange(n, dtype=jnp.int32) if paged else seg_cache, cfg)
             if kind == "attn_moe":
                 aux_total = {k2: aux_total[k2] + jnp.sum(auxs[k2]) for k2 in aux_total}
+            if paged:
+                nc = attention.paged_write(seg_cache, nc, page_ctx)
             new_caches[f"seg{i}"] = nc
 
         elif kind == "ssm":

@@ -17,7 +17,7 @@ the next request reuses them.
 
 Layout per attention layer (see ``models.attention.init_paged_cache``):
 
-    k, v        (num_pages + 1, page_size, n_kv, head_dim)
+    k, v        (num_pages + 1, page_size, n_kv * head_dim)
     k/v_scale   (num_pages + 1, page_size, n_kv)            int8 KV mode
 
 The **last** page is the trash page: writes from inactive slots (and padded

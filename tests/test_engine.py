@@ -5,6 +5,8 @@ The load-bearing contract (acceptance criterion, jnp backend): per-request
 token streams from the batched paged engine are bit-identical to running
 each request alone at the same calibrated windows — slots never couple.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -152,12 +154,39 @@ def test_oversized_prompt_rejected_as_evicted(served):
 # --------------------------------------------------------------------------
 # Satellite: int8 KV quantization under page reuse (write -> free -> realloc)
 # --------------------------------------------------------------------------
+@contextlib.contextmanager
+def _kv_int8(on):
+    attention.set_kv_cache_int8(on)
+    try:
+        yield
+    finally:
+        attention.set_kv_cache_int8(False)
+
+
+def _assert_isolated(served, reqs, ecfg, int8, dense_ref):
+    """Serve ``reqs`` on ``ecfg`` with int8 KV on or off: every request
+    finishes on max_tokens with its reference's tokens — its dense solo run
+    (``dense_ref``) or its run alone on a one-slot engine of 8 pages."""
+    cfg, params, calib = served
+    with _kv_int8(int8):
+        rep = Engine(cfg, params, ecfg, calib=calib).run(reqs)
+        solo_cfg = EngineConfig(slots=1, page_size=ecfg.page_size,
+                                num_pages=8, chunk=ecfg.chunk)
+        for req, rec in zip(reqs, rep.requests):
+            assert rec["finish_reason"] == "max_tokens"
+            want = (_solo_dense_greedy(cfg, params, calib, req) if dense_ref
+                    else Engine(cfg, params, solo_cfg, calib=calib).run(
+                        [Request(req.rid, req.prompt, req.max_new_tokens, 0)]
+                    ).requests[0]["tokens"])
+            assert rec["tokens"] == want, f"int8={int8}: request {req.rid}"
+        assert rep.nan_logit_steps == 0
+
+
 @pytest.mark.parametrize("int8", [False, True])
 def test_page_reuse_no_stale_scale_bleed(served, int8):
     """A new request reallocating a finished request's pages must see no
     trace of the old codes/scales (stale positions are masked to exact
     zeros; every written position carries its own fresh scale)."""
-    cfg, params, calib = served
     # pool = exactly one request's worth of pages: B MUST reuse A's pages.
     reqs = [Request(0, tuple(range(1, 11)), max_new_tokens=5,
                     arrival_step=0),
@@ -165,19 +194,82 @@ def test_page_reuse_no_stale_scale_bleed(served, int8):
                     arrival_step=1)]
     ecfg = EngineConfig(slots=2, page_size=4, num_pages=4, chunk=8)
     assert pages_for(15, 4) == 4          # A fills the whole pool
-    attention.set_kv_cache_int8(int8)
-    try:
-        rep = Engine(cfg, params, ecfg, calib=calib).run(reqs)
-        solo_cfg = EngineConfig(slots=1, page_size=4, num_pages=8, chunk=8)
-        for req, rec in zip(reqs, rep.requests):
-            assert rec["finish_reason"] == "max_tokens"
-            solo = Engine(cfg, params, solo_cfg, calib=calib).run(
-                [Request(req.rid, req.prompt, req.max_new_tokens, 0)])
-            assert rec["tokens"] == solo.requests[0]["tokens"], \
-                f"int8={int8}: stale page state bled into request {req.rid}"
-        assert rep.nan_logit_steps == 0
-    finally:
-        attention.set_kv_cache_int8(False)
+    _assert_isolated(served, reqs, ecfg, int8, dense_ref=False)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_last_chunk_past_block_row(served, int8):
+    """The last prefill chunk may reach past the slot's block row
+    (offset + C > P * page_size): its rows are merged into the read by
+    clipped position, so the real ones land where they belong and the
+    padded ones are never read.  bf16 KV: each request equals its dense
+    solo run; int8 KV: its solo run on an engine whose rows are long."""
+    # 3 pages of 4 hold 12 positions; a 10-token prompt in chunks of 8
+    # ends with the chunk [8, 16).
+    reqs = [Request(0, tuple(range(1, 11)), max_new_tokens=3),
+            Request(1, tuple(range(20, 31)), max_new_tokens=2)]
+    ecfg = EngineConfig(slots=2, page_size=4, num_pages=8,
+                        max_pages_per_slot=3, chunk=8)
+    assert 8 + ecfg.chunk > ecfg.max_pages_per_slot * ecfg.page_size
+    _assert_isolated(served, reqs, ecfg, int8, dense_ref=not int8)
+
+
+def _eqns(jaxpr, top=True):
+    """(equation, whether it is at the top level) for every equation of a
+    jaxpr and of the jaxprs nested in its equations."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn, top
+        stack = list(eqn.params.values())
+        while stack:
+            v = stack.pop()
+            if isinstance(v, ClosedJaxpr):
+                v = v.jaxpr
+            if isinstance(v, Jaxpr):
+                yield from _eqns(v, False)
+            elif isinstance(v, (tuple, list)):
+                stack.extend(v)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_step_programs_keep_pools_out_of_layer_scan(served, step, int8):
+    """The page pools are no scan carry, input slice or output: each step
+    program's only pool-shaped values are its inputs and ONE scatter per
+    pool after the layer loop, which writes every layer's new rows."""
+    cfg, params, calib = served
+    ecfg = EngineConfig(slots=3, page_size=4, num_pages=16, chunk=8)
+    n_rows = ecfg.resolved_max_pages
+    if step == "decode":
+        fn = model.decode_slots
+        batch = {"inputs": jnp.zeros((3, 1), jnp.int32),
+                 "block_tables": jnp.zeros((3, n_rows), jnp.int32),
+                 "pos": jnp.zeros((3,), jnp.int32),
+                 "active": jnp.ones((3,), bool)}
+    else:
+        fn = model.prefill_chunk
+        batch = {"inputs": jnp.zeros((1, ecfg.chunk), jnp.int32),
+                 "block_row": jnp.zeros((n_rows,), jnp.int32),
+                 "offset": jnp.int32(0), "valid": jnp.int32(5)}
+    with _kv_int8(int8):
+        caches = model.init_paged_caches(cfg, ecfg.num_pages, ecfg.page_size)
+        closed = jax.make_jaxpr(
+            lambda p, b, c: fn(p, b, c, cfg, calib=calib))(params, batch,
+                                                           caches)
+    stacked = {tuple(leaf.shape) for leaf in jax.tree.leaves(caches)}
+    pool_shapes = stacked | {shape[1:] for shape in stacked}
+    eqns = list(_eqns(closed.jaxpr))
+    loops = [e for e, _ in eqns if e.primitive.name in ("scan", "while")]
+    assert loops
+    for e in loops:
+        assert not [v.aval.shape for v in e.outvars
+                    if tuple(v.aval.shape) in pool_shapes]
+    writers = [(e, top) for e, top in eqns
+               if any(tuple(v.aval.shape) in pool_shapes for v in e.outvars)]
+    assert [(e.primitive.name, top) for e, top in writers] \
+        == [("scatter", True)] * len(jax.tree.leaves(caches))
+    pools_out = closed.jaxpr.outvars[-len(writers):]
+    assert [e.outvars[0] for e, _ in writers] == pools_out
 
 
 # --------------------------------------------------------------------------
