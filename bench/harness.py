@@ -2,10 +2,10 @@
 
 ``run`` drives the serving engine (``runtime/engine.py``) through
 ``adapter.Driver`` with the cell's traffic, for ``seconds`` seconds, then
-checks what the window served against the plain reference
-(``reference.py``) and returns the result line.  ``main`` (``run.py``)
-refuses to run without a chip; tests call ``run`` on the CPU at a small
-size.
+checks what the window served against the plain reference of the
+configuration's architecture (``archs/<name>/``, which alone knows one)
+and returns the result line.  ``main`` (``run.py``) refuses to run
+without a chip; tests call ``run`` on the CPU at a small size.
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ import time
 from pathlib import Path
 
 import numpy as np
+
+from bench import archs
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -42,36 +44,18 @@ def cell_parts(name: str, root: Path = ROOT) -> tuple[dict, dict, dict, dict]:
     cell = cells[name]
     conf = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     cfg = load_json(root / conf["file"])
+    archs.find(cfg, conf["file"])
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     return manifest, cell, cfg, mix
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the repo's
-    architecture entry with the file's sizes, every listed analog site on."""
-    from repro.configs import TDVMMPlan, get_config, tdvmm_rule
-    td = cfg["tdvmm"]
-    return get_config(
-        cfg["arch"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"], vocab_pad_multiple=cfg["vocab_pad_multiple"],
-        tdvmm_plan=TDVMMPlan(rules=(tdvmm_rule(
-            td["sites"], enabled=True, backend=td["backend"], bits=td["bits"],
-            weight_bits=td["weight_bits"]),)))
 
 
 def check_layout(shapes, cfg: dict) -> None:
     """The program's parameter tree must be the layout the reference draws."""
     import jax
-    from bench import reference, weights
+    from bench import weights
     got = {weights.path_str(p): tuple(s.shape)
            for p, s in jax.tree_util.tree_flatten_with_path(shapes)[0]}
-    want = reference.param_shapes(cfg)
+    want = archs.find(cfg).reference.param_shapes(cfg)
     if got != want:
         raise SystemExit(f"bench: the program's parameter tree differs from "
                          f"the reference's layout: {sorted(set(got.items()) ^ set(want.items()))}")
@@ -176,9 +160,10 @@ def positions(drv) -> dict[int, int]:
     return {rid: pos for rid, _, pos in drv.occupied()}
 
 
-def position_sum(w: Window, p0: dict, p1: dict, t0: float, t1: float) -> int:
-    """Sum over the positions processed between two snapshots of (p + 1)."""
-    total = 0
+def ranges(w: Window, p0: dict, p1: dict, t0: float, t1: float) -> list[tuple[int, int]]:
+    """The positions ``start <= p < end`` each request processed between two
+    snapshots."""
+    out = []
     for rid, r in w.reqs.items():
         end = p1.get(rid)
         if end is None:
@@ -186,9 +171,8 @@ def position_sum(w: Window, p0: dict, p1: dict, t0: float, t1: float) -> int:
             if done is None or not (t0 <= done <= t1):
                 continue
             end = len(r.prompt) + r.served - 1
-        start = p0.get(rid, 0)
-        total += (end * (end + 1) - start * (start + 1)) // 2
-    return total
+        out.append((p0.get(rid, 0), end))
+    return out
 
 
 def sample(w: Window, seed: int, target: int, most: int) -> list[int]:
@@ -220,7 +204,7 @@ def check(cfg: dict, mix: dict, seed: int, served: list[tuple], cal_tokens,
     same gap for the token that the reference in float8 puts first."""
     import jax
     import jax.numpy as jnp
-    from bench import reference
+    reference = archs.find(cfg).reference
     seqs = [np.asarray(p + tuple(t[:-1]), np.int32) for p, t in served]
     starts = [len(p) - 1 for p, _ in served]
     rows = mix["output"]["max"]
@@ -260,14 +244,14 @@ def run(cell: dict, cfg: dict, mix: dict, manifest: dict, seed: int,
         seconds: float, trace: bool, t_start: float, control: bool = False) -> dict:
     """One run; returns the result line as a dict."""
     import jax
-    from bench import adapter, traffic, weights, work, xplane
+    from bench import adapter, scopes, traffic, weights, work, xplane
     from repro.kernels.tdvmm import ops as tdvmm_ops
     from repro.models import model
     from repro.runtime.engine import Engine
 
     dev = jax.devices()
     compiles = CompileCount()
-    mcfg = model_config(cfg)
+    mcfg = archs.find(cfg).program.model_config(cfg)
     vocab = mcfg.vocab_size
     t = time.perf_counter()
     shapes = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), mcfg))
@@ -369,36 +353,50 @@ def run(cell: dict, cfg: dict, mix: dict, manifest: dict, seed: int,
     if traced is not None:
         pk = work.peaks(dev[0].device_kind)
         tdc = traced["c1"].minus(traced["c0"])
-        tr = xplane.load(xplane.find_trace(str(TRACE_DIR)))
+        path = xplane.find_trace(str(TRACE_DIR))
+        tr = xplane.load(path)
         log("trace device planes (op events): "
             + ", ".join(f"{k} ({len(v['ops'])})" for k, v in tr["devices"].items()))
         red = xplane.reduce(tr)
+        t, n0 = time.perf_counter(), compiles.n
+        hlo = {p: scopes.hlo_scopes(text) for p, text in drv.step_hlo().items()}
+        if compiles.n > n0:
+            # Not found in the cache: another lowering, whose instruction
+            # names would put the trace's ops under the wrong scopes.
+            log(f"step programs' HLO: {compiles.n - n0} compiles; no scope is read")
+            hlo = {}
+        att = scopes.reduce(scopes.load(path), hlo)
+        log(f"step programs' HLO and scope reduction {time.perf_counter() - t:.2f} s")
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         e = mix["engine"]
-        for site, m, k, n, cnt in (work.step_launches(cfg, e["slots"], e["slots"])
-                                   + work.step_launches(cfg, e["chunk"], 1)):
-            least, inten, bound = work.launch_cost(m, k, n, pk)
-            log(f"launch {site} (M,K,N)=({m},{k},{n}) x{cnt}: {inten:.1f} ops/byte, "
+        for site, g, m, k, n, cnt in work.window_launches(cfg, 1, e["slots"], 1, e["chunk"]):
+            least, inten, bound = work.launch_cost(g, m, k, n, pk)
+            log(f"launch {site} (G,M,K,N)=({g},{m},{k},{n}) x{cnt}: {inten:.1f} ops/byte, "
                 f"bound by {bound}, least {least * 1e6:.2f} us")
         decode = xplane.program(red["modules"], tdc.decode_steps)
+        prefill = xplane.program(red["modules"], tdc.prefill_steps, decode)
+        main = max((p for p in (decode, prefill) if p), key=lambda p: p[2], default=None)
         record = {
             "cfg": cfg, "mix": mix, "peaks": pk, "trace": red,
-            "counts": dataclasses.asdict(tdc), "slots": e["slots"],
+            "counts": dict(tdc), "slots": e["slots"],
             "chunk": e["chunk"], "host": e2e, "decode_program": decode,
-            "prefill_program": xplane.program(red["modules"], tdc.prefill_steps,
-                                              decode),
+            "prefill_program": prefill,
+            "main_program": main and scopes.program_name(main[0]),
+            "scopes": {p: scopes.per_run_ms(att, p) for p in att["runs"] if p in hlo},
             "least_kernel_s": work.least_kernel_seconds(
                 cfg, pk, tdc.decode_steps, e["slots"], tdc.prefill_steps, e["chunk"]),
             "kernel_launches": work.kernel_launches(
                 cfg, tdc.decode_steps, e["slots"], tdc.prefill_steps, e["chunk"]),
             "model_s": work.model_seconds(
                 cfg, pk, tdc.prompt_tokens + round(tdc.active_slot_steps),
-                tdc.generated_tokens, position_sum(w, traced["p0"], traced["p1"],
-                                                   traced["t0"], traced["t1"])),
+                tdc.generated_tokens, ranges(w, traced["p0"], traced["p1"],
+                                             traced["t0"], traced["t1"])),
         }
         log(f"trace: window {red['window_s']:.4f} s, busy {red['busy_s']:.4f} s, "
             f"programs decode {record['decode_program']} prefill "
-            f"{record['prefill_program']}; idle by host span {red['idle_by_span']}")
+            f"{record['prefill_program']}; idle by host span {att['idle_by_span']}")
+        for p, ms in record["scopes"].items():
+            log(f"self ms per run of {p}: {ms}")
         for text, sec in red["top_ops"]:
             log(f"device op {sec * 1e3:.3f} ms: {text}")
         for name, (n, sec) in sorted(red["ops"].items()):
@@ -454,7 +452,7 @@ def run(cell: dict, cfg: dict, mix: dict, manifest: dict, seed: int,
         device["busy_s"] = record["trace"]["busy_s"]
         device["window_s"] = record["trace"]["window_s"]
         out["breakdown"] = {"device_ops": record["trace"]["top_ops"],
-                            "idle_gaps": record["trace"]["top_gaps"]}
+                            "idle_gaps": att["top_gaps"]}
     for name, (v, lim, ok) in checks.items():
         log(f"check {name}: {v} (limit {lim}) {'ok' if ok else 'FAILED'}")
     out["checks"] = {name: {"value": v, "limit": lim}

@@ -63,7 +63,12 @@ def hlo_scopes(text: str, scopes=STEP_SCOPES) -> dict[str, str | None]:
     layout copies, a loop's slicing of stacked buffers) and carries no scope
     takes the scope of the nearest scoped instruction along its data flow,
     through other such movers: its operands first, then its users.  Element
-    i of a ``while`` result comes from element i of its body's root tuple."""
+    i of a ``while`` result comes from element i of its body's root tuple.
+    A fusion with no ``op_name`` whose fused scatter writes an operand in
+    place, and that fuses nothing scoped, takes the scope along that pool
+    operand's data flow in the same way: the pool's producers first, then
+    the fusion's users (an in-place row write whose ``op_name`` the compiler
+    left on the bitcast of its result)."""
     ins, comps, comp = {}, {}, None
     for line in text.splitlines():
         head = _COMP.match(line)
@@ -85,7 +90,8 @@ def hlo_scopes(text: str, scopes=STEP_SCOPES) -> dict[str, str | None]:
                 break
         meta = _OP_NAME.search(rest)
         ins[name] = {"op": op.group(1) if op else "", "path": meta and meta.group(1),
-                     "args": _REF.findall(args[:cut]), **dict(_ATTR.findall(args))}
+                     "args": _REF.findall(args[:cut]), "text": args[:cut],
+                     **dict(_ATTR.findall(args))}
         comp["names"].append(name)
         if root:
             comp["root"] = name
@@ -135,11 +141,31 @@ def hlo_scopes(text: str, scopes=STEP_SCOPES) -> dict[str, str | None]:
         return next((sc for n in order if n is not None
                      for sc in [scope_of(ins[n]["path"], scopes)] if sc), None)
 
+    def pool(name):
+        """The argument of a fusion that its fused scatter updates in place
+        (the scatter's first operand, through movers, is a parameter of the
+        fused computation), or None."""
+        comp = comps.get(ins[name].get("calls"))
+        sc = comp and next((n for n in comp["names"] if ins[n]["op"] == "scatter"), None)
+        if sc is None or not ins[sc]["args"]:
+            return None
+        n = ins[sc]["args"][0]
+        while ins[n]["op"] in MOVERS - {"parameter"} and ins[n]["args"]:
+            n = ins[n]["args"][0]
+        if ins[n]["op"] != "parameter":
+            return None
+        k = int(ins[n]["text"])
+        return ins[name]["args"][k] if k < len(ins[name]["args"]) else None
+
     out = {}
     for name, d in ins.items():
         out[name] = scope_of(d["path"], scopes)
         if out[name] is None and d["path"] is None:
             out[name] = fused(name)
+        if out[name] is None and d["path"] is None and d["op"] == "fusion" \
+                and (p := pool(name)) is not None:
+            out[name] = (nearest(name, lambda n: [p] if n == name else upstream(n))
+                         or nearest(name, lambda n: users[n]))
         if out[name] is None and mover(name):
             out[name] = (nearest(name, upstream)
                          or nearest(name, lambda n: users[n]))
@@ -172,11 +198,11 @@ def load(path: str) -> dict:
                     continue
                 dev = devices.setdefault(dev_name, {"ops": [], "modules": []})
                 if kind == "modules":
-                    dev["modules"].append((_base(ev.name), ev.start_ns, ev.end_ns))
+                    dev["modules"].append((program_name(ev.name), ev.start_ns, ev.end_ns))
                     continue
                 name = stats.get("hlo_op") or xplane.op_name(ev.name)
                 dev["ops"].append((name, ev.start_ns, ev.end_ns,
-                                   _base(stats.get("hlo_module"))))
+                                   program_name(stats.get("hlo_module"))))
     return {"host": host, "devices": devices}
 
 
@@ -260,9 +286,9 @@ def reduce(trace: dict, hlo: dict) -> dict:
     }
 
 
-def _base(program: str | None) -> str | None:
-    """A program event's name without the run id some traces append
-    (``jit_engine_decode(12)``)."""
+def program_name(program: str | None) -> str | None:
+    """A program event's name without the id some traces append
+    (``jit_engine_decode(2728503979350226886)``)."""
     return None if program is None else program.split("(", 1)[0]
 
 

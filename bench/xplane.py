@@ -13,9 +13,10 @@ host's clock:
   by its whole HLO text, ``%fusion.12 = bf16[...] fusion(...)``, and the
   name is what stands before `` = ``);
 * program executions: count and device time per program, so that a step
-  program can be told apart by how often it ran, not by its name;
-* idle gaps: the stretches inside the window where no op runs, each
-  labelled with the host span that covers most of it.
+  program can be told apart by how often it ran, not by its name.
+
+Idle gaps, labelled by the host spans around them, and time by step scope
+come from ``scopes.py``.
 """
 from __future__ import annotations
 
@@ -44,10 +45,10 @@ def op_name(text: str) -> str:
     return text[1:].split(" = ", 1)[0] if text.startswith("%") else text
 
 
-def load(path: str, spans=HOST_SPANS) -> dict:
-    """The window span, the named host spans and per-device op and program
-    events, as plain tuples (name, start_ns, end_ns); ``op_text``: the
-    first ``TEXT_CHARS`` characters of each op's HLO text."""
+def load(path: str) -> dict:
+    """The window span and per-device op and program events, as plain
+    tuples (name, start_ns, end_ns); ``op_text``: the first ``TEXT_CHARS``
+    characters of each op's HLO text."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     host: list = []
@@ -57,7 +58,7 @@ def load(path: str, spans=HOST_SPANS) -> dict:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name == WINDOW_SPAN or ev.name in spans:
+                    if ev.name == WINDOW_SPAN:
                         host.append((ev.name, ev.start_ns, ev.end_ns))
         elif plane.name.startswith("/device:"):
             dev = devices.setdefault(plane.name, {"ops": [], "modules": []})
@@ -94,28 +95,16 @@ def window(host: list) -> tuple[float, float]:
     return spans[-1]
 
 
-def label_gap(gap: tuple[float, float], spans: list) -> str:
-    """The host span that covers most of an idle gap ('none' if none)."""
-    best, cover = "none", 0.0
-    for name, s, e in spans:
-        c = min(e, gap[1]) - max(s, gap[0])
-        if c > cover:
-            best, cover = name, c
-    return best
-
-
-def reduce(trace: dict, span_names=HOST_SPANS) -> dict:
+def reduce(trace: dict) -> dict:
     """The numbers of one traced window (seconds), averaged over devices."""
     lo, hi = window(trace["host"])
-    spans = sorted(((n, s, e) for n, s, e in trace["host"]
-                    if n in span_names and e > lo and s < hi), key=lambda t: t[1])
     # A TPU trace also holds device planes on which no op ever runs; such a
     # plane is no chip of the run, and counted as one it would halve every
-    # average and put an idle gap the length of the window on the list.
+    # average.
     devs = {k: v for k, v in trace["devices"].items() if v["ops"]}
     if not devs:
         raise ValueError("trace has no device plane with ops")
-    busy, ops, modules, gaps = 0.0, collections.Counter(), {}, []
+    busy, ops, modules = 0.0, collections.Counter(), {}
     op_count = collections.Counter()
     for dev in devs.values():
         merged = merge([(s, e) for _, s, e in dev["ops"]], lo, hi)
@@ -129,17 +118,7 @@ def reduce(trace: dict, span_names=HOST_SPANS) -> dict:
                 c = modules.setdefault(name, [0, 0.0])
                 c[0] += 1
                 c[1] += e - s
-        edges = [lo] + [t for iv in merged for t in iv] + [hi]
-        gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-                 if edges[i + 1] > edges[i]]
     n = len(devs)
-    gaps.sort(key=lambda g: g[0] - g[1])
-    idle_by_span = collections.Counter()
-    j = 0
-    for g in sorted(gaps):
-        while j < len(spans) and spans[j][2] < g[0]:
-            j += 1
-        idle_by_span[label_gap(g, spans[j:j + 8])] += g[1] - g[0]
     return {
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy * 1e-9 / n,
@@ -147,9 +126,6 @@ def reduce(trace: dict, span_names=HOST_SPANS) -> dict:
         "modules": {k: [c, t * 1e-9 / n] for k, (c, t) in modules.items()},
         "top_ops": [[trace.get("op_text", {}).get(k, k), v * 1e-9 / n]
                     for k, v in ops.most_common(10)],
-        "top_gaps": [[label_gap(g, spans), (g[1] - g[0]) * 1e-9]
-                     for g in gaps[:10]],
-        "idle_by_span": {k: v * 1e-9 / n for k, v in idle_by_span.items()},
     }
 
 

@@ -9,7 +9,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import metrics, xplane  # noqa: E402
+from bench import metrics, scopes, xplane  # noqa: E402
 
 MS = 1_000_000
 
@@ -36,7 +36,10 @@ def test_busy_union_idle_and_window(extra):
     tr = trace()
     tr["devices"].update(extra)
     red = xplane.reduce(tr)
-    assert [round(g * 1e3) for _, g in red["top_gaps"]] == [25, 5, 4, 1]
+    as_scoped = dict(tr, devices={k: dict(v, ops=[(*o, None) for o in v["ops"]])
+                                  for k, v in tr["devices"].items()})
+    gaps = scopes.reduce(as_scoped, {})["top_gaps"]
+    assert [round(g * 1e3) for _, g in gaps] == [25, 5, 4, 1]
     assert red["window_s"] == pytest.approx(0.1)
     # busy: [5,35] + [36,40] + [65,95] + [99,100] = 30 + 4 + 30 + 1 ms
     assert red["busy_s"] == pytest.approx(0.065)
@@ -58,16 +61,6 @@ def test_programs_told_apart_by_run_count():
     assert xplane.program(red["modules"], 3) is None
     rec = {"decode_program": xplane.program(red["modules"], 2)}
     assert metrics.read("decode_step_ms", rec) == pytest.approx(30.0)
-
-
-def test_idle_gaps_labelled_by_host_span():
-    red = xplane.reduce(trace())
-    # gaps: [0,5] tick, [35,36] tick, [40,65] 5 ms harvest + 10 feed + 5 tick
-    #       + 5 tick -> feed covers most, [95,99] tick
-    labels = {round(s * 1e3): lab for lab, s in red["top_gaps"]}
-    assert labels == {25: "feed", 5: "tick", 4: "tick", 1: "tick"}
-    assert red["idle_by_span"]["feed"] == pytest.approx(0.025)
-    assert red["idle_by_span"]["tick"] == pytest.approx(0.010)
 
 
 def test_roofline_reader_takes_only_the_kernel():
