@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exact configs from the public literature.
+"""The assigned architectures, exact configs from the public literature.
 
 Every entry is selectable via ``--arch <id>`` in the launchers.  Sources are
 noted per config (see task assignment).  ``smoke(cfg)`` derives the reduced
@@ -87,6 +87,25 @@ def kimi_k2_1t_a32b() -> ModelConfig:
                       impl="ep"))
 
 
+TRINITY_LAYER_TYPES = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+def trinity_mini() -> ModelConfig:
+    # [hf:arcee-ai/Trinity-Mini] (afmoe) 2 dense + 30 MoE layers: 128 experts
+    # top-8 with 1 shared, sigmoid routing with a selection bias; 3 window
+    # (2048, RoPE) : 1 full (no RoPE) layers; QK-norm, gated attention,
+    # sandwich norms, muP embedding scale.
+    return ModelConfig(
+        name="trinity-mini", family="moe", n_layers=32, d_model=2048,
+        n_heads=32, n_kv_heads=4, head_dim=128, d_ff=6144, vocab_size=200192,
+        act="silu_glu", rope_theta=10000.0, swa_window=2048,
+        layer_types=TRINITY_LAYER_TYPES * 8, rope_full_layers=False,
+        qk_norm=True, attn_gate=True, sandwich_norm=True, embed_scale=True,
+        moe=MoEConfig(n_experts=128, top_k=8, d_ff=1024, n_shared_experts=1,
+                      first_k_dense=2, score_func="sigmoid", route_scale=2.826,
+                      selection_bias=True))
+
+
 def zamba2_2_7b() -> ModelConfig:
     # [arXiv:2411.15242] Mamba2 backbone + shared attention block (with the
     # concat-embedding fuse), every 6 SSM layers.
@@ -108,6 +127,7 @@ ARCHS = {
     "mixtral-8x7b": mixtral_8x7b,
     "kimi-k2-1t-a32b": kimi_k2_1t_a32b,
     "zamba2-2.7b": zamba2_2_7b,
+    "trinity-mini": trinity_mini,
 }
 
 
@@ -126,11 +146,14 @@ def smoke(cfg: ModelConfig) -> ModelConfig:
     )
     if cfg.swa_window is not None:
         kw["swa_window"] = 8
+    if cfg.layer_types is not None:
+        kw["n_layers"] = 4
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=2, d_ff=32,
             n_shared_experts=min(cfg.moe.n_shared_experts, 1),
-            first_k_dense=min(cfg.moe.first_k_dense, 1))
+            first_k_dense=min(cfg.moe.first_k_dense, 1),
+            held=min(cfg.moe.held, 4))
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(
             cfg.ssm, d_state=16, head_dim=16, expand=2, chunk=8)

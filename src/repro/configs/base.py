@@ -146,6 +146,18 @@ class MoEConfig:
     first_k_dense: int = 0          # leading dense layers before MoE starts
     impl: str = "local"             # 'local' (E replicated over dp, TP inside)
     #                                 or 'ep' (experts sharded over dp, all_to_all)
+    score_func: str = "softmax"     # router scores: softmax | sigmoid
+    route_scale: float = 1.0        # top-k weights, divided by their sum,
+    #                                 then multiplied by this
+    selection_bias: bool = False    # a per-expert bias added to the scores
+    #                                 for top-k selection only (not weighting)
+    held: int = 0                   # experts this layer holds (0: all); it
+    held_offset: int = 0            # routes over all n_experts and computes
+    #                                 the part of experts [offset, offset+held)
+
+    @property
+    def resolved_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,6 +187,17 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     swa_window: Optional[int] = None    # sliding-window attention (Mistral/Mixtral)
+    # Per-layer attention kind ("sliding_attention" | "full_attention", the
+    # HF ``layer_types``); None: every layer sliding under swa_window, else
+    # every layer full.  Only the first n_layers entries are read.
+    layer_types: Optional[tuple[str, ...]] = None
+    rope_full_layers: bool = True   # False: full-attention layers take no
+    #                                 rotary embedding (NoPE), window layers do
+    qk_norm: bool = False           # per-head RMSNorm of q and k
+    attn_gate: bool = False         # attention output times sigmoid(x Wg)
+    sandwich_norm: bool = False     # RMSNorm of the attention and FFN outputs
+    #                                 before each residual add
+    embed_scale: bool = False       # embeddings times sqrt(d_model) (muP)
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid_attn_every: int = 0      # zamba2: shared attn block every k ssm layers
@@ -219,6 +242,19 @@ class ModelConfig:
         """Can this arch run long_500k? (SSM/hybrid or sliding-window attn)."""
         return self.family in ("ssm", "hybrid") or self.swa_window is not None
 
+    def sliding_layers(self) -> tuple[bool, ...]:
+        """Per layer: does it attend over the sliding window?"""
+        if self.layer_types is None:
+            return (self.swa_window is not None,) * self.n_layers
+        kinds = tuple(self.layer_types[:self.n_layers])
+        if len(kinds) != self.n_layers or not set(kinds) <= {
+                "sliding_attention", "full_attention"}:
+            raise ValueError(f"layer_types {self.layer_types!r} for "
+                             f"{self.n_layers} layers")
+        if "sliding_attention" in kinds and self.swa_window is None:
+            raise ValueError("sliding_attention layers need swa_window")
+        return tuple(k == "sliding_attention" for k in kinds)
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -232,6 +268,8 @@ class ModelConfig:
             n += d * v                               # lm head
         per_attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
             + (self.n_heads * hd) * d
+        if self.attn_gate:
+            per_attn += d * self.n_heads * hd
         if self.qkv_bias:
             per_attn += (self.n_heads + 2 * self.n_kv_heads) * hd
         def ffn_params(dff):
@@ -245,7 +283,7 @@ class ModelConfig:
             moe_layers = self.n_layers - m.first_k_dense
             n += self.n_layers * (per_attn + 2 * d)
             n += m.first_k_dense * ffn_params(self.d_ff)
-            n += moe_layers * (m.n_experts + m.n_shared_experts) * ffn_params(m.d_ff)
+            n += moe_layers * (m.resolved_held + m.n_shared_experts) * ffn_params(m.d_ff)
             n += moe_layers * d * m.n_experts        # router
         elif self.family in ("ssm", "hybrid"):
             s = self.ssm
@@ -273,7 +311,8 @@ class ModelConfig:
             return (3 if self.act == "silu_glu" else 2) * d * dff
         total = self.param_count()
         moe_layers = self.n_layers - m.first_k_dense
-        inactive = moe_layers * (m.n_experts - m.top_k) * ffn_params(m.d_ff)
+        active = m.top_k * m.resolved_held // m.n_experts
+        inactive = moe_layers * (m.resolved_held - active) * ffn_params(m.d_ff)
         return total - inactive
 
 

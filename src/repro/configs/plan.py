@@ -61,6 +61,15 @@ GROUPED_SITES: dict[str, tuple[str, ...]] = {
 }
 
 
+def group_members(cfg: ModelConfig, site: str) -> tuple[str, ...]:
+    """The member matrices of a grouped site in this model: a gated
+    attention's wg joins the ``attn.qkv`` launch as a fourth member."""
+    members = GROUPED_SITES.get(site, ())
+    if site == "attn.qkv" and cfg.attn_gate:
+        members += ("wg",)
+    return members
+
+
 def site_group_width(site: str) -> int:
     """How many projection matrices one launch of this site covers."""
     return len(GROUPED_SITES.get(site, ())) or 1
@@ -113,7 +122,8 @@ def site_linear_shapes(cfg: ModelConfig) -> dict[str, dict]:
         return {
             "attn.qkv": {"matrices": ((d, cfg.n_heads * hd),
                                       (d, cfg.n_kv_heads * hd),
-                                      (d, cfg.n_kv_heads * hd)),
+                                      (d, cfg.n_kv_heads * hd))
+                         + ((d, cfg.n_heads * hd),) * cfg.attn_gate,
                          "per_token": layers},
             "attn.out": {"matrices": ((cfg.n_heads * hd, d),),
                          "per_token": layers},
@@ -133,11 +143,13 @@ def site_linear_shapes(cfg: ModelConfig) -> dict[str, dict]:
             base["ffn.out"]["per_token"] = m.first_k_dense
         shapes.update(base)
         moe_layers = cfg.n_layers - m.first_k_dense
+        # Of a token's top_k experts, those held here (on average).
+        active = max(1, m.top_k * m.resolved_held // m.n_experts)
         shapes["moe.expert.in"] = {
-            "matrices": ((d, m.d_ff),) * (n_in * m.top_k),
+            "matrices": ((d, m.d_ff),) * (n_in * active),
             "per_token": moe_layers}
         shapes["moe.expert.out"] = {
-            "matrices": ((m.d_ff, d),) * m.top_k, "per_token": moe_layers}
+            "matrices": ((m.d_ff, d),) * active, "per_token": moe_layers}
         if m.n_shared_experts:
             shapes["moe.shared.in"] = {
                 "matrices": ((d, m.d_ff),) * (n_in * m.n_shared_experts),

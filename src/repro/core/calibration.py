@@ -69,9 +69,18 @@ class CalibrationState:
     @classmethod
     def from_collected(cls, collected: dict[str, np.ndarray],
                        floor: float = 1e-9) -> "CalibrationState":
-        return cls(windows={
-            site: jnp.asarray(np.maximum(np.asarray(v, np.float32), floor))
-            for site, v in sorted(collected.items())})
+        """Windows from a capture's max|z| records.  A tile of a per-tile
+        window that the capture gave no charge at all (an expert that no
+        calibration row was routed to) takes the largest window of its
+        site."""
+        def window(v):
+            v = np.asarray(v, np.float32)
+            if v.ndim and v.size:
+                v = np.where(v == 0.0, v.max(), v)
+            return jnp.asarray(np.maximum(v, floor))
+
+        return cls(windows={site: window(v)
+                            for site, v in sorted(collected.items())})
 
     def as_arrays(self) -> dict[str, jax.Array]:
         """Site -> f32 window *array* (the runtime-operand form consumed by
@@ -296,12 +305,12 @@ def apply_calibration(cfg: ModelConfig,
     """
     if calib is None or not calib.windows:
         return cfg
-    from repro.configs.plan import GROUPED_SITES
+    from repro.configs.plan import group_members
     plan = cfg.tdvmm_plan if cfg.tdvmm_plan is not None else TDVMMPlan()
     rules = []
     for site in sorted(calib.windows):
         window = _host_window(calib.windows[site])
-        members = GROUPED_SITES.get(site)
+        members = group_members(cfg, site)
         if members and isinstance(window, tuple) and len(window) != len(members):
             raise ValueError(
                 f"grouped site {site!r}: calibration captured "

@@ -1,5 +1,12 @@
 """Multi-head attention: GQA/MHA, sliding-window, KV cache prefill/decode.
 
+Optional per model: per-head RMSNorm of q and k (``qk_norm``), an output
+gate ``sigmoid(x Wg)`` whose projection joins the ``attn.qkv`` launch
+(``attn_gate``), and no rotary embedding on full-attention layers
+(``rope_full_layers=False``).  A layer of a model with per-layer kinds
+sees the window only where it is a sliding layer
+(``transformer.segment_config``).
+
 Weights are stored flattened, (d_model, n_heads*head_dim), so the TP dimension
 divides evenly on a 16-way model axis for every assigned arch (e.g. yi-34b's
 56 heads x 128 = 7168); GSPMD handles the per-head einsum resharding.
@@ -51,12 +58,19 @@ def init(key, cfg: ModelConfig, dtype):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     kq, kk, kv, ko = jax.random.split(key, 4)
     bias = cfg.qkv_bias
-    return {
+    p = {
         "wq": common.dense_init(kq, d, cfg.n_heads * hd, dtype, bias=bias),
         "wk": common.dense_init(kk, d, cfg.n_kv_heads * hd, dtype, bias=bias),
         "wv": common.dense_init(kv, d, cfg.n_kv_heads * hd, dtype, bias=bias),
         "wo": common.dense_init(ko, cfg.n_heads * hd, d, dtype),
     }
+    if cfg.attn_gate:
+        p["wg"] = common.dense_init(jax.random.fold_in(key, 1), d,
+                                    cfg.n_heads * hd, dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = common.rmsnorm_init(hd, dtype)
+        p["k_norm"] = common.rmsnorm_init(hd, dtype)
+    return p
 
 
 def _split_heads(x: jax.Array, n: int, hd: int) -> jax.Array:
@@ -68,14 +82,38 @@ def _qkv(params, x: jax.Array, cfg: ModelConfig, key):
 
     The shared input is encoded once and wq/wk/wv run as three tiles of a
     single batched kernel dispatch — the paper's shared-DAC amortization —
-    instead of three ``dense`` calls that each re-encode x."""
+    instead of three ``dense`` calls that each re-encode x.  A gated layer's
+    wg is a fourth member of the same launch; its output is the gate's
+    pre-activation (else None)."""
     td = cfg.site_tdvmm("attn.qkv")
     hd = cfg.resolved_head_dim
-    q, k, v = common.dense_group(
-        (params["wq"], params["wk"], params["wv"]), x, td, key)
+    ws = (params["wq"], params["wk"], params["wv"])
+    if cfg.attn_gate:
+        ws += (params["wg"],)
+    q, k, v, *g = common.dense_group(ws, x, td, key)
     return (_split_heads(q, cfg.n_heads, hd),
             _split_heads(k, cfg.n_kv_heads, hd),
-            _split_heads(v, cfg.n_kv_heads, hd))
+            _split_heads(v, cfg.n_kv_heads, hd),
+            _split_heads(g[0], cfg.n_heads, hd) if g else None)
+
+
+def _project(params, x: jax.Array, cfg: ModelConfig, positions, key):
+    """q, k, v and the gate of a layer: per-head RMSNorm of q and k
+    (``qk_norm``), then the rotary embedding unless the layer attends over
+    every position of a model whose full layers take none (NoPE)."""
+    q, k, v, g = _qkv(params, x, cfg, key)
+    if cfg.qk_norm:
+        q = common.rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = common.rmsnorm(params["k_norm"], k, cfg.norm_eps)
+    if cfg.swa_window is not None or cfg.rope_full_layers:
+        q = common.apply_rope(q, positions, cfg.rope_theta)
+        k = common.apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v, g
+
+
+def _gated(out: jax.Array, g) -> jax.Array:
+    """The attention output, times sigmoid of the gate where there is one."""
+    return out if g is None else out * jax.nn.sigmoid(g)
 
 
 def _merge_heads(x: jax.Array) -> jax.Array:
@@ -301,16 +339,14 @@ def _causal_mask(sq: int, skv: int, offset: int, window: Optional[int]) -> jax.A
 def apply_train(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
                 key=None) -> jax.Array:
     """Full-sequence causal (optionally sliding-window) attention."""
-    q, k, v = _qkv(params, x, cfg, key)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v, g = _project(params, x, cfg, positions, key)
     s = x.shape[1]
     if s > FLASH_THRESHOLD:
         out = _flash(q, k, v, cfg)
     else:
         mask = _causal_mask(s, s, 0, cfg.swa_window)
         out = _attend(q, k, v, mask, cfg)
-    return common.dense_tp_reduce(params["wo"], _merge_heads(out),
+    return common.dense_tp_reduce(params["wo"], _merge_heads(_gated(out, g)),
                                   cfg.site_tdvmm("attn.out"), key)
 
 
@@ -333,9 +369,7 @@ def apply_prefill(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
     """Process a full prompt, filling the cache (assumes cache.pos == 0)."""
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    q, k, v = _qkv(params, x, cfg, key)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v, g = _project(params, x, cfg, positions, key)
     if s > FLASH_THRESHOLD:
         out = _flash(q, k, v, cfg)
     else:
@@ -365,7 +399,7 @@ def apply_prefill(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
             k_sc = jnp.roll(k_sc[:, -size:], shift, axis=1)
             v_sc = jnp.roll(v_sc[:, -size:], shift, axis=1)
     new_cache = KVCache(new_k, new_v, jnp.full((b,), s, jnp.int32), k_sc, v_sc)
-    y = common.dense(params["wo"], _merge_heads(out),
+    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
                      cfg.site_tdvmm("attn.out"), key)
     return y, new_cache
 
@@ -395,11 +429,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     """One attention layer's page pool (+1 trash page per rank).  Honors the
     same KV_CACHE_INT8 switch as the dense cache.  ``ranks > 1`` stacks one
     ``num_pages + 1`` region per DP rank (see ``runtime.paged_cache.PagePool``
-    for the global page-id arithmetic)."""
-    if cfg.swa_window is not None:
-        raise NotImplementedError(
-            "paged KV cache does not support sliding-window archs yet "
-            "(the ring buffer already bounds their dense cache)")
+    for the global page-id arithmetic).  A window layer's ring pool has the
+    same layout, with its caller's count of ring pages."""
     shape = (ranks * (num_pages + 1), page_size,
              cfg.n_kv_heads * cfg.resolved_head_dim)
     if KV_CACHE_INT8:
@@ -411,36 +442,50 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def _paged_targets(ctx, pools: PagedKVCache, r: int
+def _paged_targets(ctx, pools: PagedKVCache, r: int, ring: bool = False
                    ) -> tuple[jax.Array, jax.Array]:
     """Where this step's R rows go: (page id, offset) per row, (R,) each.
 
     Every layer shares them (pages are allocated per slot, not per layer).
     ``ctx`` is a ``PrefillChunkCtx`` (R = C chunk rows; rows past ``valid``
     go to the trash page) or a ``DecodeCtx`` (R = B slots; inactive slots
-    go to the trash page)."""
+    go to the trash page).  With ``ring`` the rows go to the slot's window
+    ring: position p to ring page ``(p // page_size) % ring_pages``."""
     ps = pools.k.shape[2]
     trash = pools.k.shape[1] - 1
+
+    def page(pos, n_rows):
+        return (pos // ps) % n_rows if ring else jnp.minimum(pos // ps, n_rows - 1)
+
     if isinstance(ctx, PrefillChunkCtx):
         gpos = ctx.offset + jnp.arange(r, dtype=jnp.int32)
-        n_rows = ctx.block_row.shape[0]
-        pid = ctx.block_row[jnp.minimum(gpos // ps, n_rows - 1)]
+        row = ctx.ring_row if ring else ctx.block_row
+        pid = row[page(gpos, row.shape[0])]
         return jnp.where(jnp.arange(r) < ctx.valid, pid, trash), gpos % ps
-    n_rows = ctx.block_tables.shape[1]
-    page_idx = jnp.minimum(ctx.pos // ps, n_rows - 1)
-    pid = jnp.take_along_axis(ctx.block_tables, page_idx[:, None], 1)[:, 0]
+    tables = ctx.ring_tables if ring else ctx.block_tables
+    page_idx = page(ctx.pos, tables.shape[1])
+    pid = jnp.take_along_axis(tables, page_idx[:, None], 1)[:, 0]
     return jnp.where(ctx.active, pid, trash), ctx.pos % ps
 
 
+def _ring_positions(size: int, end) -> jax.Array:
+    """The position each of a window ring's ``size`` entries holds in a step
+    that has written every position below ``end``: the latest p < end with
+    p = entry (mod size); negative where the slot has no such position yet."""
+    j = jnp.arange(size, dtype=jnp.int32)
+    return end - 1 - jnp.remainder(end - 1 - j, size)
+
+
 @scope("kv.write")
-def paged_write(pools: PagedKVCache, rows: PagedKVCache, ctx) -> PagedKVCache:
+def paged_write(pools: PagedKVCache, rows: PagedKVCache, ctx,
+                ring: bool = False) -> PagedKVCache:
     """Write every layer's new rows into the stacked pools, once, after the
     layer scan: ``rows`` holds (L, R, ...) leaves (``_fresh_rows`` of each
     layer), ``pools`` the donated (L, pages, page_size, ...) pools, and row
-    r of layer l goes to ``(l, *_paged_targets(ctx, pools, R)[r])``.
+    r of layer l goes to ``(l, *_paged_targets(ctx, pools, R, ring)[r])``.
     One scatter per pool, in place on the donated pool (why not inside the
     scan: see ``transformer._scan_segment``)."""
-    pid, off = _paged_targets(ctx, pools, rows.k.shape[1])
+    pid, off = _paged_targets(ctx, pools, rows.k.shape[1], ring)
     layer = jnp.arange(pools.k.shape[0], dtype=jnp.int32)[:, None]
 
     def write(buf, val):
@@ -516,30 +561,38 @@ def apply_prefill_paged(params, x: jax.Array, cfg: ModelConfig,
     position, so those rows are never read.  Bit-for-bit identical to
     ``apply_prefill`` on the whole prompt when the chunk covers it AND the
     cache is not int8-quantized (per-row encode/attend; masked tail keys
-    contribute exact zeros).  Under KV_CACHE_INT8 this path attends over
+    contribute exact zeros).  A window layer (``cfg.swa_window``) reads and
+    writes its slot's window ring instead (``ctx.ring_row``): each ring
+    entry's position comes from ``_ring_positions``, and keys older than
+    the window are masked.  Under KV_CACHE_INT8 this path attends over
     the quantize->dequantize KV (earlier chunks can only be read back
     dequantized), whereas dense ``apply_prefill`` attends over the
     full-precision k/v before storing — the engine's isolation contract is
     therefore engine-vs-solo-engine in int8 mode, not engine-vs-dense."""
     _, c, _ = x.shape
     ps = pools.k.shape[2]
-    n_rows = ctx.block_row.shape[0]
+    window = cfg.swa_window
+    row = ctx.block_row if window is None else ctx.ring_row
+    n_rows = row.shape[0]
     gpos = ctx.offset + jnp.arange(c, dtype=jnp.int32)       # (C,) global
     positions = gpos[None]
-    q, k, v = _qkv(params, x, cfg, key)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v, g = _project(params, x, cfg, positions, key)
 
     rows, k_new, v_new = _fresh_rows(pools, k[0], v[0], q.dtype)
-    kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
+    if window is None:
+        kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
+    else:
+        kpos = _ring_positions(n_rows * ps, ctx.offset + c)
     end = ctx.offset + ctx.valid
     sel = (kpos >= ctx.offset) & (kpos < end)
     src = jnp.clip(kpos - ctx.offset, 0, c - 1)
-    k_read, v_read = _paged_read(pools, layer, ctx.block_row[None], k_new,
+    k_read, v_read = _paged_read(pools, layer, row[None], k_new,
                                  v_new, src[None], sel[None], q.dtype)
     mask = (kpos[None, :] <= gpos[:, None]) & (kpos[None, :] < end)
+    if window is not None:
+        mask &= (kpos[None, :] >= 0) & (kpos[None, :] > gpos[:, None] - window)
     out = _attend(q, k_read, v_read, mask[None, None], cfg)
-    y = common.dense(params["wo"], _merge_heads(out),
+    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
                      cfg.site_tdvmm("attn.out"), key)
     return y, rows
 
@@ -553,29 +606,37 @@ def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
     the attention output and this layer's B new rows, which
     ``paged_write`` stores after the layer scan.
 
-    Each slot attends over its own gathered pages with its new row merged
+    Each slot attends over its own gathered pages (a window layer: its
+    window ring, ``ctx.ring_tables``) with its new row merged
     in at position ``pos``; inactive slots' rows go to the trash page,
     never advance, and produce ignored outputs.  There is NO
     decode-past-capacity poisoning path here: the engine evicts a request
     *before* its next write would overflow its page budget, so an
     overflowing write can never corrupt (or NaN) a neighbor slot."""
     ps = pools.k.shape[2]
-    n_rows = ctx.block_tables.shape[1]
+    window = cfg.swa_window
+    tables = ctx.block_tables if window is None else ctx.ring_tables
+    n_rows = tables.shape[1]
     pos = ctx.pos
     positions = pos[:, None]
-    q, k, v = _qkv(params, x, cfg, key)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v, g = _project(params, x, cfg, positions, key)
 
     rows, k_new, v_new = _fresh_rows(pools, k[:, 0], v[:, 0], q.dtype)
-    kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
-    sel = kpos[None, :] == pos[:, None]                       # (B, cap)
+    if window is None:
+        kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
+        sel = kpos[None, :] == pos[:, None]                   # (B, cap)
+    else:
+        kpos = _ring_positions(n_rows * ps, pos[:, None] + 1)  # (B, cap)
+        sel = kpos == pos[:, None]
     src = jnp.arange(pos.shape[0])[:, None]                   # (B, 1)
-    k_read, v_read = _paged_read(pools, layer, ctx.block_tables, k_new,
+    k_read, v_read = _paged_read(pools, layer, tables, k_new,
                                  v_new, src, sel, q.dtype)
-    mask = (kpos[None, :] <= pos[:, None])[:, None, None, :]  # (B,1,1,cap)
-    out = _attend(q, k_read, v_read, mask, cfg)
-    y = common.dense(params["wo"], _merge_heads(out),
+    if window is None:
+        mask = kpos[None, :] <= pos[:, None]
+    else:   # the ring holds no position past pos
+        mask = (kpos >= 0) & (kpos > pos[:, None] - window)
+    out = _attend(q, k_read, v_read, mask[:, None, None, :], cfg)  # (B,1,1,cap)
+    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
                      cfg.site_tdvmm("attn.out"), key)
     return y, rows
 
@@ -586,9 +647,7 @@ def apply_decode(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
     b = x.shape[0]
     pos = cache.pos                                      # (B,) int32
     positions = pos[:, None]                             # (B, 1)
-    q, k, v = _qkv(params, x, cfg, key)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v, g = _project(params, x, cfg, positions, key)
 
     size = cache.k.shape[1]
     if cfg.swa_window is not None:
@@ -649,7 +708,7 @@ def apply_decode(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
         valid = kpos[None, :] <= pos[:, None]
     mask = valid[:, None, None, :]                       # (B, 1, 1, S)
     out = _attend(q, k_read, v_read, mask, cfg)
-    y = common.dense(params["wo"], _merge_heads(out),
+    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
                      cfg.site_tdvmm("attn.out"), key)
     pos_next = pos + 1
     if over is not None:

@@ -29,7 +29,10 @@ def init_params(key, cfg: ModelConfig) -> dict:
 
 def _embed(params, batch: dict, cfg: ModelConfig) -> jax.Array:
     if cfg.input_mode == "tokens":
-        return params["embed"]["table"][batch["inputs"]]
+        x = params["embed"]["table"][batch["inputs"]]
+        if cfg.embed_scale:
+            x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(x.dtype)
+        return x
     return batch["inputs"].astype(common.resolve_dtype(cfg.dtype))
 
 
@@ -75,9 +78,11 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, key=None,
 # --------------------------------------------------------------------------
 def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     dtype = common.resolve_dtype(cfg.dtype)
+    sliding = transformer.segment_sliding(cfg)
 
-    def one_attn():
-        return attention.init_cache(cfg, batch, max_len, dtype)
+    def one_attn(i=0):
+        scfg = transformer.segment_config(cfg, sliding[i])
+        return attention.init_cache(scfg, batch, max_len, dtype)
 
     def one_ssm():
         return ssm.init_cache(cfg, batch, dtype)
@@ -88,7 +93,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     caches: dict[str, Any] = {}
     for i, (kind, n) in enumerate(transformer.segments(cfg)):
         if kind in ("attn_ffn", "attn_moe"):
-            caches[f"seg{i}"] = stack(one_attn, n)
+            caches[f"seg{i}"] = stack(lambda i=i: one_attn(i), n)
         elif kind == "ssm":
             caches[f"seg{i}"] = stack(one_ssm, n)
         elif kind == "hybrid":
@@ -139,13 +144,17 @@ def decode_step(params, batch: dict, caches: dict, cfg: ModelConfig,
 # Paged serving (continuous-batching engine, runtime/engine.py)
 # --------------------------------------------------------------------------
 def init_paged_caches(cfg: ModelConfig, num_pages: int, page_size: int,
-                      ranks: int = 1) -> dict:
+                      ranks: int = 1, ring_pages: int = 0) -> dict:
     """Page pools for every attention layer (attention families only).
 
     Unlike ``init_caches`` there is no batch/max_len here: capacity is the
     shared pool, and per-request footprint is decided at admission time by
     the engine's block tables.  All layers share one logical page allocation
     (the same page id addresses the same token range in every layer's pool).
+    Window layers' segments hold window rings instead, ``ring_pages`` pages
+    in all (``runtime.paged_cache.ring_pages`` a slot).  An MoE model's
+    caches also carry ``moe_routed``: rows its steps routed to the experts
+    this chip holds, summed on the device.
     """
     if cfg.family not in ("dense", "moe", "vlm", "audio"):
         raise NotImplementedError(
@@ -153,22 +162,30 @@ def init_paged_caches(cfg: ModelConfig, num_pages: int, page_size: int,
             "(SSM state is O(1) per slot; use the static path)")
     dtype = common.resolve_dtype(cfg.dtype)
 
-    def one_attn():
-        return attention.init_paged_cache(cfg, num_pages, page_size, dtype,
-                                          ranks=ranks)
-
-    def stacked(n):
+    def stacked(n, pages):
         # One zero allocation per stacked pool: stacking n per-layer pools
         # would hold twice the pools' bytes while it copies.
         return jax.tree.map(lambda a: jnp.zeros((n,) + a.shape, a.dtype),
-                            jax.eval_shape(one_attn))
+                            jax.eval_shape(lambda: attention.init_paged_cache(
+                                cfg, pages, page_size, dtype, ranks=ranks)))
 
+    sliding = transformer.segment_sliding(cfg)
+    if any(sliding) and ring_pages < 1:
+        raise ValueError("window layers need ring_pages > 0")
     caches: dict[str, Any] = {}
     for i, (kind, n) in enumerate(transformer.segments(cfg)):
         if kind not in ("attn_ffn", "attn_moe"):
             raise NotImplementedError(f"paged serving: segment kind {kind!r}")
-        caches[f"seg{i}"] = stacked(n)
+        caches[f"seg{i}"] = stacked(n, ring_pages if sliding[i] else num_pages)
+    if cfg.family == "moe":
+        caches["moe_routed"] = jnp.zeros((), jnp.int32)
     return caches
+
+
+def _count_routed(caches: dict, new_caches: dict, aux: dict) -> None:
+    """Carry the device-side count of routed expert rows across a step."""
+    if "moe_routed" in caches:
+        new_caches["moe_routed"] = caches["moe_routed"] + aux["moe_routed"]
 
 
 def prefill_chunk(params, batch: dict, caches: dict, cfg: ModelConfig,
@@ -189,12 +206,14 @@ def prefill_chunk(params, batch: dict, caches: dict, cfg: ModelConfig,
     from repro.runtime.paged_cache import PrefillChunkCtx
     cfg = apply_calibration(cfg, calib)
     ctx = PrefillChunkCtx(block_row=batch["block_row"],
-                          offset=batch["offset"], valid=batch["valid"])
+                          offset=batch["offset"], valid=batch["valid"],
+                          ring_row=batch.get("ring_row"))
     with calibration.runtime_windows(windows):
         x = _embed(params, batch, cfg)
-        x, new_caches, _ = transformer.apply(params["blocks"], x, cfg,
-                                             "prefill_paged", caches, None,
-                                             embed0=x, page_ctx=ctx)
+        x, new_caches, aux = transformer.apply(params["blocks"], x, cfg,
+                                               "prefill_paged", caches, None,
+                                               embed0=x, page_ctx=ctx)
+        _count_routed(caches, new_caches, aux)
         # logits only at the chunk's last real token (== prefill_step's
         # x[:, -1:] on the final chunk); padded rows never reach the head.
         with scope("head"):
@@ -215,12 +234,13 @@ def decode_slots(params, batch: dict, caches: dict, cfg: ModelConfig,
     from repro.runtime.paged_cache import DecodeCtx
     cfg = apply_calibration(cfg, calib)
     ctx = DecodeCtx(block_tables=batch["block_tables"], pos=batch["pos"],
-                    active=batch["active"])
+                    active=batch["active"], ring_tables=batch.get("ring_tables"))
     with calibration.runtime_windows(windows):
         x = _embed(params, batch, cfg)
-        x, new_caches, _ = transformer.apply(params["blocks"], x, cfg,
-                                             "decode_paged", caches, None,
-                                             embed0=x, page_ctx=ctx)
+        x, new_caches, aux = transformer.apply(params["blocks"], x, cfg,
+                                               "decode_paged", caches, None,
+                                               embed0=x, page_ctx=ctx)
+        _count_routed(caches, new_caches, aux)
         with scope("head"):
             x = common.rmsnorm(params["ln_f"], x, cfg.norm_eps)
             return _head(params, x, cfg), new_caches

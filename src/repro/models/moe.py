@@ -18,6 +18,22 @@ Dispatch is sort-based (argsort by expert id + rank-in-group + scatter into an
 (E, capacity, d) buffer): no one-hot dispatch tensors, so it scales to E=384.
 Both modes run inside shard_map; on a single device (tests) the same math runs
 without collectives.
+
+Training drops rows past ``capacity_factor``; serving and calibration
+(``dropless``) size each expert's buffer at the step's rows, the most one
+expert can get (a row picks an expert at most once), so no row is dropped
+and a row's result does not depend on what shares its step.
+
+An expert layer may hold a share of the experts (``MoEConfig.held`` from
+``held_offset``, one chip's share under expert parallelism): it routes
+every row over all ``n_experts`` and computes only its held experts' part;
+a selection of an expert held elsewhere is left out, its weight unspent.
+On one chip there is no exchange.
+
+Router: softmax or sigmoid scores in float32, top-k selection on the
+scores (plus a per-expert selection bias, ``router/b``, where
+``selection_bias``), weights from the scores alone, normalised over the k
+and scaled (``route_scale``).
 """
 from __future__ import annotations
 
@@ -32,6 +48,7 @@ from repro.configs.base import ModelConfig
 from repro.core import calibration
 from repro.launch import meshctx
 from repro.models import common
+from repro.runtime.trace import scope
 
 
 def init(key, cfg: ModelConfig, dtype):
@@ -52,8 +69,9 @@ def init(key, cfg: ModelConfig, dtype):
         return p
 
     p = {
-        "router": common.dense_init(keys[0], d, m.n_experts, jnp.float32),
-        "experts": expert_bank(keys[1], m.n_experts),
+        "router": common.dense_init(keys[0], d, m.n_experts, jnp.float32,
+                                    bias=m.selection_bias),
+        "experts": expert_bank(keys[1], m.resolved_held),
     }
     if m.n_shared_experts:
         p["shared"] = expert_bank(keys[2], m.n_shared_experts)
@@ -107,22 +125,39 @@ def _expert_ffn(bank, x, cfg: ModelConfig, tp_axis: Optional[str], key=None,
     return y
 
 
+@scope("moe.route")
 def _route(params, x_flat, cfg: ModelConfig):
-    """Router: returns (ids (T,K), gates (T,K), aux losses)."""
+    """Router, digital, in float32: returns (ids (T,K) over all n_experts,
+    float32 weights (T,K), aux losses)."""
     m = cfg.moe
-    logits = (x_flat.astype(jnp.float32) @ params["router"]["w"])      # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    logits = jnp.dot(x_flat.astype(jnp.float32), params["router"]["w"],
+                     precision=jax.lax.Precision.HIGHEST)              # (T, E)
+    if m.score_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    choice = probs + params["router"]["b"] if m.selection_bias else probs
+    _, ids = jax.lax.top_k(choice, m.top_k)
+    gates = jnp.take_along_axis(probs, ids, axis=-1)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9) * m.route_scale
     # Switch-style load-balance loss + router z-loss.  Expert counts via
     # scatter-add, NOT one_hot: a (T, K, E) one-hot is ~100 MB per layer per
-    # microbatch at kimi-k2 scale (perf it.4, EXPERIMENTS.md §Perf).
+    # microbatch at kimi-k2 scale.
     me = jnp.mean(probs, axis=0)                                       # (E,)
     counts = jnp.zeros((m.n_experts,), jnp.float32).at[ids.reshape(-1)].add(1.0)
     ce = counts / ids.shape[0]
     lb_loss = m.n_experts * jnp.sum(me * ce)
     z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    return ids, gates.astype(x_flat.dtype), {"lb_loss": lb_loss, "z_loss": z_loss}
+    return ids, gates, {"lb_loss": lb_loss, "z_loss": z_loss}
+
+
+def _held_ids(ids: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Each selected expert's index among the experts this layer holds;
+    ``held`` (one past the last) for an expert held elsewhere."""
+    m = cfg.moe
+    local = ids - m.held_offset
+    return jnp.where((local >= 0) & (local < m.resolved_held), local,
+                     m.resolved_held)
 
 
 def _dispatch_indices(ids: jax.Array, top_k: int):
@@ -151,22 +186,31 @@ def _gather_from_buffer(buf, sorted_expert, pos, order, gates, top_k):
     Unsorting uses the inverse permutation as a GATHER (perf it.4): a scatter
     into a zeros buffer costs an extra zero-fill + random-write pass."""
     vals = buf[sorted_expert, jnp.minimum(pos, buf.shape[1] - 1)]      # (T*K, d)
-    vals = jnp.where((pos < buf.shape[1])[:, None], vals, 0.0)
+    kept = (pos < buf.shape[1]) & (sorted_expert < buf.shape[0])
+    vals = jnp.where(kept[:, None], vals, 0.0)
     inv_order = jnp.argsort(order)
     unsorted = vals[inv_order]
     per_k = unsorted.reshape(-1, top_k, vals.shape[-1])
-    return jnp.sum(per_k * gates[..., None].astype(vals.dtype), axis=1)
+    return jnp.sum(per_k.astype(jnp.float32) * gates[..., None].astype(
+        jnp.float32), axis=1).astype(vals.dtype)
 
 
-def _moe_local(params, x_flat, cfg: ModelConfig, tp_axis, key=None):
-    """Experts replicated over DP; only collective is the tp psum."""
+def _moe_local(params, x_flat, cfg: ModelConfig, tp_axis, key=None,
+               dropless: bool = False):
+    """Experts replicated over DP (or the held share); only collective is
+    the tp psum.  aux gains ``moe_routed``: the buffer rows filled."""
     m = cfg.moe
+    held = m.resolved_held
     ids, gates, aux = _route(params, x_flat, cfg)
-    cap = _capacity(x_flat.shape[0], m.top_k, m.n_experts, m.capacity_factor)
-    se, pos, order, tok = _dispatch_indices(ids, m.top_k)
-    buf = _scatter_to_buffer(x_flat, se, pos, tok, m.n_experts, cap)
+    cap = x_flat.shape[0] if dropless else _capacity(
+        x_flat.shape[0], m.top_k, m.n_experts, m.capacity_factor)
+    with scope("moe.dispatch"):
+        se, pos, order, tok = _dispatch_indices(_held_ids(ids, cfg), m.top_k)
+        buf = _scatter_to_buffer(x_flat, se, pos, tok, held, cap)
+        aux["moe_routed"] = jnp.sum((se < held) & (pos < cap), dtype=jnp.int32)
     out = _expert_ffn(params["experts"], buf, cfg, tp_axis, key=key)
-    y = _gather_from_buffer(out, se, pos, order, gates, m.top_k)
+    with scope("moe.combine"):
+        y = _gather_from_buffer(out, se, pos, order, gates, m.top_k)
     return y, aux
 
 
@@ -192,10 +236,12 @@ def _moe_ep(params, x_flat, cfg: ModelConfig, tp_axis, dp_axes, dp_size,
     return y, aux
 
 
-def apply(params, x: jax.Array, cfg: ModelConfig, key=None) -> tuple[jax.Array, dict]:
+def apply(params, x: jax.Array, cfg: ModelConfig, key=None,
+          dropless: bool = False) -> tuple[jax.Array, dict]:
     """x: (B, S, d) -> (y, aux_losses).  ``key`` enables train-time TD-VMM
     programming noise on the expert (and shared-expert) matmuls when the
-    resolved ``moe.expert.*`` / ``moe.shared.*`` site configs set noise."""
+    resolved ``moe.expert.*`` / ``moe.shared.*`` site configs set noise.
+    ``dropless``: every expert's buffer holds the call's rows (serving)."""
     m = cfg.moe
     b, s, d = x.shape
     mesh = meshctx.get_mesh()
@@ -221,8 +267,11 @@ def apply(params, x: jax.Array, cfg: ModelConfig, key=None) -> tuple[jax.Array, 
         # NB: shared-expert tp reduction is handled by GSPMD outside shard_map.
 
     if mesh is None:
-        y, aux = _moe_local(params, x.reshape(-1, d), cfg, None, key=k_routed)
+        y, aux = _moe_local(params, x.reshape(-1, d), cfg, None, key=k_routed,
+                            dropless=dropless)
         return y.reshape(b, s, d) + shared_y, aux
+    if m.resolved_held != m.n_experts:
+        raise NotImplementedError("a held share of the experts under a mesh")
 
     dp = meshctx.dp_axes()
     tp = meshctx.tp_axis()
@@ -274,7 +323,9 @@ def apply(params, x: jax.Array, cfg: ModelConfig, key=None) -> tuple[jax.Array, 
                         kk = jax.random.fold_in(kk, jax.lax.axis_index(a))
                 y, aux = _moe_ep(p, flat, cfg, tp, dp, dp_size, key=kk)
             else:
-                y, aux = _moe_local(p, flat, cfg, tp, key=kk)
+                y, aux = _moe_local(p, flat, cfg, tp, key=kk,
+                                    dropless=dropless)
+                aux.pop("moe_routed")       # a per-shard count
         aux = jax.tree.map(lambda v: jax.lax.pmean(v, dp), aux)
         return y.reshape(xb.shape), aux
 

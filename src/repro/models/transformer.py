@@ -3,6 +3,10 @@
 Stack layouts per family:
   dense/vlm/audio : n_layers x [attn + ffn]                    (one scanned seg)
   moe             : first_k_dense x [attn + ffn] + rest x [attn + moe]
+  layer_types set : one segment per run of layers of one block kind and one
+                    attention kind (window or full); Trinity's first 8:
+                    2 dense window | 1 moe window | 1 moe full | 3 moe window
+                    | 1 moe full
   ssm             : n_layers x [mamba2]
   hybrid (zamba2) : groups of `hybrid_attn_every` mamba2 layers, a SHARED
                     attention+ffn block (single param set, reused) after each
@@ -52,13 +56,19 @@ def attn_ffn_block(params, x, cfg: ModelConfig, mode: str, cache, positions, key
             params["attn"], h, cfg, *cache, page_ctx, key)
     else:
         a, new_cache = attention.apply_decode(params["attn"], h, cfg, cache, key)
+    if cfg.sandwich_norm:
+        a = common.rmsnorm(params["ln_post_attn"], a, cfg.norm_eps)
     x = x + a
     h = common.rmsnorm(params["ln2"], x, cfg.norm_eps)
     aux = {}
     if "moe" in params:
-        f, aux = moe.apply(params["moe"], h, cfg, key)
+        # Serving and calibration never drop a row: what a request gets
+        # must not depend on what shares its step.
+        f, aux = moe.apply(params["moe"], h, cfg, key, dropless=mode != "train")
     else:
         f = ffn.apply(params["ffn"], h, cfg, key)
+    if cfg.sandwich_norm:
+        f = common.rmsnorm(params["ln_post_ffn"], f, cfg.norm_eps)
     return x + f, new_cache, aux
 
 
@@ -86,6 +96,9 @@ def _init_attn_ffn(key, cfg: ModelConfig, use_moe: bool, dtype):
         "ln2": common.rmsnorm_init(cfg.d_model, dtype),
         "attn": attention.init(k1, cfg, dtype),
     }
+    if cfg.sandwich_norm:
+        p["ln_post_attn"] = common.rmsnorm_init(cfg.d_model, dtype)
+        p["ln_post_ffn"] = common.rmsnorm_init(cfg.d_model, dtype)
     if use_moe:
         p["moe"] = moe.init(k2, cfg, dtype)
     else:
@@ -104,6 +117,8 @@ def _init_ssm(key, cfg: ModelConfig, dtype):
 # Segments: (kind, n_layers) with stacked params
 # --------------------------------------------------------------------------
 def segments(cfg: ModelConfig) -> list[tuple[str, int]]:
+    if cfg.layer_types is not None:
+        return [(kind, n) for kind, n, _ in _typed_runs(cfg)]
     if cfg.family in ("dense", "vlm", "audio"):
         return [("attn_ffn", cfg.n_layers)]
     if cfg.family == "moe":
@@ -118,6 +133,40 @@ def segments(cfg: ModelConfig) -> list[tuple[str, int]]:
     if cfg.family == "hybrid":
         return [("hybrid", cfg.n_layers)]
     raise ValueError(cfg.family)
+
+
+def _typed_runs(cfg: ModelConfig) -> list[tuple[str, int, bool]]:
+    """Per-layer attention kinds: one segment per run of layers with the
+    same block kind and the same attention kind, (kind, n, sliding) each,
+    so that every scan is homogeneous."""
+    if cfg.family not in ("dense", "moe"):
+        raise ValueError(f"layer_types on family {cfg.family!r}")
+    dense = cfg.moe.first_k_dense if cfg.family == "moe" else cfg.n_layers
+    runs: list[list] = []
+    for layer, sliding in enumerate(cfg.sliding_layers()):
+        kind = "attn_ffn" if layer < dense else "attn_moe"
+        if runs and runs[-1][0] == kind and runs[-1][2] == sliding:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1, sliding])
+    return [tuple(r) for r in runs]
+
+
+def segment_sliding(cfg: ModelConfig) -> list[bool]:
+    """Per segment of ``segments(cfg)``: do its layers attend over the
+    sliding window (and keep their KV in a window ring when served)?"""
+    if cfg.layer_types is None:
+        return [cfg.swa_window is not None] * len(segments(cfg))
+    return [sliding for *_, sliding in _typed_runs(cfg)]
+
+
+def segment_config(cfg: ModelConfig, sliding: bool) -> ModelConfig:
+    """The configuration one segment's layers run under: a model with
+    per-layer kinds keeps its window on sliding segments only."""
+    if cfg.layer_types is None:
+        return cfg
+    return cfg.replace(layer_types=None,
+                       swa_window=cfg.swa_window if sliding else None)
 
 
 def _stacked_init(fn, key, n: int):
@@ -182,26 +231,34 @@ def apply(params, x: jax.Array, cfg: ModelConfig, mode: str,
     aux_total = {"lb_loss": jnp.zeros((), jnp.float32),
                  "z_loss": jnp.zeros((), jnp.float32)}
     paged = mode in ("prefill_paged", "decode_paged")
+    sliding = segment_sliding(cfg)
 
     for i, (kind, n) in enumerate(segments(cfg)):
         seg_params = params[f"seg{i}"]
         seg_cache = None if caches is None else caches.get(f"seg{i}")
 
         if kind in ("attn_ffn", "attn_moe"):
-            def body(p, h, c, _pools=seg_cache):
+            keys = ("lb_loss", "z_loss") + (
+                ("moe_routed",) if kind == "attn_moe" else ())
+
+            def body(p, h, c, _pools=seg_cache,
+                     _cfg=segment_config(cfg, sliding[i]), _keys=keys):
                 c = (_pools, c) if paged else c
-                h2, nc, aux = attn_ffn_block(p, h, cfg, mode, c, positions, key,
+                h2, nc, aux = attn_ffn_block(p, h, _cfg, mode, c, positions, key,
                                              page_ctx=page_ctx)
-                aux = {k2: aux.get(k2, jnp.zeros((), jnp.float32))
-                       for k2 in ("lb_loss", "z_loss")}
+                aux = {k2: aux.get(k2, jnp.zeros(
+                    (), jnp.int32 if k2 == "moe_routed" else jnp.float32))
+                       for k2 in _keys}
                 return h2, nc, aux
             x, nc, auxs = _scan_segment(
                 body, seg_params, x,
                 jnp.arange(n, dtype=jnp.int32) if paged else seg_cache, cfg)
             if kind == "attn_moe":
-                aux_total = {k2: aux_total[k2] + jnp.sum(auxs[k2]) for k2 in aux_total}
+                aux_total = {k2: aux_total.get(k2, 0) + jnp.sum(auxs[k2])
+                             for k2 in keys}
             if paged:
-                nc = attention.paged_write(seg_cache, nc, page_ctx)
+                nc = attention.paged_write(seg_cache, nc, page_ctx,
+                                           ring=sliding[i])
             new_caches[f"seg{i}"] = nc
 
         elif kind == "ssm":

@@ -11,6 +11,8 @@ absorbed by:
   * a **paged** KV cache: attention KV lives in fixed-size pages owned per
     request via block tables (``runtime/paged_cache.py``), so short requests
     stop paying ``max_len`` memory and finished requests' pages recycle;
+    a model's window layers keep theirs in a fixed ring of pages per slot
+    (``paged_cache.ring_pages``: the window plus one chunk);
   * **chunked prefill**: prompts are absorbed ``chunk`` tokens per step
     through the single compiled prefill shape, interleaved with decode.
 
@@ -115,11 +117,11 @@ from repro.kernels.tdvmm import ops as tdvmm_ops
 from repro.launch import meshctx
 from repro.launch import sharding as shardlib
 from repro.launch.mesh import axis_info
-from repro.models import model
+from repro.models import model, transformer
 from repro.runtime import fault
 from repro.runtime import sla as sla_policy
 from repro.runtime import trace
-from repro.runtime.paged_cache import PagePool, pages_for
+from repro.runtime.paged_cache import PagePool, pages_for, ring_pages
 from repro.runtime.scheduler import (Request, RequestRecord, Slot,
                                      SlotScheduler, static_baseline)
 
@@ -279,8 +281,15 @@ class RunState:
     last_clip_obs: int = 0
     wall_s: float = 0.0
     util_samples: list = dataclasses.field(default_factory=list)
-    kv_pages_read: int = 0        # pages the steps' KV gathers touched
+    kv_pages_read: int = 0        # pages the steps' KV gathers touched (one
+    #                               layer of each pool kind: full, window ring)
     kv_pages_live: int = 0        # of those, pages holding a written position
+    kv_window_pages_read: int = 0  # of kv_pages_read, window-ring pages
+    moe_rows_routed: int = 0      # (row, expert) pairs routed to the experts
+    #                               held here, every MoE layer, as of the last
+    #                               readback (a device count read with it)
+    moe_rows_computed: int = 0    # expert buffer rows the expert launches
+    #                               computed, every MoE layer
     drift_events: list = dataclasses.field(default_factory=list)
     preempted: bool = False
     snapshot_path: Optional[str] = None
@@ -311,9 +320,9 @@ class Engine:
                 "(use launch.serve --static for SSM/hybrid)")
         if cfg.input_mode != "tokens":
             raise NotImplementedError("engine serves token-input models")
-        if cfg.swa_window is not None:
-            raise NotImplementedError(
-                "engine + sliding-window attention not supported yet")
+        sliding = transformer.segment_sliding(cfg)
+        if any(sliding) and mesh is not None:
+            raise NotImplementedError("window rings under a mesh")
         self.cfg = cfg
         self.ecfg = engine_cfg
         self.calib = calib
@@ -335,6 +344,16 @@ class Engine:
         else:
             self._dp_axes, self._tp_axis, self.dp = (), None, 1
         self.total_slots = self.dp * engine_cfg.slots
+        # Window layers keep their KV in rings of ring_pages pages a slot
+        # (slot s: ring pages [s * R, (s + 1) * R)); full layers in the pool.
+        self._full_layers = not all(sliding)
+        self.ring_pages = ring_pages(cfg.swa_window, engine_cfg.chunk,
+                                     engine_cfg.page_size) if any(sliding) else 0
+        self._ring_rows = np.arange(self.total_slots * self.ring_pages,
+                                    dtype=np.int32).reshape(self.total_slots, -1)
+        self._ring_tables = jnp.asarray(self._ring_rows) if self.ring_pages else None
+        self._moe_rows_per_row = 0 if cfg.moe is None else (
+            cfg.moe.resolved_held * (cfg.n_layers - cfg.moe.first_k_dense))
 
         self.cfg_serving = apply_calibration(cfg, calib)
         self._check_pinned_windows()
@@ -359,10 +378,10 @@ class Engine:
 
         # Per-page HBM bytes across all layers (for the high-water stat) and
         # the paged-pool shardings the two steps are pinned to.
-        shapes = jax.eval_shape(lambda: model.init_paged_caches(
-            cfg, engine_cfg.num_pages, engine_cfg.page_size, ranks=self.dp))
+        shapes = jax.eval_shape(self._init_caches)
         total = sum(np.prod(leaf.shape) * leaf.dtype.itemsize
-                    for leaf in jax.tree.leaves(shapes))
+                    for i, window in enumerate(sliding) if not window
+                    for leaf in jax.tree.leaves(shapes[f"seg{i}"]))
         self.page_bytes = int(
             total // (self.dp * (engine_cfg.num_pages + 1)))
         self._cache_sh = None
@@ -392,6 +411,24 @@ class Engine:
         self._st: Optional[RunState] = None
         self._fault: Optional[FaultConfig] = None
         self._guard: Optional[fault.PreemptionGuard] = None
+
+    def _init_caches(self):
+        """Fresh page pools (and window rings) of this engine's shape."""
+        e = self.ecfg
+        return model.init_paged_caches(
+            self.cfg, e.num_pages, e.page_size, ranks=self.dp,
+            ring_pages=self.total_slots * self.ring_pages)
+
+    def _read_routed(self, caches) -> None:
+        """The device count of routed expert rows, read beside a step's
+        readback (the step is done by then: no sync of its own).  The
+        device's int32 sum wraps; the host total stays equal to it modulo
+        2**32, so what was added since the last read is their difference
+        modulo 2**32."""
+        if "moe_routed" in caches:
+            st = self._st
+            st.moe_rows_routed += (int(caches["moe_routed"])
+                                   - st.moe_rows_routed) % (1 << 32)
 
     def _place_windows(self, windows: dict) -> dict:
         """Replicate the window operands across the mesh (meshless: as-is).
@@ -476,8 +513,7 @@ class Engine:
         ecfg = self.ecfg
         sched = self._make_sched()
         sched.add(requests)
-        caches = model.init_paged_caches(
-            self.cfg, ecfg.num_pages, ecfg.page_size, ranks=self.dp)
+        caches = self._init_caches()
         if self._cache_sh is not None:
             caches = jax.device_put(caches, self._cache_sh)
         if self.tracer is not None:
@@ -773,6 +809,8 @@ class Engine:
             batch = {"inputs": jnp.asarray(tokens),
                      "block_row": jnp.asarray(row),
                      "offset": jnp.int32(start), "valid": jnp.int32(n)}
+            if self.ring_pages:
+                batch["ring_row"] = jnp.asarray(self._ring_rows[slot.sid])
             if self.mesh is not None:
                 batch = jax.device_put(batch, self._batch_sh["prefill"])
         try:
@@ -792,13 +830,14 @@ class Engine:
                 row_logits = logits[0, 0]
                 tok = int(jnp.argmax(row_logits[:vocab]))
                 nan = int(bool(jnp.isnan(row_logits).any()))
+                self._read_routed(caches)
         with trace.span("engine.emit"):
             st.prefill_steps += 1
             slot.prefill_done += n
             slot.pos += n
             st.prompt_tokens += n
-            st.kv_pages_read += ecfg.resolved_max_pages
-            st.kv_pages_live += pages_for(slot.pos, ecfg.page_size)
+            self._count_pages(1, [slot])
+            st.moe_rows_computed += self._moe_rows_per_row * ecfg.chunk
             self._account(slot.record, n)
             if self.tracer is not None:
                 self.tracer.mark_chunk(
@@ -845,6 +884,8 @@ class Engine:
                      "block_tables": jnp.asarray(tables),
                      "pos": jnp.asarray(pos),
                      "active": jnp.asarray(active)}
+            if self.ring_pages:
+                batch["ring_tables"] = self._ring_tables
             if self.mesh is not None:
                 batch = jax.device_put(batch, self._batch_sh["decode"])
         try:
@@ -868,21 +909,36 @@ class Engine:
         with trace.span("engine.readback"):
             toks = np.asarray(jnp.argmax(logits[:, 0, :vocab], axis=-1))
             nans = np.asarray(jnp.isnan(logits[:, 0]).any(axis=-1))
+            self._read_routed(caches)
         with trace.span("engine.emit"):
             st.decode_steps += 1
             if self.tracer is not None:
                 self.tracer.mark_decode(
                     [s.record.request.rid for s in runnable], st.steps)
             st.util_samples.append(len(runnable) / b)
-            st.kv_pages_read += b * cap_pages
+            st.moe_rows_computed += self._moe_rows_per_row * b
             for slot in runnable:              # admission order
                 st.nan_steps += int(nans[slot.sid])
                 slot.pos += 1
-                st.kv_pages_live += pages_for(slot.pos, ps)
                 st.generated_tokens += 1
                 self._account(slot.record, 1)
                 self._emit(slot, int(toks[slot.sid]))
+            self._count_pages(b, runnable)
         st.steps += 1
+
+    def _count_pages(self, rows: int, slots: list[Slot]) -> None:
+        """Pages a step's gathers touched, for ``rows`` slots' rows, and of
+        those the pages holding a written position of ``slots``: the full
+        pool's block rows and the window rings, one layer of each."""
+        st, ps = self._st, self.ecfg.page_size
+        if self._full_layers:
+            st.kv_pages_read += rows * self.ecfg.resolved_max_pages
+            st.kv_pages_live += sum(pages_for(s.pos, ps) for s in slots)
+        if self.ring_pages:
+            st.kv_pages_read += rows * self.ring_pages
+            st.kv_window_pages_read += rows * self.ring_pages
+            st.kv_pages_live += sum(min(pages_for(s.pos, ps), self.ring_pages)
+                                    for s in slots)
 
     # ------------------------------------------------------------------
     # Drift detection + online recalibration
@@ -1014,6 +1070,9 @@ class Engine:
                 "util_samples": [float(u) for u in st.util_samples],
                 "kv_pages_read": st.kv_pages_read,
                 "kv_pages_live": st.kv_pages_live,
+                "kv_window_pages_read": st.kv_window_pages_read,
+                "moe_rows_routed": st.moe_rows_routed,
+                "moe_rows_computed": st.moe_rows_computed,
                 "drift_events": st.drift_events,
             },
         }
@@ -1101,8 +1160,7 @@ class Engine:
 
         # --- device caches (re-sharded onto the mesh when one is set) -----
         ecfg = self.ecfg
-        shapes = jax.eval_shape(lambda: model.init_paged_caches(
-            self.cfg, ecfg.num_pages, ecfg.page_size, ranks=self.dp))
+        shapes = jax.eval_shape(self._init_caches)
         sh_flat = dict(ckpt.leaf_paths(self._cache_sh)) \
             if self._cache_sh is not None else {}
         leaves = []
@@ -1194,6 +1252,9 @@ class Engine:
             util_samples=list(c["util_samples"]),
             kv_pages_read=c.get("kv_pages_read", 0),
             kv_pages_live=c.get("kv_pages_live", 0),
+            kv_window_pages_read=c.get("kv_window_pages_read", 0),
+            moe_rows_routed=c.get("moe_rows_routed", 0),
+            moe_rows_computed=c.get("moe_rows_computed", 0),
             drift_events=list(c["drift_events"]),
         )
 

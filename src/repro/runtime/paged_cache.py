@@ -20,6 +20,13 @@ Layout per attention layer (see ``models.attention.init_paged_cache``):
     k, v        (num_pages + 1, page_size, n_kv * head_dim)
     k/v_scale   (num_pages + 1, page_size, n_kv)            int8 KV mode
 
+A window layer (sliding attention) keeps its KV in a second pool of the
+same layout, the **window ring**: each slot owns ``ring_pages`` pages of it
+(slot ``s``: ring pages ``[s * R, (s + 1) * R)``, fixed, so no allocator),
+and position ``p`` lives at ``(ring_row[(p // page_size) % R], p %
+page_size)``.  The ring holds the window plus one chunk, so a window
+layer's KV stays ``R`` pages a slot however long the request.
+
 The **last** page is the trash page: writes from inactive slots (and padded
 prefill-chunk rows) are steered there instead of being predicated out, so the
 compiled step has no data-dependent control flow.  The trash page is never
@@ -49,10 +56,13 @@ class PrefillChunkCtx(NamedTuple):
     block_row: (P,) int32 — the slot's page ids, padded with the trash page.
     offset:    ()   int32 — global position of the chunk's first token.
     valid:     ()   int32 — real tokens in this chunk (rest is padding).
+    ring_row:  (R,) int32 — the slot's window-ring page ids (models with
+                            window layers; None otherwise).
     """
     block_row: jax.Array
     offset: jax.Array
     valid: jax.Array
+    ring_row: Optional[jax.Array] = None
 
 
 class DecodeCtx(NamedTuple):
@@ -64,10 +74,13 @@ class DecodeCtx(NamedTuple):
     active:       (B,)   bool  — occupied decode slots; inactive rows write
                                  to the trash page and their logits are
                                  ignored by the engine.
+    ring_tables:  (B, R) int32 — each slot's window-ring page ids (models
+                                 with window layers; None otherwise).
     """
     block_tables: jax.Array
     pos: jax.Array
     active: jax.Array
+    ring_tables: Optional[jax.Array] = None
 
 
 class PagePool:
@@ -169,3 +182,13 @@ class PagePool:
 def pages_for(n_tokens: int, page_size: int) -> int:
     """Pages needed to hold n_tokens positions (at least one)."""
     return max(1, -(-n_tokens // page_size))
+
+
+def ring_pages(window: int, chunk: int, page_size: int) -> int:
+    """Pages of one slot's window ring: position p lives at entry ``p mod
+    (ring_pages * page_size)``.  A prefill chunk of ``chunk`` rows reads
+    keys ``window - 1`` positions before its first row, and its own rows
+    (merged by position, written after the layer scan): ``window + chunk -
+    1`` positions, each still in the ring when the chunk reads it.  A decode
+    step reads ``window`` positions, fewer."""
+    return pages_for(window + chunk - 1, page_size)

@@ -53,7 +53,7 @@ import jax
 import numpy as np
 
 __all__ = ["Tracer", "validate_chrome_trace", "ENGINE_PID", "REQUEST_PID",
-           "ENGINE_SPANS", "STEP_SCOPES", "span", "scope"]
+           "ENGINE_SPANS", "STEP_SCOPES", "MOE_SCOPES", "span", "scope"]
 
 ENGINE_PID = 0
 REQUEST_PID = 1
@@ -62,6 +62,11 @@ ENGINE_SPANS = ("engine.admit", "engine.assemble", "engine.dispatch",
                 "engine.readback", "engine.emit")
 STEP_SCOPES = ("kv.write", "kv.read", "attention", "weight_program", "tdvmm",
                "head")
+# The expert layer's phases, in an MoE model's step programs only: the
+# router (scores, top-k, weights), the sort and scatter of rows into the
+# experts' buffer, the gather and weighted sum back.  Kept apart from
+# STEP_SCOPES, every name of which each step program of every model has.
+MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.combine")
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:
@@ -71,9 +76,10 @@ def span(name: str) -> jax.profiler.TraceAnnotation:
 
 
 def scope(name: str):
-    """The named scope of one step-program phase in ``STEP_SCOPES``."""
-    if name not in STEP_SCOPES:
-        raise ValueError(f"{name!r} is not one of {STEP_SCOPES}")
+    """The named scope of one step-program phase in ``STEP_SCOPES`` or
+    ``MOE_SCOPES``."""
+    if name not in STEP_SCOPES + MOE_SCOPES:
+        raise ValueError(f"{name!r} is not one of {STEP_SCOPES + MOE_SCOPES}")
     return jax.named_scope(name)
 
 _PHASES = ("B", "E", "X", "C", "i", "M")

@@ -1,4 +1,5 @@
-"""Mosaic compiles of the TD-VMM kernel variants at qwen1.5-0.5b widths.
+"""Mosaic compiles of the TD-VMM kernel variants at qwen1.5-0.5b widths, and
+of the batched expert launch at Trinity-Mini's.
 
 Each test lowers one ``ops.tdvmm_matmul`` launch with ``interpret=False``
 for a described (not attached) TPU v5e chip and compiles it with the chip's
@@ -126,3 +127,22 @@ def test_data_calibrated_readout(chip, site):
         group_widths=group),
         ((CALIB_M, k), jnp.int8), ((k, n), jnp.int8),
         ((CALIB_M,), jnp.float32), ((n,), jnp.float32))
+
+
+# Trinity-Mini's held experts as one batched launch (G = 16 experts), at the
+# dispatch capacities of its cell: the 96-slot decode step and the 512-row
+# prefill chunk (dropless: a step's rows), with per-expert windows.
+EXPERTS = 16
+
+
+@pytest.mark.parametrize("m", [96, 512])
+@pytest.mark.parametrize("k, n", [(2048, 1024), (1024, 2048)])
+def test_batched_expert_launch(chip, k, n, m):
+    blocks = _blocks(m, k, n, "int8")
+    g = EXPERTS
+    _compile(chip, lambda x, w, xs, ws, win: ops.tdvmm_matmul(
+        x, w, xs, ws, gain=1e-4, out_bits=6, backend="pallas",
+        interpret=False, code_dtype="int8", block_sizes=blocks,
+        out_window=win),
+        ((g, m, k), jnp.int8), ((g, k, n), jnp.int8), ((g, m), jnp.float32),
+        ((g, n), jnp.float32), ((g,), jnp.float32))
