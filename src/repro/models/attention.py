@@ -19,6 +19,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_decode.paged_decode import paged_decode_kernel
+from repro.kernels.tdvmm.ops import resolve_backend
+from repro.launch import meshctx
 from repro.models import common
 from repro.runtime.paged_cache import PrefillChunkCtx
 from repro.runtime.trace import scope
@@ -541,6 +544,80 @@ def _paged_read(pools: PagedKVCache, layer, tables, k_new, v_new, src, sel,
             jnp.where(sel, v_new[src], v_read.astype(dtype)))
 
 
+def reads_in_place(pools: PagedKVCache, mesh) -> bool:
+    """Whether a decode step reads its pages in place through the Pallas
+    kernel (``_paged_read_in_place``) rather than by gathering them
+    (``_decode_gathered``): on a TPU (``"auto"`` resolved as the TD-VMM
+    kernels resolve it), for pools the kernel reads (no int8 KV: it reads
+    no scales), outside a multi-device ``mesh`` (GSPMD cannot partition a
+    Mosaic call), and for pages of whole native tiles (rows a multiple of
+    the dtype's sublanes, a lane-aligned row).  ``pools`` may hold shapes.
+    The gather path is the reference and the CPU path."""
+    page_size, width = pools.k.shape[-2:]
+    return (resolve_backend("auto") == "pallas" and pools.k_scale is None
+            and (mesh is None or mesh.size == 1)
+            and page_size % (32 // pools.k.dtype.itemsize) == 0
+            and width % 128 == 0)
+
+
+def _window_pages(ring: jax.Array, pos: jax.Array, window: int,
+                  page_size: int) -> tuple[jax.Array, jax.Array]:
+    """A window layer's decode read: each slot's ring pages that hold its
+    keys in ``(pos - window, pos)``, in position order ((B, n) page ids,
+    n the most pages ``window - 1`` positions span), and the logical page
+    of the first of them, (B,).  Position p lives on ring page
+    ``(p // page_size) % ring_pages``."""
+    n_ring = ring.shape[1]
+    n = min(n_ring, -(-(window - 1) // page_size) + 1)
+    first = jnp.maximum(pos - window + 1, 0) // page_size
+    logical = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+    return jnp.take_along_axis(ring, logical % n_ring, axis=1), first
+
+
+@scope("kv.read")
+def _paged_read_in_place(q, pools: PagedKVCache, layer, tables, pos, active,
+                         window: Optional[int]):
+    """Layer ``layer``'s attention of each active slot's query q (B, H, hd)
+    over the keys its pages hold below ``pos`` (a window layer: in the
+    window), read in place from the stacked pools by
+    ``paged_decode_kernel``: only the pages holding such keys, nothing
+    copied in HBM.  Returns the kernel's (m, l, acc)."""
+    b, h, hd = q.shape
+    page_size, width = pools.k.shape[-2:]
+    n_kv = width // hd
+    if window is None:
+        pages, first = tables, jnp.zeros_like(pos)
+    else:
+        pages, first = _window_pages(tables, pos, window, page_size)
+    count = jnp.where(active, -(-pos // page_size) - first, 0)
+    own = (jnp.arange(h)[:, None] // (h // n_kv)) == jnp.arange(n_kv)[None]
+    q_bd = jnp.where(own[None, :, :, None], q[:, :, None, :], 0)
+    return paged_decode_kernel(
+        q_bd.reshape(b, h, width), pools.k, pools.v, layer, pages,
+        first * page_size, count, pos, head_dim=hd, window=window,
+        interpret=resolve_backend("auto") != "pallas")
+
+
+@scope("attention")
+def _merge_fresh(m, l, acc, q, k_new, v_new):
+    """The in-place read's online softmax with the step's own row merged
+    in (it is in no pool yet: ``paged_write`` stores it after the layer
+    scan) by one log-sum-exp step, then normalised.  m, l: (B, H, 1);
+    acc: (B, H, hd); q: (B, H, hd); k_new, v_new: (B, n_kv, hd).  Returns
+    (B, H, hd)."""
+    b, h, hd = q.shape
+    n_kv = k_new.shape[1]
+    groups = h // n_kv
+    s = jnp.einsum("bkgd,bkd->bkg", q.reshape(b, n_kv, groups, hd), k_new,
+                   preferred_element_type=jnp.float32)
+    s = s.reshape(b, h, 1) * hd ** -0.5
+    m_new = jnp.maximum(m, s)
+    alpha, e = jnp.exp(m - m_new), jnp.exp(s - m_new)
+    v_h = jnp.repeat(v_new, groups, axis=1).astype(jnp.float32)
+    out = (alpha * acc + e * v_h) / (alpha * l + e)
+    return out.astype(q.dtype)
+
+
 def apply_prefill_paged(params, x: jax.Array, cfg: ModelConfig,
                         pools: PagedKVCache, layer, ctx, key=None
                         ) -> tuple[jax.Array, PagedKVCache]:
@@ -606,27 +683,53 @@ def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
     the attention output and this layer's B new rows, which
     ``paged_write`` stores after the layer scan.
 
-    Each slot attends over its own gathered pages (a window layer: its
-    window ring, ``ctx.ring_tables``) with its new row merged
-    in at position ``pos``; inactive slots' rows go to the trash page,
+    Each slot attends over its own pages (a window layer: its window ring,
+    ``ctx.ring_tables``) with its new row merged in at position ``pos``:
+    on a TPU read in place, only the pages that hold keys
+    (``_decode_in_place``), else gathered whole (``_decode_gathered``,
+    ``reads_in_place`` chooses); inactive slots' rows go to the trash page,
     never advance, and produce ignored outputs.  There is NO
     decode-past-capacity poisoning path here: the engine evicts a request
     *before* its next write would overflow its page budget, so an
     overflowing write can never corrupt (or NaN) a neighbor slot."""
-    ps = pools.k.shape[2]
     window = cfg.swa_window
     tables = ctx.block_tables if window is None else ctx.ring_tables
-    n_rows = tables.shape[1]
     pos = ctx.pos
-    positions = pos[:, None]
-    q, k, v, g = _project(params, x, cfg, positions, key)
+    q, k, v, g = _project(params, x, cfg, pos[:, None], key)
 
     rows, k_new, v_new = _fresh_rows(pools, k[:, 0], v[:, 0], q.dtype)
+    if reads_in_place(pools, meshctx.get_mesh()):
+        out = _decode_in_place(q, k_new, v_new, pools, layer, tables, pos,
+                               ctx.active, window)
+    else:
+        out = _decode_gathered(q, k_new, v_new, pools, layer, tables, pos,
+                               window, cfg)
+    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
+                     cfg.site_tdvmm("attn.out"), key)
+    return y, rows
+
+
+def _decode_in_place(q, k_new, v_new, pools: PagedKVCache, layer, tables,
+                     pos, active, window: Optional[int]) -> jax.Array:
+    """A decode step's attention, its pages read in place: q (B, 1, H, hd),
+    k_new / v_new (B, n_kv, hd) the step's rows as a read returns them.
+    Returns (B, 1, H, hd)."""
+    m, l, acc = _paged_read_in_place(q[:, 0], pools, layer, tables, pos,
+                                     active, window)
+    return _merge_fresh(m, l, acc, q[:, 0], k_new, v_new)[:, None]
+
+
+def _decode_gathered(q, k_new, v_new, pools: PagedKVCache, layer, tables,
+                     pos, window: Optional[int], cfg: ModelConfig
+                     ) -> jax.Array:
+    """``_decode_in_place`` by gathering every page of each slot's table
+    (a window layer: its whole ring) and scoring every position of them."""
+    n_pos = tables.shape[1] * pools.k.shape[2]
     if window is None:
-        kpos = jnp.arange(n_rows * ps, dtype=jnp.int32)
+        kpos = jnp.arange(n_pos, dtype=jnp.int32)
         sel = kpos[None, :] == pos[:, None]                   # (B, cap)
     else:
-        kpos = _ring_positions(n_rows * ps, pos[:, None] + 1)  # (B, cap)
+        kpos = _ring_positions(n_pos, pos[:, None] + 1)       # (B, cap)
         sel = kpos == pos[:, None]
     src = jnp.arange(pos.shape[0])[:, None]                   # (B, 1)
     k_read, v_read = _paged_read(pools, layer, tables, k_new,
@@ -635,10 +738,7 @@ def apply_decode_paged(params, x: jax.Array, cfg: ModelConfig,
         mask = kpos[None, :] <= pos[:, None]
     else:   # the ring holds no position past pos
         mask = (kpos >= 0) & (kpos > pos[:, None] - window)
-    out = _attend(q, k_read, v_read, mask[:, None, None, :], cfg)  # (B,1,1,cap)
-    y = common.dense(params["wo"], _merge_heads(_gated(out, g)),
-                     cfg.site_tdvmm("attn.out"), key)
-    return y, rows
+    return _attend(q, k_read, v_read, mask[:, None, None, :], cfg)  # (B,1,1,cap)
 
 
 def apply_decode(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,

@@ -117,7 +117,7 @@ from repro.kernels.tdvmm import ops as tdvmm_ops
 from repro.launch import meshctx
 from repro.launch import sharding as shardlib
 from repro.launch.mesh import axis_info
-from repro.models import model, transformer
+from repro.models import attention, model, transformer
 from repro.runtime import fault
 from repro.runtime import sla as sla_policy
 from repro.runtime import trace
@@ -281,7 +281,7 @@ class RunState:
     last_clip_obs: int = 0
     wall_s: float = 0.0
     util_samples: list = dataclasses.field(default_factory=list)
-    kv_pages_read: int = 0        # pages the steps' KV gathers touched (one
+    kv_pages_read: int = 0        # pages the steps' KV reads touched (one
     #                               layer of each pool kind: full, window ring)
     kv_pages_live: int = 0        # of those, pages holding a written position
     kv_window_pages_read: int = 0  # of kv_pages_read, window-ring pages
@@ -384,6 +384,7 @@ class Engine:
                     for leaf in jax.tree.leaves(shapes[f"seg{i}"]))
         self.page_bytes = int(
             total // (self.dp * (engine_cfg.num_pages + 1)))
+        self._decode_in_place = attention.reads_in_place(shapes["seg0"], mesh)
         self._cache_sh = None
         self._batch_sh = {}
         jit_kw: dict[str, Any] = {}
@@ -923,7 +924,10 @@ class Engine:
                 st.generated_tokens += 1
                 self._account(slot.record, 1)
                 self._emit(slot, int(toks[slot.sid]))
-            self._count_pages(b, runnable)
+            if self._decode_in_place:
+                self._count_pages_in_place([s.pos - 1 for s in runnable])
+            else:
+                self._count_pages(b, runnable)
         st.steps += 1
 
     def _count_pages(self, rows: int, slots: list[Slot]) -> None:
@@ -939,6 +943,23 @@ class Engine:
             st.kv_window_pages_read += rows * self.ring_pages
             st.kv_pages_live += sum(min(pages_for(s.pos, ps), self.ring_pages)
                                     for s in slots)
+
+    def _count_pages_in_place(self, positions: list[int]) -> None:
+        """Pages a decode step that reads in place reads for slots at
+        ``positions`` (before the step), one layer of each pool kind: every
+        page below a slot's position, and of its window ring those that
+        hold the window (``attention._paged_read_in_place``), so every page
+        read is live."""
+        st, ps, window = self._st, self.ecfg.page_size, self.cfg.swa_window
+        for pos in positions:
+            below = -(-pos // ps)
+            read = below if self._full_layers else 0
+            if self.ring_pages:
+                ring = below - max(pos - window + 1, 0) // ps
+                st.kv_window_pages_read += ring
+                read += ring
+            st.kv_pages_read += read
+            st.kv_pages_live += read
 
     # ------------------------------------------------------------------
     # Drift detection + online recalibration

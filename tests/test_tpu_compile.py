@@ -1,5 +1,6 @@
-"""Mosaic compiles of the TD-VMM kernel variants at qwen1.5-0.5b widths, and
-of the batched expert launch at Trinity-Mini's.
+"""Mosaic compiles of the TD-VMM kernel variants at qwen1.5-0.5b widths, of
+the batched expert launch at Trinity-Mini's, and of the decode step's
+in-place page read at the three decode cells' shapes.
 
 Each test lowers one ``ops.tdvmm_matmul`` launch with ``interpret=False``
 for a described (not attached) TPU v5e chip and compiles it with the chip's
@@ -19,7 +20,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.plan import site_linear_shapes
+from repro.kernels.paged_decode.paged_decode import paged_decode_kernel
 from repro.kernels.tdvmm import ops, tdvmm
+from repro.models import attention
 
 SHAPES = site_linear_shapes(get_config("qwen1.5-0.5b"))
 DECODE_M, PREFILL_M, CALIB_M = 8, 128, 8 * 128
@@ -146,3 +149,35 @@ def test_batched_expert_launch(chip, k, n, m):
         out_window=win),
         ((g, m, k), jnp.int8), ((g, k, n), jnp.int8), ((g, m), jnp.float32),
         ((g, n), jnp.float32), ((g,), jnp.float32))
+
+
+# The decode step's in-place page read at the decode cells' shapes: (slots,
+# heads, KV heads, head_dim, layers of the stacked pool, pages in it, pages
+# a slot's table lists, window).  Trinity-Mini reads its 2 full layers
+# through 512-page block rows and its 6 window layers through 160-page
+# rings.
+PAGED_DECODE = {
+    "qwen05b-decode": (48, 16, 16, 64, 24, 48 * 64, 64, None),
+    "qwen14b-decode": (64, 40, 8, 128, 8, 8192, 128, None),
+    "trinity-mixed.full": (96, 32, 4, 128, 2, 96 * 512, 512, None),
+    "trinity-mixed.ring": (96, 32, 4, 128, 6, 96 * 160, 160, 2048),
+}
+
+
+@pytest.mark.parametrize("cell", PAGED_DECODE)
+def test_paged_decode_kernel(chip, cell):
+    b, h, n_kv, hd, layers, pages, per_slot, window = PAGED_DECODE[cell]
+    page, width = 16, n_kv * hd
+
+    def read(q_bd, k, v, layer, tables, pos, count):
+        if window is None:
+            first = jnp.zeros_like(pos)
+        else:
+            tables, first = attention._window_pages(tables, pos, window, page)
+        return paged_decode_kernel(q_bd, k, v, layer, tables, first * page,
+                                   count, pos, head_dim=hd, window=window)
+
+    pool = ((layers, pages + 1, page, width), jnp.bfloat16)
+    _compile(chip, read, ((b, h, width), jnp.bfloat16), pool, pool,
+             ((), jnp.int32), ((b, per_slot), jnp.int32), ((b,), jnp.int32),
+             ((b,), jnp.int32))
