@@ -1,51 +1,18 @@
-"""Shared benchmark utilities: timing + CSV row emission + JSON reports."""
+"""Shared paper-figure utilities: wall-clock timing + CSV row emission."""
 from __future__ import annotations
 
-import json
 import time
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 
-ROWS: list[dict] = []
 
-
-def reset_rows() -> None:
-    """Drop any rows emitted by earlier suites in the same process.
-
-    Suites that write a JSON report call this first: ``save_json`` dumps
-    every row since the last save, so without the reset a full
-    ``benchmarks.run`` sweep would sweep print-only suites' rows (e.g.
-    bench_llm_mapping) into the next report and the artifact would differ
-    from the standalone ``python -m benchmarks.<suite>`` run."""
-    ROWS[:] = []
-
-
-class Timing(float):
-    """A median-microseconds wall time that also carries how it was measured.
-
-    Subclasses float (the median), so every existing consumer that divides
-    or compares a ``time_call`` result is unchanged; ``emit`` additionally
-    records the repeat count and min-to-max spread so a noisy median can't
-    silently masquerade as a stable one in the JSON report.
-    """
-    repeats: int
-    spread_us: float
-
-    def __new__(cls, median_us: float, repeats: int, spread_us: float):
-        self = super().__new__(cls, median_us)
-        self.repeats = repeats
-        self.spread_us = spread_us
-        return self
-
-
-def time_call(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> Timing:
+def time_call(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> float:
     """Median-of-``iters`` wall time per call in microseconds (post-jit).
 
     Every sample is ``block_until_ready``-fenced (async dispatch would
-    otherwise time the enqueue, not the compute), warmup runs absorb
-    compilation and first-touch allocation, and the min-to-max spread across
-    the repeats rides along on the returned ``Timing``."""
+    otherwise time the enqueue, not the compute), and warmup runs absorb
+    compilation and first-touch allocation."""
     for _ in range(warmup):
         out = fn(*args)
         jax.block_until_ready(out)
@@ -56,62 +23,9 @@ def time_call(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> Timing:
         jax.block_until_ready(out)
         times.append(time.perf_counter() - t0)
     times.sort()
-    return Timing(times[len(times) // 2] * 1e6, iters,
-                  (times[-1] - times[0]) * 1e6)
+    return times[len(times) // 2] * 1e6
 
 
-def time_host(fn: Callable, warmup: int = 1, iters: int = 3):
-    """Median-of-``iters`` wall time for a *host-driven* callable (e.g. a
-    full serving-engine run) -> ``(last_result, Timing)`` in microseconds.
-
-    Same hygiene as ``time_call`` — warmup absorbs jit compilation, the
-    median resists scheduler noise, and the min-to-max spread rides on the
-    ``Timing`` — but without the ``block_until_ready`` fence: the callable
-    is expected to synchronize internally (the engine's drive loop pulls
-    every step's logits to the host).  The callable must be idempotent
-    (each invocation re-initializes its own state) so the returned result
-    is the same object every repeat would produce."""
-    out = None
-    for _ in range(warmup):
-        out = fn()
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn()
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return out, Timing(times[len(times) // 2] * 1e6, iters,
-                       (times[-1] - times[0]) * 1e6)
-
-
-def emit(name: str, us_per_call: float, derived: str,
-         data: Optional[dict] = None):
-    """Record (and print) one benchmark row.  ``data`` carries structured
-    metrics (bytes moved, GB/s, speedups) into the JSON report."""
-    row = {"name": name, "us_per_call": round(us_per_call, 1),
-           "derived": derived}
-    if isinstance(us_per_call, Timing):
-        row["timing_repeats"] = us_per_call.repeats
-        row["timing_spread_us"] = round(us_per_call.spread_us, 1)
-    if data:
-        row.update(data)
-    ROWS.append(row)
+def emit(name: str, us_per_call: float, derived: str) -> None:
+    """Print one ``name,us_per_call,derived`` CSV row."""
     print(f"{name},{us_per_call:.1f},{derived}")
-
-
-def save_json(path: str, meta: Optional[dict] = None) -> str:
-    """Dump every row emitted since the last save (plus run metadata) as a
-    JSON report — the CI-tracked perf trajectory artifact
-    (e.g. BENCH_kernels.json).  Snapshots and clears the row buffer so each
-    suite's report contains only its own rows."""
-    rows, ROWS[:] = list(ROWS), []
-    doc = {
-        "backend": jax.default_backend(),
-        "device_count": jax.device_count(),
-        **(meta or {}),
-        "rows": rows,
-    }
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-    print(f"wrote {path} ({len(rows)} rows)")
-    return path

@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper table/figure.
+"""Paper-figure harness — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows:
   * bench_precision    — Fig. 4  (DIBL error surface, effective bits)
@@ -6,47 +6,29 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * bench_latency      — section 4.2 latency / Fig. 2d pipelining
   * bench_comparison   — section 4.2 prior-work comparison table
   * bench_perceptron   — section 3 case study (10x10x10 time-domain MLP)
-  * bench_kernels      — Pallas kernel reference-path micro-benches
   * bench_llm_mapping  — beyond-paper: assigned archs costed on TD-VMM tiles
-  * bench_serving      — continuous-batching engine on a ragged trace
-  * roofline_report    — dry-run roofline terms per (arch x shape x mesh)
 
-After the sweep the JSON reports' invariants are re-asserted in the same
-run (``bench_kernels.check_invariants`` + ``bench_serving.check_invariants``
-— the one-command version of the CI bench-smoke gates), so a stale
-``BENCH_kernels.json`` can't silently drift from the code that claims it.
+Serving speed on the chip is measured by ``bench/run.py`` over the cells of
+``BENCHMARK.json``, not here.
 """
 from __future__ import annotations
 
-import json
 import traceback
 
 
 def main() -> None:
     from benchmarks import (bench_comparison, bench_energy_area,
-                            bench_kernels, bench_latency, bench_llm_mapping,
-                            bench_perceptron, bench_precision, bench_serving,
-                            roofline_report)
+                            bench_latency, bench_llm_mapping,
+                            bench_perceptron, bench_precision)
     print("name,us_per_call,derived")
     failed = False
     for mod in (bench_precision, bench_energy_area, bench_latency,
-                bench_comparison, bench_perceptron, bench_kernels,
-                bench_llm_mapping, bench_serving, roofline_report):
+                bench_comparison, bench_perceptron, bench_llm_mapping):
         try:
             mod.run()
         except Exception:  # noqa: BLE001 — benches are independent
             failed = True
             print(f"{mod.__name__},ERROR,see_stderr")
-            traceback.print_exc()
-    for path, checker in (("BENCH_kernels.json", bench_kernels.check_invariants),
-                          ("BENCH_serving.json", bench_serving.check_invariants)):
-        try:
-            with open(path) as f:
-                checker(json.load(f))
-            print(f"{path}: invariants OK")
-        except Exception:  # noqa: BLE001
-            failed = True
-            print(f"{path}: INVARIANT FAILURE")
             traceback.print_exc()
     if failed:
         raise SystemExit(1)

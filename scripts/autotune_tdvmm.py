@@ -9,8 +9,8 @@ the other platform's table is preserved verbatim.
 
 Shapes come from two sources:
 
-  * the fixed shapes ``benchmarks/bench_kernels.py`` times (always included,
-    so the checked-in BENCH_kernels.json rows are table hits), and
+  * a fixed list of reference shapes (always included, so the table keeps
+    answering them), and
   * every launch shape the resolved plans emit
     (``configs.plan.plan_launch_shapes``) across the selected ``--archs``
     at ``--m`` tokens — the model-emitted work list.
@@ -43,11 +43,11 @@ import numpy as np  # noqa: E402
 
 TABLE_PATH = ROOT / "src" / "repro" / "kernels" / "tdvmm" / "autotune_table.py"
 
-# The shapes benchmarks/bench_kernels.py times (plus the perceptron case
-# study): these must be table hits so the checked-in BENCH_kernels.json rows
-# carry autotune_hit=True.
+# Reference shapes, always swept so the table keeps its entries for them:
+# model-width codes matmuls in every code dtype, the ragged grouped qkv and
+# ssm.in_proj launches, and the perceptron case study.
 BENCH_SHAPES: list[tuple[int, int, int, str]] = [
-    # bench_tdvmm_backends (f32 codes) + the int8/int4 byte-count shapes
+    # model-width codes matmuls in f32, int8 and int4 codes
     (512, 1024, 4096, "float32"),
     (512, 1024, 4096, "int8"),
     (512, 1024, 4096, "int4"),
@@ -56,10 +56,10 @@ BENCH_SHAPES: list[tuple[int, int, int, str]] = [
     (512, 2048, 512, "float32"),
     (512, 2048, 512, "int8"),
     (512, 2048, 512, "int4"),
-    # td_matmul_layer + bench_fused_epilogue
+    # a full layer and the fused-epilogue shape
     (256, 1024, 4096, "int8"),
     (256, 1024, 512, "int8"),
-    # bench_grouped_projection ragged concat launches
+    # ragged grouped concat launches (attn.qkv G=3, ssm.in_proj G=5)
     (64, 896, 1152, "int8"),
     (64, 512, 2432, "int8"),
     # the perceptron case-study shape

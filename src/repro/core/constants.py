@@ -51,11 +51,6 @@ MOSCAP_F_PER_UM2 = 6.0e-15
 # --- Default computing precision ---------------------------------------------
 DEFAULT_BITS = 6        # DIBL-limited precision ceiling (abstract, section 4.1)
 
-# --- TPU v5e roofline constants (task spec; used by launch/roofline.py) -------
-TPU_PEAK_FLOPS_BF16 = 197e12     # per chip
-TPU_HBM_BW = 819e9               # bytes/s per chip
-TPU_ICI_BW = 50e9                # bytes/s per link
-
 
 @dataclasses.dataclass(frozen=True)
 class TDVMMSpec:

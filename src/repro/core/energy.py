@@ -183,7 +183,7 @@ def serving_energy_model(cfg, tile_n: int = 256, n_devices: int = 1) -> dict:
     term on both ends of the pair: the upstream tile skips its ADC readout
     and the downstream tile skips its input DAC (Fig. 2 — the intermediate
     p-bit boundary disappears), so a ``chain=True`` plan shows up directly
-    as fewer joules per token in ``benchmarks/bench_serving.py``.
+    as fewer joules per token.
 
     Ops are counted as 2 * d_in * d_out per matrix per token (the paper's
     MAC = mult + add convention); tile energy includes padding waste (a

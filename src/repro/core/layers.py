@@ -228,10 +228,6 @@ def td_matmul(
 ) -> jax.Array:
     """Four-quadrant TD-VMM fast path.  x: (..., N_in), w: (N_in, N_out)."""
     if not cfg.enabled:
-        from repro.models import common as _c
-        pet = _c.matmul_out_dtype()
-        if pet is not None:
-            return jnp.dot(x, w, preferred_element_type=pet)
         return x @ w
 
     with scope("tdvmm"):
@@ -287,10 +283,7 @@ def td_expert_matmul(
     contribute zero charge, so capacity padding is exact.
     """
     if not cfg.enabled:
-        from repro.models import common as _c
-        pet = _c.matmul_out_dtype()
-        kw = {"preferred_element_type": pet} if pet is not None else {}
-        return jnp.einsum("eck,ekn->ecn", x, w, **kw)
+        return jnp.einsum("eck,ekn->ecn", x, w)
 
     with scope("tdvmm"):
         e, c, k = x.shape
@@ -363,10 +356,7 @@ def td_grouped_matmul(
     if not ws:
         return ()
     if not cfg.enabled:
-        from repro.models import common as _c
-        pet = _c.matmul_out_dtype()
-        kw = {"preferred_element_type": pet} if pet is not None else {}
-        return tuple(jnp.dot(x, w, **kw) for w in ws)
+        return tuple(jnp.dot(x, w) for w in ws)
 
     with scope("tdvmm"):
         k = x.shape[-1]

@@ -24,17 +24,17 @@ bit-for-bit identical to int8.  ``"f32"`` is the legacy float-code path
 (8-bit codes, noise-perturbed analog currents); exact only while
 |acc| < 2^24.  ``"auto"`` follows the input arrays' dtypes.
 
-Epilogue placement (Pallas backend): a *fixed* readout window (``out_scale``
-given) or no readout runs the whole epilogue inside the kernel's final K
-step (tdvmm_fused_kernel); a data-calibrated window (``out_scale=None`` with
-``out_bits``) first runs ``tdvmm_absmax_kernel`` for the per-tile max|z|,
-reduces it per readout slot, and then runs the fused kernel with those
-windows.  Either way each output tile materializes in HBM exactly once,
-already in model units (``fused_calibration=False`` forces the legacy
-unfused jnp epilogue for the calibrated case).  All epilogues evaluate the
-same expression term for term — the window's reciprocal comes from one XLA
-expression, ``tdvmm.readout_factors``, on every path — so every pairing is
-bit-for-bit identical.
+Epilogue placement (Pallas backend): every readout runs inside the
+kernel's final K step (tdvmm_fused_kernel), so each output tile
+materializes in HBM exactly once, already in model units.  A *fixed*
+readout window (``out_scale`` or ``out_window`` given) or no readout is one
+launch.  A data-calibrated window (``out_scale=None`` with ``out_bits``) is
+two: ``tdvmm_absmax_kernel`` writes the per-tile max|z|, which is reduced
+per readout slot into the window the fused launch then reads.  The jnp
+backend runs the same epilogue (``_epilogue``) on the einsum accumulation;
+every epilogue evaluates the same expression term for term — the window's
+reciprocal comes from one XLA expression, ``tdvmm.readout_factors``, on
+every path — so the backends are bit-for-bit identical.
 
 Batching: 3-D inputs (E, M, K) x (E, K, N) map the expert dim onto the
 kernel's batched grid axis (scales (E, M) / (E, N)); 2-D inputs run as E=1.
@@ -59,7 +59,7 @@ silently falling back to heuristic blocks.
 
 Gradients flow through a shared custom VJP (plain matmul cotangents on the
 STE-wrapped codes, identity through the readout quantizer), so every backend
-x dtype x fusion combination is trainable and backend-independent in the
+x dtype combination is trainable and backend-independent in the
 backward pass.  Pass int arrays directly only on no-grad (serving) paths;
 the QAT path feeds the f32 STE view and lets the forward cast to int8.
 """
@@ -75,8 +75,7 @@ import numpy as np
 
 from repro.kernels.tdvmm.tdvmm import (
     acc_dtype_for, autotune_blocks, autotune_lookup, autotune_platform,
-    pad_to_blocks, readout_factors, tdvmm_absmax_kernel, tdvmm_fused_kernel,
-    tdvmm_matmul_kernel)
+    pad_to_blocks, readout_factors, tdvmm_absmax_kernel, tdvmm_fused_kernel)
 from repro.runtime.trace import scope
 
 
@@ -115,7 +114,7 @@ class KernelPlan(NamedTuple):
 
 # Every plan_kernel lookup of this process, keyed (M, K, N, dtype-name) —
 # the kernel report that makes untuned (heuristic-fallback) shapes visible
-# in BENCH_kernels.json instead of quietly slow.
+# (``autotune_report``) instead of quietly slow.
 _AUTOTUNE_LOG: dict[tuple[int, int, int, str], dict] = {}
 _AUTOTUNE_WARNED: set[tuple[int, int, int, str]] = set()
 _logger = logging.getLogger(__name__)
@@ -331,7 +330,7 @@ def _whole_on_each_device(kernel, *operands):
 
 def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
                 out_scale, out_window, backend, interpret, code_dtype,
-                blocks, group_widths, fused_calibration):
+                blocks, group_widths):
     ex, m, k = x_codes.shape
     e, _, n = w_codes.shape
     shared_x = ex == 1 and e > 1
@@ -377,61 +376,46 @@ def _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
     mp, np_ = xp.shape[-2], wp.shape[-1]
     exact = (mp, np_) == (m, n)
 
-    if out_bits is None or out_scale is not None or out_window is not None:
-        # Fixed readout window (runtime-operand or static, or no readout):
-        # fully fused epilogue — the (bm, bn) tile leaves VMEM exactly once,
-        # already in model units.
-        xsp = jnp.pad(x_scale, ((0, 0), (0, mp - m)))[..., :, None]
-        wsp = jnp.pad(w_scale, ((0, 0), (0, np_ - n)))[..., None, :]
-        window, scale_arg = None, out_scale
-        if out_bits is not None and out_window is not None:
-            ow = jnp.asarray(out_window, jnp.float32)
-            if group_widths is not None:
-                window = _member_window_cols_arr(ow, group_widths, np_)
-            else:
-                window = ow.reshape(-1, 1, 1) if ow.ndim >= 1 \
-                    else ow.reshape(1, 1, 1)
-            scale_arg = None
-        elif (out_bits is not None and group_widths is not None
-                and isinstance(out_scale, tuple)):
-            window, scale_arg = _member_window_cols(
-                out_scale, group_widths, np_), None
-        y = _whole_on_each_device(functools.partial(
-            tdvmm_fused_kernel, gain=gain, out_bits=out_bits,
-            out_scale=scale_arg, bm=bm, bk=bk, bn=bn, interpret=interpret,
-            unpack4=unpack4), xp, wp, xsp, wsp, window)
-        return y if exact else y[:, :m, :n]
-    if fused_calibration:
-        # Data-calibrated window, still one (M, N) HBM output: per-tile
-        # maxima, reduced per readout slot, become the fused launch's window.
-        xsp = jnp.pad(x_scale, ((0, 0), (0, mp - m)))[..., :, None]
-        wsp = jnp.pad(w_scale, ((0, 0), (0, np_ - n)))[..., None, :]
+    # Every readout runs in the fused kernel's epilogue: the (bm, bn) tile
+    # leaves VMEM exactly once, already in model units.
+    xsp = jnp.pad(x_scale, ((0, 0), (0, mp - m)))[..., :, None]
+    wsp = jnp.pad(w_scale, ((0, 0), (0, np_ - n)))[..., None, :]
+    window, scale_arg = None, out_scale
+    if out_bits is not None and out_window is not None:
+        # Fixed window as a runtime operand.
+        ow = jnp.asarray(out_window, jnp.float32)
+        if group_widths is not None:
+            window = _member_window_cols_arr(ow, group_widths, np_)
+        else:
+            window = ow.reshape(-1, 1, 1) if ow.ndim >= 1 \
+                else ow.reshape(1, 1, 1)
+        scale_arg = None
+    elif (out_bits is not None and group_widths is not None
+            and isinstance(out_scale, tuple)):
+        window, scale_arg = _member_window_cols(
+            out_scale, group_widths, np_), None
+    elif out_bits is not None and out_scale is None:
+        # Data-calibrated window: per-tile maxima, reduced per readout
+        # slot, become the fused launch's window.
         tile_max = _whole_on_each_device(functools.partial(
             tdvmm_absmax_kernel, gain=gain, bm=bm, bk=bk, bn=bn,
             interpret=interpret, unpack4=unpack4), xp, wp)
         window = _slot_windows(tile_max, min(bn, np_), np_, group_widths)
-        y = _whole_on_each_device(functools.partial(
-            tdvmm_fused_kernel, gain=gain, out_bits=out_bits, bm=bm, bk=bk,
-            bn=bn, interpret=interpret, unpack4=unpack4),
-            xp, wp, xsp, wsp, window)
-        return y if exact else y[:, :m, :n]
-    # Legacy two-pass: integrate in the kernel, epilogue unfused in jnp.
-    acc = _whole_on_each_device(functools.partial(
-        tdvmm_matmul_kernel, bm=bm, bk=bk, bn=bn, interpret=interpret,
-        unpack4=unpack4), xp, wp)
-    acc = acc if exact else acc[:, :m, :n]
-    return _epilogue(acc, x_scale, w_scale, gain, out_bits, out_scale,
-                     group_widths, out_window)
+    y = _whole_on_each_device(functools.partial(
+        tdvmm_fused_kernel, gain=gain, out_bits=out_bits,
+        out_scale=scale_arg, bm=bm, bk=bk, bn=bn, interpret=interpret,
+        unpack4=unpack4), xp, wp, xsp, wsp, window)
+    return y if exact else y[:, :m, :n]
 
 
 # ---------------------------------------------------------------------------
-# Shared custom VJP (all backends / dtypes / fusion modes)
+# Shared custom VJP (all backends / dtypes)
 # ---------------------------------------------------------------------------
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _tdvmm_core(x_codes, w_codes, x_scale, w_scale, out_window, gain,
                 out_bits, out_scale, backend, interpret, code_dtype, blocks,
-                group_widths, fused_calibration):
+                group_widths):
     """Differentiable integrate+epilogue on canonical (E, M, K) shapes.
 
     ``out_window`` rides as a differentiable-position arg (it is traced —
@@ -440,21 +424,20 @@ def _tdvmm_core(x_codes, w_codes, x_scale, w_scale, out_window, gain,
     path where the window never enters the autodiff graph at all."""
     return _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
                        out_scale, out_window, backend, interpret, code_dtype,
-                       blocks, group_widths, fused_calibration)
+                       blocks, group_widths)
 
 
 def _tdvmm_core_fwd(x_codes, w_codes, x_scale, w_scale, out_window, gain,
                     out_bits, out_scale, backend, interpret, code_dtype,
-                    blocks, group_widths, fused_calibration):
+                    blocks, group_widths):
     y = _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
                     out_scale, out_window, backend, interpret, code_dtype,
-                    blocks, group_widths, fused_calibration)
+                    blocks, group_widths)
     return y, (x_codes, w_codes, x_scale, w_scale, out_window, y)
 
 
 def _tdvmm_core_bwd(gain, out_bits, out_scale, backend, interpret,
-                    code_dtype, blocks, group_widths, fused_calibration,
-                    res, g):
+                    code_dtype, blocks, group_widths, res, g):
     x_codes, w_codes, x_scale, w_scale, out_window, y = res
     denom = x_scale[..., :, None] * w_scale[..., None, :]
     # Recover the post-readout latch value z = y / (xs * ws); internal
@@ -515,30 +498,28 @@ def codes_matmul(
     ones_n = jnp.ones((e, n), jnp.float32)
     acc = _dispatch(x_codes, w_codes, ones_m, ones_n, 1.0, None, None, None,
                     resolve_backend(backend), bool(interpret), code_dtype,
-                    None, None, True)
+                    None, None)
     return acc[0] if squeeze else acc
 
 
 def _dispatch(x_codes, w_codes, x_scale, w_scale, gain, out_bits, out_scale,
               out_window, backend, interpret, code_dtype, blocks,
-              group_widths, fused_calibration):
+              group_widths):
     """Route int inputs straight to the impl (no float cotangents exist);
     float inputs go through the shared custom VJP."""
     if jnp.issubdtype(x_codes.dtype, jnp.integer):
         return _tdvmm_impl(x_codes, w_codes, x_scale, w_scale, gain,
                            out_bits, out_scale, out_window, backend,
-                           interpret, code_dtype, blocks, group_widths,
-                           fused_calibration)
+                           interpret, code_dtype, blocks, group_widths)
     return _tdvmm_core(x_codes, w_codes, x_scale, w_scale, out_window, gain,
                        out_bits, out_scale, backend, interpret, code_dtype,
-                       blocks, group_widths, fused_calibration)
+                       blocks, group_widths)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("gain", "out_bits", "out_scale", "backend", "interpret",
-                     "code_dtype", "block_sizes", "group_widths",
-                     "fused_calibration"))
+                     "code_dtype", "block_sizes", "group_widths"))
 def tdvmm_matmul(
     x_codes: jax.Array,      # (M, K) or (E, M, K) signed time codes
     w_codes: jax.Array,      # (K, N) or (E, K, N) signed weight codes
@@ -552,14 +533,12 @@ def tdvmm_matmul(
     code_dtype: str = "auto",
     block_sizes: tuple[int, int, int] | None = None,
     group_widths: Optional[tuple[int, ...]] = None,
-    fused_calibration: bool = True,
     out_window: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Quantized four-quadrant TD-VMM: codes matmul + readout + scale epilogue.
 
     ``out_scale=None`` calibrates the readout window from the data (§3.1) —
-    on the Pallas backend via a per-tile max launch plus the fused kernel
-    (``fused_calibration=False`` forces the legacy unfused epilogue); pass
+    on the Pallas backend via a per-tile max launch plus the fused kernel; pass
     the value captured by ``core.layers.calibrate_out_scale`` (or the
     model-wide calibration pass) to skip the per-call max entirely.  A tuple
     is an (E,)-vector of fixed per-expert windows for batched inputs — still
@@ -645,8 +624,7 @@ def tdvmm_matmul(
     w_scale = w_scale.reshape(e, n).astype(jnp.float32)
     y = _dispatch(x_codes, w_codes, x_scale, w_scale, gain, out_bits,
                   out_scale, out_window, backend, bool(interpret),
-                  code_dtype, block_sizes, group_widths,
-                  bool(fused_calibration))
+                  code_dtype, block_sizes, group_widths)
     # lax.squeeze, not y[0]: integer indexing lowers to a full-range slice
     # copy of the (M, N) output before the squeeze view.
     return jax.lax.squeeze(y, (0,)) if squeeze else y

@@ -1,4 +1,4 @@
-"""jit-able train / prefill / decode step factories.
+"""jit-able train step factory and the train state it carries.
 
 train_step supports gradient-accumulation microbatching (scan over G
 microbatches, fp32 grad accumulators) — the memory/throughput lever for the
@@ -6,7 +6,6 @@ big configs — and donates the train state.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -81,14 +80,3 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, optimizer: Optimizer,
 
     return train_step
 
-
-def make_prefill_step(cfg: ModelConfig):
-    def prefill(params, batch, caches):
-        return model.prefill_step(params, batch, caches, cfg)
-    return prefill
-
-
-def make_decode_step(cfg: ModelConfig):
-    def decode(params, batch, caches):
-        return model.decode_step(params, batch, caches, cfg)
-    return decode

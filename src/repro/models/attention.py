@@ -36,8 +36,7 @@ class KVCache(NamedTuple):
     v_scale: jax.Array | None = None
 
 
-# perf it.9 — int8 KV cache (decode is cache-bandwidth-bound; see
-# EXPERIMENTS.md §Roofline "what moves the dominant term" for decode rows).
+# int8 KV cache: half the KV bytes a bandwidth-bound decode step reads.
 KV_CACHE_INT8 = False
 
 
@@ -126,7 +125,6 @@ def _merge_heads(x: jax.Array) -> jax.Array:
 FLASH_THRESHOLD = 2048   # use online-softmax blocked attention above this S
 FLASH_BLOCK_Q = 1024
 FLASH_BLOCK_KV = 1024
-FLASH_BLOCK_SKIP = False  # perf it.2: iterate only causal/in-window tile pairs
 
 
 def _attend_flash(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
@@ -203,116 +201,6 @@ def _attend_flash(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
     return outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq, h, d)[:, :sq_real]
 
 
-def _attend_flash_blocks(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
-    """Perf it.2: flash attention that iterates ONLY the (q, kv) tile pairs the
-    causal/SWA structure makes non-empty, with the tile mask shared as a small
-    loop-invariant constant per pair class.
-
-    vs _attend_flash (which visits all nq x nkv pairs and materializes a mask
-    per pair): causal halves the tile count; a W-window sweep at length S
-    visits ~S*W/B^2 tiles instead of (S/B)^2 — an 8x FLOP cut for Mixtral's
-    32k prefill.  Pair classes (full / diagonal / window-edge) run as three
-    scans over STATIC index lists, so the HLO trip counts — and the roofline
-    terms derived from them — reflect the real work.  Online-softmax merging
-    is order-independent, so processing tiles class-by-class is exact."""
-    b, sq, h, d = q.shape
-    skv = k.shape[1]
-    assert sq == skv and q_offset == 0, "block-skip path is for self-attention"
-    kvh = cfg.n_kv_heads
-    g = h // kvh
-    bs = min(FLASH_BLOCK_Q, sq)
-    # Non-block-multiple S: zero-pad to the tile grid.  Padded key columns
-    # only ever appear in diagonal tiles (every off-diagonal pair reads
-    # earlier, fully-real key blocks), where the causal mask already excludes
-    # them for real query rows (col > row); padded query rows are sliced off.
-    sq_real = sq
-    pad = (-sq) % bs
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        sq += pad
-    nq = sq // bs
-    scale = d ** -0.5
-    w = cfg.swa_window
-
-    qr = q.reshape(b, nq, bs, kvh, g, d).transpose(1, 0, 3, 4, 2, 5)
-    kr = k.reshape(b, nq, bs, kvh, d).transpose(1, 0, 3, 2, 4)
-    vr = v.reshape(b, nq, bs, kvh, d).transpose(1, 0, 3, 2, 4)
-
-    # --- static tile-pair classification -----------------------------------
-    full, diag, edges = [], [], {}
-    for qi in range(nq):
-        for ki in range(qi + 1):
-            r = qi - ki
-            if w is not None and r * bs >= w + bs - 1:
-                continue                       # fully outside the window
-            if r == 0:
-                diag.append((qi, ki))
-            elif w is not None and (r + 1) * bs > w:
-                edges.setdefault(r, []).append((qi, ki))   # window boundary
-            else:
-                full.append((qi, ki))
-
-    ii = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
-    diag_mask = ii >= jj
-    if w is not None:
-        diag_mask &= (ii - jj) < w
-
-    def scan_pairs(carry, pairs, mask):
-        if not pairs:
-            return carry
-        idx = jnp.asarray(pairs, jnp.int32)
-
-        def step(c, p):
-            m, l, acc = c
-            qi, ki = p[0], p[1]
-            qb = jax.lax.dynamic_index_in_dim(qr, qi, 0, keepdims=False)
-            kb = jax.lax.dynamic_index_in_dim(kr, ki, 0, keepdims=False)
-            vb = jax.lax.dynamic_index_in_dim(vr, ki, 0, keepdims=False)
-            logits = jnp.einsum("bkgqd,bktd->bkgqt", qb, kb,
-                                preferred_element_type=jnp.float32) * scale
-            if mask is not None:
-                logits = jnp.where(mask[None, None, None], logits, -1e30)
-            mi = jax.lax.dynamic_index_in_dim(m, qi, 0, keepdims=False)
-            li = jax.lax.dynamic_index_in_dim(l, qi, 0, keepdims=False)
-            ai = jax.lax.dynamic_index_in_dim(acc, qi, 0, keepdims=False)
-            m_new = jnp.maximum(mi, logits.max(-1))
-            p_ = jnp.exp(logits - m_new[..., None])
-            corr = jnp.exp(mi - m_new)
-            l_new = li * corr + p_.sum(-1)
-            a_new = ai * corr[..., None] + jnp.einsum(
-                "bkgqt,bktd->bkgqd", p_.astype(vb.dtype), vb,
-                preferred_element_type=jnp.float32)
-            return (jax.lax.dynamic_update_index_in_dim(m, m_new, qi, 0),
-                    jax.lax.dynamic_update_index_in_dim(l, l_new, qi, 0),
-                    jax.lax.dynamic_update_index_in_dim(acc, a_new, qi, 0)), None
-
-        carry, _ = jax.lax.scan(step, carry, idx)
-        return carry
-
-    m0 = jnp.full((nq, b, kvh, g, bs), -1e30, jnp.float32)
-    l0 = jnp.zeros((nq, b, kvh, g, bs), jnp.float32)
-    a0 = jnp.zeros((nq, b, kvh, g, bs, d), jnp.float32)
-    carry = (m0, l0, a0)
-    carry = scan_pairs(carry, full, None)
-    carry = scan_pairs(carry, diag, diag_mask)
-    for r, pairs in edges.items():
-        edge_mask = (r * bs + ii - jj) < w
-        carry = scan_pairs(carry, pairs, edge_mask)
-    m, l, acc = carry
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq, h, d)[
-        :, :sq_real].astype(q.dtype)
-
-
-def _flash(q, k, v, cfg: ModelConfig) -> jax.Array:
-    if FLASH_BLOCK_SKIP and q.shape[1] == k.shape[1]:
-        return _attend_flash_blocks(q, k, v, cfg)
-    return _attend_flash(q, k, v, cfg)
-
-
 @scope("attention")
 def _attend(q, k, v, mask, cfg: ModelConfig) -> jax.Array:
     """q: (B,Sq,H,D); k,v: (B,Skv,Kv,D); mask: (B,1,Sq,Skv) or broadcastable."""
@@ -345,12 +233,12 @@ def apply_train(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
     q, k, v, g = _project(params, x, cfg, positions, key)
     s = x.shape[1]
     if s > FLASH_THRESHOLD:
-        out = _flash(q, k, v, cfg)
+        out = _attend_flash(q, k, v, cfg)
     else:
         mask = _causal_mask(s, s, 0, cfg.swa_window)
         out = _attend(q, k, v, mask, cfg)
-    return common.dense_tp_reduce(params["wo"], _merge_heads(_gated(out, g)),
-                                  cfg.site_tdvmm("attn.out"), key)
+    return common.dense(params["wo"], _merge_heads(_gated(out, g)),
+                        cfg.site_tdvmm("attn.out"), key)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> KVCache:
@@ -374,7 +262,7 @@ def apply_prefill(params, x: jax.Array, cfg: ModelConfig, cache: KVCache,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     q, k, v, g = _project(params, x, cfg, positions, key)
     if s > FLASH_THRESHOLD:
-        out = _flash(q, k, v, cfg)
+        out = _attend_flash(q, k, v, cfg)
     else:
         mask = _causal_mask(s, s, 0, cfg.swa_window)
         out = _attend(q, k, v, mask, cfg)

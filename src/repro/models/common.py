@@ -71,24 +71,6 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# Matmul output dtype control (perf knob; see EXPERIMENTS.md §Perf it.1)
-# --------------------------------------------------------------------------
-# When set to bf16, every dense matmul emits bf16 partial sums
-# (preferred_element_type), so GSPMD's tensor-parallel all-reduces move half
-# the bytes.  MXU still accumulates in f32 internally on TPU.
-_MATMUL_OUT_DTYPE = None
-
-
-def set_matmul_out_dtype(dtype):
-    global _MATMUL_OUT_DTYPE
-    _MATMUL_OUT_DTYPE = dtype
-
-
-def matmul_out_dtype():
-    return _MATMUL_OUT_DTYPE
-
-
-# --------------------------------------------------------------------------
 # Dense (TD-VMM-aware)
 # --------------------------------------------------------------------------
 def dense_init(key, d_in: int, d_out: int, dtype=jnp.float32, bias: bool = False,
@@ -122,63 +104,6 @@ def dense_group(param_group, x: jax.Array, td: TDVMMLayerConfig,
     return tuple(
         y + p["b"].astype(y.dtype) if "b" in p else y
         for p, y in zip(param_group, ys))
-
-
-# --------------------------------------------------------------------------
-# Explicit-TP reduction matmul (perf it.1b — EXPERIMENTS.md §Perf)
-# --------------------------------------------------------------------------
-# GSPMD places the tensor-parallel all-reduce directly after the partial-sum
-# dot, which the CPU backend legalizes to f32 — and on TPU is also f32 when
-# the dot accumulates in f32.  For the two reduction matmuls of each block
-# (attn wo, ffn w_down) this wrapper makes the collective EXPLICIT: local
-# (f/tp) x (f/tp, d) matmul, cast to bf16, psum over the model axis — halving
-# the dominant wire bytes.  Weights arrive FSDP+TP sharded; the FSDP gather
-# over dp is explicit too (bf16).
-TP_EXPLICIT = False
-
-
-def set_tp_explicit(on: bool):
-    global TP_EXPLICIT
-    TP_EXPLICIT = on
-
-
-def dense_tp_reduce(params, x: jax.Array, td: TDVMMLayerConfig, key=None) -> jax.Array:
-    """x: (..., f) with f TP-shardable; w: (f, d).  Falls back to dense()
-    when explicit TP is off, no mesh is active, or TD-VMM mode is on."""
-    from jax.sharding import PartitionSpec as P
-    from repro.launch import meshctx
-
-    mesh = meshctx.get_mesh()
-    if not TP_EXPLICIT or mesh is None or td.enabled:
-        return dense(params, x, td, key)
-    dp = meshctx.dp_axes()
-    tp = meshctx.tp_axis()
-    w = params["w"]
-    f, d_out = w.shape
-    tpn = mesh.shape[tp]
-    dpn = 1
-    for a in dp:
-        dpn *= mesh.shape[a]
-    if f % tpn or x.shape[0] % dpn or w.shape[0] % tpn or d_out % dpn:
-        return dense(params, x, td, key)
-
-    def inner(x_loc, w_loc):
-        # w_loc: (f/tp, d/dp) -> gather FSDP shards (bf16 wire)
-        w_full = jax.lax.all_gather(w_loc, dp, axis=1, tiled=True)
-        y = jnp.dot(x_loc, w_full)                  # (..., f/tp) @ (f/tp, d)
-        y = jax.lax.psum(y.astype(jnp.bfloat16), tp)
-        return y
-
-    batch_spec = P(dp, *([None] * (x.ndim - 2)), tp)
-    y = jax.shard_map(
-        inner, mesh=mesh,
-        in_specs=(batch_spec, P(tp, dp)),
-        out_specs=P(dp, *([None] * (x.ndim - 1))),
-        check_vma=False,
-    )(x, w)
-    if "b" in params:
-        y = y + params["b"].astype(y.dtype)
-    return y
 
 
 def activation(name: str, x: jax.Array) -> jax.Array:

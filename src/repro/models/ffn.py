@@ -30,5 +30,4 @@ def apply(params, x: jax.Array, cfg: ModelConfig, key=None) -> jax.Array:
         h = h * common.dense(params["w_up"], x, td_in, key)
     else:
         h = common.activation(cfg.act, common.dense(params["w_up"], x, td_in, key))
-    return common.dense_tp_reduce(params["w_down"], h,
-                                  cfg.site_tdvmm("ffn.out"), key)
+    return common.dense(params["w_down"], h, cfg.site_tdvmm("ffn.out"), key)

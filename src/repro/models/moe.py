@@ -37,7 +37,6 @@ and scaled (``route_scale``).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -104,15 +103,13 @@ def _expert_ffn(bank, x, cfg: ModelConfig, tp_axis: Optional[str], key=None,
     td_in = cfg.site_tdvmm(site_prefix + ".in")
     td_out = cfg.site_tdvmm(site_prefix + ".out")
     keys = iter(jax.random.split(key, 3)) if key is not None else None
-    pet = common.matmul_out_dtype()
-    kw = {"preferred_element_type": pet} if pet is not None else {}
 
     def mm(a, wmat, td):
         if td.enabled:
             from repro.core import layers as td_layers
             k = next(keys) if keys is not None else None
             return td_layers.td_expert_matmul(a, wmat, td, key=k)
-        return jnp.einsum("ecd,edf->ecf", a, wmat, **kw)
+        return jnp.einsum("ecd,edf->ecf", a, wmat)
 
     if "w_gate" in bank:
         h = jax.nn.silu(mm(x, bank["w_gate"], td_in))

@@ -41,7 +41,7 @@ last rank — see the ``PagePool`` docstring for the id arithmetic.
 Host side, ``PagePool`` is a deterministic free-list allocator (lowest free
 id first) that tracks the in-use high-water mark — the paged counterpart of
 the dense path's ``batch * max_len`` footprint, asserted smaller on ragged
-traces by ``benchmarks/bench_serving.py``.
+traces by ``tests/test_engine.py``.
 """
 from __future__ import annotations
 

@@ -29,8 +29,8 @@ with all open spans, so a preempted engine restored in a fresh process
 continues the *same* trace — one continuous, schema-valid file across a
 kill+restore (Engine snapshot meta v4).
 
-``validate_chrome_trace`` is the shared schema check (tests, benchmarks,
-CI): integer pid/tid, non-decreasing ``ts`` per (pid, tid), balanced
+``validate_chrome_trace`` is the shared schema check (tests and CI):
+integer pid/tid, non-decreasing ``ts`` per (pid, tid), balanced
 stack-disciplined ``B``/``E`` pairs.
 
 Profiler attribution is the second, device-side view, written into the
@@ -325,7 +325,7 @@ class Tracer:
 
 
 # --------------------------------------------------------------------------
-# Schema validation (shared by tests, benchmarks, and CI)
+# Schema validation (shared by tests and CI)
 # --------------------------------------------------------------------------
 def validate_chrome_trace(doc) -> dict:
     """Validate a Chrome Trace Event Format document.

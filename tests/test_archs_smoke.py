@@ -1,6 +1,6 @@
 """Per-architecture smoke tests: reduced config, one forward/train/decode step
-on CPU; assert output shapes and no NaNs.  Full configs are exercised only via
-the dry-run (launch/dryrun.py, ShapeDtypeStruct — no allocation)."""
+on CPU; assert output shapes and no NaNs.  Full widths run on the chip, in
+the benchmark's cells (``bench/run.py``)."""
 import jax
 import jax.numpy as jnp
 import pytest

@@ -279,8 +279,9 @@ def test_tdvmm_ragged_group_widths_matches_sequential():
 def test_tdvmm_fused_calibration_matches_unfused():
     """The fused data-calibrated readout (per-tile max|z| launch, then the
     fused kernel with the per-expert windows: one (M, N) HBM write) is
-    bit-for-bit with the legacy two-pass path and with the jnp oracle —
-    batched experts included."""
+    bit-for-bit with the unfused formulation — ``ops._epilogue`` on the
+    kernel's raw accumulation — and with the jnp oracle, batched experts
+    included."""
     from repro.kernels.tdvmm import ops
     e, m, k, n = 2, 33, 96, 40
     kx, kw = jax.random.split(jax.random.PRNGKey(8))
@@ -291,13 +292,180 @@ def test_tdvmm_fused_calibration_matches_unfused():
     ws = jax.random.uniform(jax.random.PRNGKey(10), (e, n), minval=0.5,
                             maxval=2.0)
     kwargs = dict(gain=1e-4, out_bits=6, out_scale=None)
-    fused = ops.tdvmm_matmul(xq, wq, xs, ws, backend="pallas",
-                             fused_calibration=True, **kwargs)
-    unfused = ops.tdvmm_matmul(xq, wq, xs, ws, backend="pallas",
-                               fused_calibration=False, **kwargs)
+    fused = ops.tdvmm_matmul(xq, wq, xs, ws, backend="pallas", **kwargs)
+    bm, bk, bn = autotune_blocks(m, k, n, xq.dtype)
+    xp, wp = pad_to_blocks(xq, wq, bm, bk, bn)
+    acc = tdvmm_matmul_kernel(xp, wp, bm=bm, bk=bk, bn=bn,
+                              interpret=True)[:, :m, :n]
+    unfused = ops._epilogue(acc, xs, ws, **kwargs)
     oracle = ops.tdvmm_matmul(xq, wq, xs, ws, backend="jnp", **kwargs)
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(oracle))
+
+
+# --------------------------------------------------------------------------
+# tdvmm: launches, encodes, output writes and code bytes, counted in the
+# traced program (no timing)
+# --------------------------------------------------------------------------
+def _eqns(jx):
+    """Every equation of a jaxpr, recursing into nested (pjit / custom_vjp /
+    scan / pallas_call) sub-jaxprs."""
+    for eqn in jx.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+                elif hasattr(sub, "jaxpr"):
+                    yield from _eqns(sub.jaxpr)
+
+
+def _traced(fn, *args):
+    return list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _count(eqns, prim):
+    return sum(e.primitive.name == prim for e in eqns)
+
+
+def _mn_writes(fn, args, m, n):
+    """Top-level equations that materialize an (..., M, N) array: each is an
+    HBM round trip of the whole output.  A pallas_call's own output is one
+    write; its body's blocks live in VMEM, so it is not entered.  View
+    primitives write nothing of their own."""
+    views = {"squeeze", "reshape", "broadcast_in_dim", "transpose"}
+
+    def walk(jx):
+        count = 0
+        for eqn in jx.eqns:
+            if eqn.primitive.name in views:
+                continue
+            mn_out = any(getattr(v.aval, "shape", ())[-2:] == (m, n)
+                         for v in eqn.outvars)
+            subs = [sub for val in eqn.params.values()
+                    for sub in (val if isinstance(val, (list, tuple))
+                                else [val])
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr")]
+            if eqn.primitive.name == "pallas_call" or not subs:
+                count += mn_out
+            else:
+                count += sum(walk(sub if hasattr(sub, "eqns") else sub.jaxpr)
+                             for sub in subs)
+        return count
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("m,k,ns", [
+    (64, 896, (896, 128, 128)),                   # attn.qkv: wq / wk / wv
+    (64, 512, (1024, 1024, 128, 128, 16)),        # ssm.in_proj: z/x/B/C/dt
+], ids=["attn_qkv", "ssm_in_proj"])
+def test_grouped_projection_is_one_launch(m, k, ns):
+    """G same-input projections run as ONE ragged concat launch with ONE
+    input encode (G sequential td_matmuls: G of each), each member padded
+    only to the lane, and bit-for-bit equal to the sequential calls on both
+    backends."""
+    from repro.core.layers import (TDVMMLayerConfig, td_grouped_matmul,
+                                   td_matmul)
+    g = len(ns)
+    x = jax.random.normal(jax.random.PRNGKey(g), (m, k))
+    ws = tuple(jax.random.normal(jax.random.PRNGKey(17 + i), (k, n)) * 0.1
+               for i, n in enumerate(ns))
+    outs = {}
+    for backend in ("jnp", "pallas"):
+        cfg = TDVMMLayerConfig(enabled=True, backend=backend)
+        grouped = jax.jit(lambda x_, ws_, c=cfg: td_grouped_matmul(x_, ws_, c))
+        seq = jax.jit(lambda x_, ws_, c=cfg: tuple(td_matmul(x_, w, c)
+                                                   for w in ws_))
+        eg, es = _traced(grouped, x, ws), _traced(seq, x, ws)
+        prim = "dot_general" if backend == "jnp" else "pallas_call"
+        assert _count(es, prim) == g * _count(eg, prim), backend
+        if backend == "jnp":
+            assert _count(eg, "dot_general") == 1
+            encodes = [sum(e.primitive.name == "convert_element_type"
+                           and any(v.aval.shape == (m, k)
+                                   and v.aval.dtype == jnp.int8
+                                   for v in e.outvars) for e in eqns)
+                       for eqns in (eg, es)]
+            assert encodes == [1, g]
+            n_total = next(e.invars[1].aval.shape[-1] for e in eg
+                           if e.primitive.name == "dot_general")
+            assert n_total <= 1.05 * sum(ns), (n_total, ns)
+        outs[backend] = grouped(x, ws)
+        for a, b in zip(outs[backend], seq(x, ws)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(outs["jnp"], outs["pallas"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("out_scale,launches", [(0.5, 1), (None, 2)],
+                         ids=["fixed_window", "data_calibrated"])
+def test_fused_readout_writes_one_mn_output(out_scale, launches):
+    """The Pallas readout, with a fixed window or calibrated from the data
+    (a per-tile max launch first), writes the (M, N) output to HBM once,
+    already in model units; the jnp backend's unfused epilogue writes more."""
+    from repro.kernels.tdvmm import ops
+    m, k, n = 256, 1024, 512
+    xc = jnp.zeros((m, k), jnp.int8)
+    wc = jnp.zeros((k, n), jnp.int8)
+    xs, ws = jnp.ones((m,)), jnp.ones((n,))
+    writes = {}
+    for backend in ("jnp", "pallas"):
+        fn = jax.jit(lambda *a, b=backend: ops.tdvmm_matmul(
+            *a, gain=1e-4, out_bits=6, out_scale=out_scale, backend=b))
+        writes[backend] = _mn_writes(fn, (xc, wc, xs, ws), m, n)
+        if backend == "pallas":
+            assert _count(_traced(fn, xc, wc, xs, ws),
+                          "pallas_call") == launches
+    assert writes["pallas"] == 1
+    assert writes["jnp"] > writes["pallas"]
+
+
+@pytest.mark.parametrize("code_dtype", ["int8", "f32"])
+def test_code_dtype_reaches_the_contraction(code_dtype):
+    """int8 code storage is what the codes matmul consumes (a quarter of the
+    f32 bytes), not a cast back to f32 before the dot."""
+    from repro.kernels.tdvmm import ops
+    m, k, n = 512, 2048, 512
+    dt = jnp.int8 if code_dtype == "int8" else jnp.float32
+    args = (jnp.zeros((m, k), dt), jnp.zeros((k, n), dt), jnp.ones((m,)),
+            jnp.ones((n,)))
+    fn = jax.jit(lambda *a: ops.tdvmm_matmul(
+        *a, gain=1e-4, out_bits=6, out_scale=0.5, backend="jnp"))
+    dot = next(e for e in _traced(fn, *args)
+               if e.primitive.name == "dot_general")
+    assert dot.invars[0].aval.dtype == dt
+
+
+def test_int4_launch_streams_half_the_code_bytes():
+    """The int4 Pallas launch streams nibble-packed codes: its operands come
+    to at most 0.6x the int8 launch's bytes (the scales ride in both)."""
+    from repro.kernels.tdvmm import ops
+    m, k, n = 512, 1024, 4096
+    args = (jnp.zeros((m, k), jnp.int8), jnp.zeros((k, n), jnp.int8),
+            jnp.ones((m,)), jnp.ones((n,)))
+    streamed = {}
+    for code_dtype in ("int8", "int4"):
+        fn = jax.jit(lambda *a, c=code_dtype: ops.tdvmm_matmul(
+            *a, gain=1e-4, out_bits=6, out_scale=0.5, backend="pallas",
+            code_dtype=c))
+        call = next(e for e in _traced(fn, *args)
+                    if e.primitive.name == "pallas_call")
+        streamed[code_dtype] = sum(
+            int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize
+            for v in call.invars)
+    assert streamed["int4"] <= 0.6 * streamed["int8"], streamed
+
+
+def test_autotune_table_answers_the_reference_shapes():
+    """Model-width launches are table hits on this platform, with their
+    blocks recorded in the process's autotune report."""
+    from repro.kernels.tdvmm import ops
+    for m, k, n in [(512, 1024, 4096), (256, 896, 896)]:
+        plan = ops.plan_kernel("pallas", m, k, n, "f32")
+        assert plan.autotune_hit, (m, k, n, plan)
+        entry = ops.autotune_report()["entries"][f"{m}x{k}x{n}:float32"]
+        assert entry["hit"] and len(entry["blocks"]) == 3
 
 
 # --------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Multi-device numerical equivalence: the sharded execution paths (GSPMD
-FSDP+TP, shard_map MoE local/EP, explicit-TP reductions) must produce the
-same numbers as single-device execution.
+FSDP+TP, shard_map MoE local/EP) must produce the same numbers as
+single-device execution.
 
 Runs in a subprocess with 4 forced host devices so the main test process
 keeps its single-device jax runtime.
@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, smoke
 from repro.launch import meshctx, sharding
 from repro.launch.mesh import make_test_mesh, axis_info
-from repro.models import model, common
+from repro.models import model
 
 results = {}
 import dataclasses
@@ -59,16 +59,6 @@ for arch in ["mixtral-8x7b", "kimi-k2-1t-a32b", "yi-34b"]:
         out = fwd(params_sharded, batch)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
     results[arch] = err
-
-    # explicit-TP path (it.1b) for the dense arch
-    if arch == "yi-34b":
-        common.set_tp_explicit(True)
-        with mesh:
-            out2 = jax.jit(lambda p, bt: model.forward(p, bt, cfg)[0])(
-                params_sharded, batch)
-        common.set_tp_explicit(False)
-        results["yi-34b_tp_explicit"] = float(
-            jnp.max(jnp.abs(out2.astype(jnp.float32) - ref.astype(jnp.float32))))
     meshctx.set_mesh(None)
 
 # ---- elastic restore: checkpoint under mesh A, restore under mesh B --------
