@@ -39,12 +39,14 @@ the ``num_pages + 1`` region and the global trash page is the last row of the
 last rank — see the ``PagePool`` docstring for the id arithmetic.
 
 Host side, ``PagePool`` is a deterministic free-list allocator (lowest free
-id first) that tracks the in-use high-water mark — the paged counterpart of
-the dense path's ``batch * max_len`` footprint, asserted smaller on ragged
-traces by ``tests/test_engine.py``.
+id first; a min-heap per rank, so a call costs the pages it moves, not the
+pool) that tracks the in-use high-water mark — the paged counterpart of the
+dense path's ``batch * max_len`` footprint, asserted smaller on ragged traces
+by ``tests/test_engine.py``.
 """
 from __future__ import annotations
 
+import heapq
 from typing import NamedTuple, Optional
 
 import jax
@@ -111,9 +113,12 @@ class PagePool:
         self.page_size = page_size
         self.ranks = ranks
         self._stride = num_pages + 1
-        # Per-rank free lists, each kept sorted ascending (global ids).
+        # Per-rank min-heaps of free global ids (an ascending list is a
+        # heap), and one "is free" flag per device row: alloc and free cost
+        # O(log P) per page moved, not O(P) per call.
         self._free = [list(range(r * self._stride, r * self._stride + num_pages))
                       for r in range(ranks)]
+        self._is_free = bytearray((b"\x01" * num_pages + b"\x00") * ranks)
         self.high_water = 0
 
     @property
@@ -142,8 +147,8 @@ class PagePool:
         return rank
 
     def alloc(self, n: int, rank: int = 0) -> Optional[list[int]]:
-        """Take the n lowest free page ids of ``rank``, or None (nothing
-        taken) if that rank's region can't satisfy the request."""
+        """Take the n lowest free page ids of ``rank`` (ascending), or None
+        (nothing taken) if that rank's region can't satisfy the request."""
         if n < 0:
             raise ValueError(f"alloc({n})")
         if not (0 <= rank < self.ranks):
@@ -151,32 +156,49 @@ class PagePool:
         free = self._free[rank]
         if n > len(free):
             return None
-        pages, self._free[rank] = free[:n], free[n:]
+        pages = [heapq.heappop(free) for _ in range(n)]
+        for p in pages:
+            self._is_free[p] = 0
         self.high_water = max(self.high_water, self.in_use)
         return pages
 
     def free(self, pages: list[int]) -> None:
+        """Return ``pages`` (any ranks, any order) to the pool.  Raises, and
+        takes nothing back, on a duplicate, an out-of-range page or a page
+        that is already free."""
         if len(set(pages)) != len(pages):
             raise ValueError(f"duplicate page ids in free: {pages}")
         for p in pages:
-            rank = self._rank_of(p)
-            if p in self._free[rank]:
+            self._rank_of(p)
+            if self._is_free[p]:
                 raise ValueError(f"double free of page {p}")
         for p in pages:
-            self._free[self._rank_of(p)].append(p)
-        for f in self._free:
-            f.sort()
+            self._is_free[p] = 1
+            heapq.heappush(self._free[p // self._stride], p)
 
     def free_lists(self) -> list[list[int]]:
-        """Snapshot of the per-rank free lists (copies, for checkpointing)."""
-        return [list(f) for f in self._free]
+        """Snapshot of the per-rank free lists (sorted copies, for
+        checkpointing)."""
+        return [sorted(f) for f in self._free]
 
     def restore_free(self, lists: list[list[int]]) -> None:
-        """Restore free lists from a snapshot (inverse of ``free_lists``)."""
+        """Restore free lists from a snapshot (inverse of ``free_lists``).
+        Each rank's list holds that rank's pages, each at most once."""
         if len(lists) != self.ranks:
             raise ValueError(f"snapshot has {len(lists)} rank free-lists, "
                              f"pool has {self.ranks}")
-        self._free = [sorted(int(p) for p in f) for f in lists]
+        free = [sorted(int(p) for p in f) for f in lists]
+        is_free = bytearray(self.ranks * self._stride)
+        for rank, f in enumerate(free):
+            for p in f:
+                if self._rank_of(p) != rank:
+                    raise ValueError(f"snapshot free-list of rank {rank} "
+                                     f"holds page {p} of another rank")
+                if is_free[p]:
+                    raise ValueError(f"snapshot free-list of rank {rank} "
+                                     f"holds page {p} twice")
+                is_free[p] = 1
+        self._free, self._is_free = free, is_free
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:

@@ -456,6 +456,148 @@ def test_page_pool_dp_ranks_partition_and_snapshot():
         PagePool(num_pages=6, page_size=4).restore_free(lists)
 
 
+class _SortedListPool:
+    """Reference model of the pool's semantics: per-rank sorted lists,
+    ``alloc`` takes the head, ``free`` merges and re-sorts."""
+
+    def __init__(self, num_pages, ranks):
+        stride = num_pages + 1
+        self.lists = [list(range(r * stride, r * stride + num_pages))
+                      for r in range(ranks)]
+        self.total = ranks * num_pages
+        self.high_water = 0
+
+    def in_use(self):
+        return self.total - sum(len(f) for f in self.lists)
+
+    def alloc(self, n, rank):
+        f = self.lists[rank]
+        if n > len(f):
+            return None
+        pages, self.lists[rank] = f[:n], f[n:]
+        self.high_water = max(self.high_water, self.in_use())
+        return pages
+
+    def free(self, pages, stride):
+        for p in pages:
+            self.lists[p // stride].append(p)
+        for f in self.lists:
+            f.sort()
+
+
+@pytest.mark.parametrize("ranks,num_pages",
+                         [(1, 6), (2, 6), (1, 300), (2, 4_096),
+                          (1, 49_152), (2, 24_576)])
+def test_page_pool_matches_sorted_list_reference(ranks, num_pages):
+    """A seeded run of allocs (admission-sized and decode's alloc(1)) and
+    frees (whole slots, across ranks, in any order): the pool hands out the
+    same ids, the same Nones and the same counts as the sorted-list model
+    after every call, and a free_lists() -> restore_free() round trip
+    mid-run changes nothing that follows."""
+    rng = np.random.default_rng(ranks * 100_003 + num_pages)
+    stride = num_pages + 1
+    pool, ref = PagePool(num_pages, 4, ranks=ranks), _SortedListPool(
+        num_pages, ranks)
+    held = []                                   # page blocks, like slots
+    max_n = max(3, num_pages // 96)
+    for step in range(3_000):
+        op = rng.random()
+        if op < 0.03 and held and ref.in_use() < ref.total:
+            # a finish that names a free page: refused, nothing taken back
+            free = [p for f in ref.lists for p in f]
+            bad = held[rng.integers(len(held))] + [
+                free[rng.integers(len(free))]]
+            with pytest.raises(ValueError, match="double free"):
+                pool.free([bad[i] for i in rng.permutation(len(bad))])
+        elif op < 0.15 and held:                # decode crosses a page
+            block = held[rng.integers(len(held))]
+            rank = block[0] // stride
+            got = pool.alloc(1, rank=rank)
+            assert got == ref.alloc(1, rank)
+            if got is not None:
+                block.extend(got)
+        elif op < 0.7 or not held:              # admission
+            n, rank = int(rng.integers(0, max_n + 1)), int(rng.integers(ranks))
+            got = pool.alloc(n, rank=rank)
+            assert got == ref.alloc(n, rank)
+            if got:
+                held.append(got)
+        else:                                   # finishes, any order
+            k = min(len(held), 1 + int(rng.random() < 0.2))
+            pages = [p for _ in range(k)
+                     for p in held.pop(rng.integers(len(held)))]
+            pages = [pages[i] for i in rng.permutation(len(pages))]
+            pool.free(pages)
+            ref.free(pages, stride)
+        assert pool.free_pages == sum(len(f) for f in ref.lists)
+        assert pool.in_use == ref.in_use()
+        assert pool.high_water == ref.high_water
+        if step % 500 == 250:
+            lists = pool.free_lists()
+            assert lists == ref.lists
+            pool = PagePool(num_pages, 4, ranks=ranks)
+            pool.restore_free(lists)
+            pool.high_water = ref.high_water
+            assert pool.free_lists() == lists
+    assert pool.free_lists() == ref.lists
+
+
+def _large_pool_with_a_slot():
+    """Two ranks of 49,152 pages; rank 1 holds a 512-page slot above 100
+    free pages (taken and given back)."""
+    pool = PagePool(num_pages=49_152, page_size=16, ranks=2)
+    first = pool.alloc(100, rank=1)
+    slot = pool.alloc(512, rank=1)
+    pool.free(first)
+    return pool, slot
+
+
+@pytest.mark.parametrize("case", ["double_free_one_of_512",
+                                  "duplicate_in_one_call",
+                                  "rank0_trash_row", "global_trash_page",
+                                  "past_last_rank", "negative_id",
+                                  "restore_wrong_rank_count",
+                                  "restore_page_of_other_rank",
+                                  "restore_page_twice"])
+def test_page_pool_error_paths_on_a_large_pool(case):
+    """Each bad call raises ValueError and leaves the pool as it was: the
+    same free lists, and the slot's pages still free exactly once."""
+    pool, slot = _large_pool_with_a_slot()
+    stride = 49_153
+    before = pool.free_lists()
+    bad_free = {
+        "double_free_one_of_512": slot[:511] + [stride + 5],   # a free page
+        "duplicate_in_one_call": slot + [slot[17]],
+        "rank0_trash_row": slot + [49_152],
+        "global_trash_page": slot + [pool.trash_page],
+        "past_last_rank": slot + [2 * stride],
+        "negative_id": slot + [-1],
+    }
+    if case in bad_free:
+        match = {"double_free_one_of_512": "double free",
+                 "duplicate_in_one_call": "duplicate"}.get(case,
+                                                           "out-of-range")
+        with pytest.raises(ValueError, match=match):
+            pool.free(bad_free[case])
+    else:
+        lists = [list(f) for f in before]
+        if case == "restore_wrong_rank_count":
+            lists, match = lists[:1], "rank free-lists"
+        elif case == "restore_page_of_other_rank":
+            lists[0].append(slot[0])
+            match = "another rank"
+        else:
+            lists[1].append(lists[1][-1])
+            match = "twice"
+        with pytest.raises(ValueError, match=match):
+            pool.restore_free(lists)
+    assert pool.free_lists() == before
+    pool.free(slot)
+    with pytest.raises(ValueError, match="double free"):
+        pool.free([slot[0]])
+    assert pool.alloc(612, rank=1) == list(range(stride, stride + 612))
+
+
 # --------------------------------------------------------------------------
 # Acceptance (PR 9): mesh-sharded engine, (1,1) mesh == no mesh exactly
 # --------------------------------------------------------------------------
